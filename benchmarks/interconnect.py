@@ -3,54 +3,52 @@
 Edge scale: first-order energy/latency from the CGRA model.
 Pod scale: lowered-HLO comparison of the torus ring schedule
 (collective_permute chain) vs XLA's default all-gather for the same
-tensor-parallel GEMM, on an 8-way fake mesh (subprocess-free: this module is
-run by benchmarks.run inside the main process, which keeps 1 device — so the
-pod-scale part shells out).
+tensor-parallel GEMM, compiled in this process over every device it has:
+the chips of a TPU host, or host CPU devices forced before the process
+starts (``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 """
-import os
 import re
-import subprocess
-import sys
-import textwrap
 
 from repro.core.cgra import CGRAConfig, simulate_transformer_layer
 
-_POD_SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import jax, jax.numpy as jnp, numpy as np, re
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax moved it to the top level
-        from jax import shard_map
-    from jax.sharding import PartitionSpec as P
+
+def pod_schedule() -> list[str]:
+    """Collective-op counts of both schedules' compiled HLO."""
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from repro.core import torus
     from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh((8,), ("model",))
+    n = jax.device_count()
+    if n < 2:
+        return [f"not run: the ring needs >= 2 devices, this process has {n} "
+                f"(on CPU start it with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count=8)"]
+    mesh = make_mesh((n,), ("model",))
     T, D, F = 1024, 512, 2048
     x = jax.ShapeDtypeStruct((T, D), jnp.bfloat16)
     w = jax.ShapeDtypeStruct((D, F), jnp.bfloat16)
+    row, col = P("model", None), P(None, "model")
 
     ring = shard_map(lambda xs, ws: torus.ring_allgather_matmul(xs, ws),
-                     mesh=mesh, in_specs=(P("model", None), P(None, "model")),
-                     out_specs=P(None, "model"))
+                     mesh=mesh, in_specs=(row, col), out_specs=col)
     t_ring = jax.jit(ring).lower(x, w).compile().as_text()
-
-    def xla_default(xs, ws):
-        return jnp.matmul(xs, ws)  # x token-sharded -> XLA all-gathers
-    f2 = jax.jit(xla_default,
-                 in_shardings=(jax.NamedSharding(mesh, P("model", None)),
-                               jax.NamedSharding(mesh, P(None, "model"))),
-                 out_shardings=jax.NamedSharding(mesh, P(None, "model")))
-    t_xla = f2.lower(x, w).compile().as_text()
+    xla_default = jax.jit(  # x token-sharded -> XLA all-gathers
+        jnp.matmul,
+        in_shardings=(NamedSharding(mesh, row), NamedSharding(mesh, col)),
+        out_shardings=NamedSharding(mesh, col))
+    t_xla = xla_default.lower(x, w).compile().as_text()
 
     def stats(txt):
         return {k: len(re.findall(k, txt))
                 for k in ("all-gather", "collective-permute", "all-reduce")}
-    print("ring", stats(t_ring))
-    print("xla ", stats(t_xla))
-""")
+    return [f"devices: {n} x {jax.devices()[0].device_kind}",
+            f"ring {stats(t_ring)}", f"xla  {stats(t_xla)}",
+            "derived: the torus schedule issues only neighbor "
+            "collective-permutes (overlappable per-step), zero all-gathers"]
 
 
 def run() -> list[str]:
@@ -70,15 +68,9 @@ def run() -> list[str]:
                f"energy, {100*(1 - t.cycles/s.cycles):.2f}% latency (first-order)")
 
     out.append("")
-    out.append("# E2 pod scale — HLO collective schedule, TP GEMM on 8-way mesh")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run([sys.executable, "-c", _POD_SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=600)
-    out.extend((res.stdout or res.stderr).strip().splitlines())
-    out.append("derived: the torus schedule issues only neighbor "
-               "collective-permutes (overlappable per-step), zero all-gathers")
+    out.append("# E2 pod scale — HLO collective schedule, TP GEMM over every "
+               "device")
+    out.extend(pod_schedule())
     return out
 
 

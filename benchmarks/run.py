@@ -10,6 +10,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (blocking_sweep, edge_transformer, interconnect,
                             kernel_bench, kernel_mode_sweep, mob_overlap,
                             roofline_table, serving_throughput)

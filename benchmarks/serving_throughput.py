@@ -23,10 +23,12 @@ Workloads:
   rates.  Recompute admits on prompt-only reservations and resolves pool
   pressure by preempt-and-recompute; off reserves fully up front and sheds
   the same load at the bounded queue instead.
-- mesh scaling: re-execs itself with 8 forced host devices and measures
-  closed-batch tokens/s plus compiled-HLO bytes-accessed-per-decode-token
-  at mesh widths 1/2/4/8 (host-CPU shards share the physical core pool, so
-  bytes moved — not tokens/s — is the scaling signal).
+- mesh scaling: closed-batch tokens/s plus compiled-HLO
+  bytes-accessed-per-decode-token at mesh widths 1/2/4/8, over the devices
+  this process has (on CPU, start it with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; host-CPU shards
+  share the physical core pool, so bytes moved — not tokens/s — is the
+  scaling signal there).
 
 ``--json PATH`` additionally dumps the headline numbers (tokens/s, prefix
 hit rate, concurrency at fixed memory, goodput/TTFT/ITL chunked vs
@@ -208,14 +210,18 @@ def bench_degraded(cfg, params, preemption, *, n_req=16, rate=400.0,
         deadline_expired=eng.stats.deadline_expired - base[2])
 
 
-def bench_mesh_child(arch: str) -> dict:
-    """Runs inside the 8-forced-device subprocess: closed-batch throughput
-    and compiled decode bytes-per-token at mesh widths 1/2/4/8."""
+def bench_mesh_scaling(arch: str) -> dict:
+    """Closed-batch throughput and compiled decode bytes-per-token at mesh
+    widths 1/2/4/8, in this process, over the devices it has: the chips of a
+    TPU host, or host CPU devices forced before the process starts
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  Widths past
+    the device count are not run."""
     from repro.serving import MeshSpec
     cfg = reduce_config(get_config(arch))
     params = M.init(cfg, jax.random.PRNGKey(0))
     prompts = make_workload(cfg, n=4)
-    out = {"devices": jax.device_count(), "widths": {}}
+    out = {"devices": jax.device_count(),
+           "device_kind": jax.devices()[0].device_kind, "widths": {}}
     for m in (1, 2, 4, 8):
         if m > jax.device_count():
             continue
@@ -234,8 +240,6 @@ def bench_mesh_child(arch: str) -> dict:
             jnp.asarray(sched.keys),
             jnp.zeros(eng.config.max_batch, jnp.bool_))
         ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):               # older jax spelling
-            ca = ca[0] if ca else {}
         toks_per_call = eng.config.decode_chunk * eng.config.max_batch
         out["widths"][str(m)] = dict(
             decode_tokens_per_s=round(stats.tokens_per_s, 2),
@@ -244,29 +248,6 @@ def bench_mesh_child(arch: str) -> dict:
                 float(ca.get("bytes accessed", 0.0)) / toks_per_call),
         )
     return out
-
-
-def bench_mesh_scaling(arch: str) -> dict:
-    """Re-exec this script with 8 forced host devices (the parent process
-    must keep its single-device view) and collect the child's JSON."""
-    import os
-    import subprocess
-    import sys
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                       "src")
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        res = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--mesh-child",
-             "--arch", arch],
-            env=env, capture_output=True, text=True, timeout=1200)
-        if res.returncode != 0:
-            return {"error": (res.stderr or res.stdout)[-500:]}
-        return json.loads(res.stdout.strip().splitlines()[-1])
-    except (subprocess.TimeoutExpired, json.JSONDecodeError, OSError) as e:
-        return {"error": repr(e)}
 
 
 def _pctl(xs, q):
@@ -384,22 +365,18 @@ def run(arch: str = "olmo-1b", slo_ttft_s: float = 2.0,
                f"max_concurrent={pr['no_share']['max_concurrent']}")
 
     ms = bench_mesh_scaling(arch)
-    if "error" in ms:
-        out.append(f"mesh scaling: skipped ({ms['error'][:120]})")
-    else:
-        out.append(f"mesh scaling (8 forced host devices, 1xM model-parallel, "
-                   f"4 reqs x {MAX_NEW} new tokens; bytes from compiled "
-                   f"decode HLO cost analysis):")
-        out.append("  mesh,decode_tok_s,end_to_end_tok_s,decode_bytes_per_tok")
-        for m, row in sorted(ms["widths"].items(), key=lambda kv: int(kv[0])):
-            out.append(f"  1x{m},{row['decode_tokens_per_s']},"
-                       f"{row['end_to_end_tokens_per_s']},"
-                       f"{row['decode_bytes_per_token']}")
-        out.append("derived: host-CPU mesh widths share one physical core "
-                   "pool, so tokens/s measures sharding overhead, not "
-                   "speedup; bytes-per-token is the real signal (per-device "
-                   "weight traffic should fall as 1/M for the sharded "
-                   "projections)")
+    out.append(f"mesh scaling ({ms['devices']} x {ms['device_kind']}, 1xM "
+               f"model-parallel, 4 reqs x {MAX_NEW} new tokens; bytes from "
+               f"compiled decode HLO cost analysis):")
+    out.append("  mesh,decode_tok_s,end_to_end_tok_s,decode_bytes_per_tok")
+    for m, row in sorted(ms["widths"].items(), key=lambda kv: int(kv[0])):
+        out.append(f"  1x{m},{row['decode_tokens_per_s']},"
+                   f"{row['end_to_end_tokens_per_s']},"
+                   f"{row['decode_bytes_per_token']}")
+    out.append("derived: host-CPU mesh widths share one physical core "
+               "pool, so there tokens/s measures sharding overhead, not "
+               "speedup; bytes-per-token is the signal (per-device weight "
+               "traffic should fall as 1/M for the sharded projections)")
 
     blob = dict(
         arch=cfg.name,
@@ -439,12 +416,7 @@ def main():
                     help="time-to-first-token SLO (s) for goodput")
     ap.add_argument("--slo-itl", type=float, default=0.25,
                     help="inter-token-latency SLO (s) for goodput")
-    ap.add_argument("--mesh-child", action="store_true",
-                    help=argparse.SUPPRESS)  # internal: 8-device re-exec
     args = ap.parse_args()
-    if args.mesh_child:
-        print(json.dumps(bench_mesh_child(args.arch)))
-        return
     lines, blob = run(args.arch, slo_ttft_s=args.slo_ttft,
                       slo_itl_s=args.slo_itl)
     print("\n".join(lines))

@@ -4,12 +4,16 @@ the scanned multi-token loop, and releases its pages for the next arrival
 (common prompt prefixes share pages via the radix cache).
 
     PYTHONPATH=src python examples/serve.py --arch gemma3-4b --max-new 24
+
+``--no-reduced`` serves the published widths (a TPU's work); the default
+serves ``reduce_config(arch)``, which runs in seconds on a CPU.
 """
 import argparse
 
 import jax
 
 from repro.configs import get_config, reduce_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import (Engine, EngineConfig, bytes_tokenizer_decode,
                            bytes_tokenizer_encode)
@@ -25,6 +29,10 @@ REQUESTS = [
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve reduce_config(arch) (default); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.8)
     ap.add_argument("--batch", type=int, default=2,
@@ -43,8 +51,11 @@ def main():
                     choices=["off", "recompute", "drop"],
                     help="page-pressure policy (see EngineConfig.preemption)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = reduce_config(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduce_config(cfg)
     params = M.init(cfg, jax.random.PRNGKey(0))
     # batch of 2 for 4 requests: watch the engine recycle pages mid-flight;
     # the `with` block retires anything unfinished as CANCELLED and checks

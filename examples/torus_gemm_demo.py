@@ -1,28 +1,25 @@
 """Distributed torus-scheduled GEMM demo (paper C3 at pod scale): the FFN of
-a transformer layer computed with neighbor-only collective_permute rings on
-an 8-device mesh, validated against the dense result, with the lowered
-collective schedule printed.
+a transformer layer computed with neighbor-only collective_permute rings
+over every device of the process, validated against the dense result, with
+the lowered collective schedule printed.  On a TPU host the ring spans its
+chips; on CPU, force host devices when starting the process:
 
-    PYTHONPATH=src python examples/torus_gemm_demo.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/torus_gemm_demo.py
 """
-import os
+import re
 
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=8").strip()
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
-import re  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-from jax import shard_map  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
-from repro.core import torus  # noqa: E402
+from repro.core import torus
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("model",),
+    mesh = jax.make_mesh((jax.device_count(),), ("model",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.RandomState(0)
     B, S, D, F = 2, 64, 256, 1024

@@ -1,4 +1,4 @@
-"""BlockSpec bounds prover (rules K001-K003).
+"""BlockSpec bounds prover (rules K001-K004).
 
 Every Pallas kernel exposes its grid / index-map construction as a
 :class:`repro.kernels.spec.KernelSpec`.  The prover enumerates the full grid
@@ -15,7 +15,11 @@ K002  along the innermost grid axis, the number of DMAs (1 + index
       the same block) never exceeds the ``pl.when``-live block count: dead
       blocks must be remapped onto live indices, not merely masked;
 K003  output index maps are invariant along the declared reduction axes
-      (otherwise partial accumulator states are stored per step).
+      (otherwise partial accumulator states are stored per step);
+K004  the last two dims of every block are multiples of the TPU tile
+      ``(8, 128)`` or equal the array's dims (``block * grid_blocks``) —
+      the rule Mosaic enforces when it lowers the kernel for the chip, which
+      interpret mode never checks.
 """
 from __future__ import annotations
 
@@ -86,8 +90,25 @@ def _eval_map(op: OperandSpec, grid_ids: Sequence[np.ndarray],
     return np.stack(cols, axis=-1)
 
 
+#: Mosaic's (sublane, lane) tile of a block's last two dims
+TPU_TILE = (8, 128)
+
+
+def _untileable(op: OperandSpec) -> List[str]:
+    """K004 messages for one operand's block shape."""
+    out = []
+    dims = list(zip(op.block_shape, op.grid_blocks))[-2:]
+    for (blk, n), tile in zip(dims, TPU_TILE[-len(dims):]):
+        if blk % tile and n != 1:  # one block along the dim = the whole dim
+            arr = tuple(b * g for b, g in zip(op.block_shape, op.grid_blocks))
+            out.append(f"block {tuple(op.block_shape)} of '{op.name}' over "
+                       f"array {arr}: block dim {blk} is neither a multiple "
+                       f"of {tile} nor the whole dim {blk * n}")
+    return out
+
+
 def check_kernel_spec(spec: KernelSpec, context: str = "") -> List[Finding]:
-    """Run K001-K003 over one kernel instantiation."""
+    """Run K001-K004 over one kernel instantiation."""
     out: List[Finding] = []
     ctx = f"{context} kernel={spec.name}" if context else f"kernel={spec.name}"
     grid_ids = [ix.reshape(-1) for ix in np.indices(spec.grid)]
@@ -101,6 +122,10 @@ def check_kernel_spec(spec: KernelSpec, context: str = "") -> List[Finding]:
         if key not in seen_rules:
             seen_rules.add(key)
             out.append(Finding(rule, msg, ctx, spec.src_file, spec.src_line))
+
+    for op in spec.operands:
+        for msg in _untileable(op):
+            emit("K004", msg)
 
     for combo in combos:
         oob: List[str] = []

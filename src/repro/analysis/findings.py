@@ -37,6 +37,9 @@ RULES: Dict[str, str] = {
             "to a live index so the revisit DMA is elided)",
     "K003": "output revisit: output index map varies along a reduction grid "
             "axis (partial accumulator stores)",
+    "K004": "untileable block: one of a block's last two dims is neither a "
+            "multiple of the TPU tile (8 sublanes, 128 lanes) nor the whole "
+            "array dim (Mosaic refuses to lower the kernel)",
     "P001": "paging invariant violation (PagePool/RadixCache structural "
             "check, see paging.check_invariants)",
     "R001": "unreachable resilience branch: a FinishReason the Scheduler "

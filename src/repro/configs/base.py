@@ -109,11 +109,10 @@ class ArchConfig:
     moe_every: int = 1  # apply MoE FFN on every k-th layer (1 = all layers)
     capacity_factor: float = 1.0
     num_moe_groups: int = 1  # dispatch groups (= DP shards at scale)
-    # expert-sharded dispatch under manual shard_map: the right layout on TPU
-    # (slot buffers shard over the model axis), but the CPU XLA backend
-    # check-fails promoting the copy-combiner all-reduce its partitioner
-    # emits for auto-axis contractions inside manual regions -> default off
-    # in this container; flip on for real TPU runs.
+    # expert-sharded dispatch under manual shard_map (slot buffers shard over
+    # the model axis).  Engine turns it on by itself for a mesh whose model
+    # axis divides num_experts (expert-parallel decode); one device keeps
+    # the einsum dispatch.
     moe_shard_map: bool = False
 
     # SSM (Mamba-2 / SSD)

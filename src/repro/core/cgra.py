@@ -208,22 +208,32 @@ def simulate_transformer_layer(cfg: CGRAConfig, d_model: int, n_heads: int,
 # TPU tile-shape selection — the CGRA "mapper" generalized to the MXU.
 # ---------------------------------------------------------------------------
 
-TPU_VMEM_BYTES = 16 * 1024 * 1024  # v5e per-core VMEM
+# The VMEM one Pallas kernel may use unless its call raises the limit
+# (``vmem_limit_bytes``): Mosaic's default scoped limit on v5e.  The chip's
+# physical VMEM is larger; the scoped limit is what a kernel is refused at.
+TPU_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 MXU_DIM = 128
 
 
 def select_block_shapes(M: int, K: int, N: int, dtype_bytes: int = 2,
-                        vmem_budget: int = TPU_VMEM_BYTES // 2,
+                        out_bytes: int = 4,
+                        vmem_budget: int = TPU_SCOPED_VMEM_BYTES // 2,
                         acc_bytes: int = 4) -> tuple[int, int, int]:
     """Pick (bm, bk, bn), multiples of the MXU dim, maximizing data reuse
-    (large bm x bn output blocks) subject to double-buffered VMEM residency:
-        2*(bm*bk + bk*bn)*dtype_bytes + bm*bn*acc_bytes <= vmem_budget.
+    (large bm x bn output blocks) subject to the kernel's VMEM residency:
 
-    This is the same mapping decision the paper's Memory Controller makes for
-    the 4x4 array, scaled to VMEM/MXU. (C1/C4)
+        2*(bm*bk + bk*bn)*dtype_bytes    double-buffered A and B blocks
+      + 2*bm*bn*out_bytes                double-buffered output block
+      + 2*bm*bn*acc_bytes                accumulator + the block dot's result
+      <= vmem_budget
+
+    The budget is half the scoped limit, leaving the rest to Mosaic's own
+    scratch.  This is the same mapping decision the paper's Memory
+    Controller makes for the 4x4 array, scaled to VMEM/MXU. (C1/C4)
     """
     def fits(bm, bk, bn):
-        return 2 * (bm * bk + bk * bn) * dtype_bytes + bm * bn * acc_bytes <= vmem_budget
+        return (2 * (bm * bk + bk * bn) * dtype_bytes
+                + 2 * bm * bn * (out_bytes + acc_bytes)) <= vmem_budget
 
     def clamp(x, cap):
         return max(MXU_DIM, min(((x + MXU_DIM - 1) // MXU_DIM) * MXU_DIM, cap))

@@ -17,25 +17,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # moved out of experimental in newer jax releases
-    from jax.experimental.shard_map import shard_map
-except ImportError:
-    from jax import shard_map
-
-
-def axis_size(axis_name) -> int:
-    """Static named-axis size; ``lax.axis_size`` only exists in newer jax
-    (``psum(1, axis)`` is folded to a concrete int inside shard_map)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
 
 
 def _ring_perm(axis_name, shift=1):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return [(i, (i + shift) % n) for i in range(n)]
 
 
@@ -51,7 +38,7 @@ def ring_allgather_matmul(x_shard, w_local, axis_name="model"):
     neighbor link (overlap).  Bytes on the wire equal the all-gather, but
     every transfer is a single switchless neighbor hop.
     """
-    tp = axis_size(axis_name)
+    tp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     Tl, D = x_shard.shape
     Fl = w_local.shape[1]
@@ -77,7 +64,7 @@ def matmul_reducescatter_ring(h_full, w_local, axis_name="model"):
     gathering each device's partial GEMM for that chunk — tp-1 neighbor hops,
     each overlapped with the next partial GEMM.
     """
-    tp = axis_size(axis_name)
+    tp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     T, Fl = h_full.shape
     Tl = T // tp
@@ -100,7 +87,7 @@ def ring_allreduce(x, axis_name="model"):
     """Bidirectional-ring all-reduce via ppermute (reduce-scatter + all-gather
     on flattened chunks).  Used where we want the collective expressed as
     neighbor hops (e.g. to prove C3 schedules) rather than XLA's all-reduce."""
-    tp = axis_size(axis_name)
+    tp = lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % tp
     flat = jnp.pad(flat, (0, pad))

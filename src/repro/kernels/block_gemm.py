@@ -31,10 +31,14 @@ F32 = jnp.float32
 
 
 def gemm_spec(M: int, K: int, N: int, *, block_shape=None,
-              dtype_bytes: int = 4, int8: bool = False) -> KernelSpec:
-    """Grid/BlockSpec contract of ``block_gemm`` / ``block_gemm_int8``."""
+              dtype_bytes: int = 4, out_bytes: int = 4,
+              int8: bool = False) -> KernelSpec:
+    """Grid/BlockSpec contract of ``block_gemm`` / ``block_gemm_int8``.
+    ``dtype_bytes``/``out_bytes``: operand and output element sizes, which
+    size the blocks against the VMEM budget."""
     if block_shape is None:
-        block_shape = select_block_shapes(M, K, N, dtype_bytes=dtype_bytes)
+        block_shape = select_block_shapes(M, K, N, dtype_bytes=dtype_bytes,
+                                          out_bytes=out_bytes)
     bm, bk, bn = block_shape
     Mp, Kp, Np = (-(-M // bm)) * bm, (-(-K // bk)) * bk, (-(-N // bn)) * bn
     nm, nn, nk = Mp // bm, Np // bn, Kp // bk
@@ -99,12 +103,13 @@ def block_gemm(a, b, *, block_shape=None, out_dtype=None, interpret=False):
     Arbitrary shapes are padded up to the block grid (the CGRA handles
     ragged edges the same way: partial blocks run at lower utilization).
     """
-    out_dtype = out_dtype or a.dtype
+    out_dtype = jnp.dtype(out_dtype or a.dtype)
     M, K = a.shape
     K2, N = b.shape
     assert K == K2, (a.shape, b.shape)
     spec = gemm_spec(M, K, N, block_shape=block_shape,
-                     dtype_bytes=a.dtype.itemsize)
+                     dtype_bytes=a.dtype.itemsize,
+                     out_bytes=out_dtype.itemsize)
     bm, bk, bn = (spec.operands[0].block_shape[0],
                   spec.operands[0].block_shape[1],
                   spec.operands[1].block_shape[1])
@@ -153,7 +158,7 @@ def block_gemm_int8(a_q, b_q, a_scale, b_scale, *, block_shape=None,
     M, K = a_q.shape
     N = b_q.shape[1]
     spec = gemm_spec(M, K, N, block_shape=block_shape, dtype_bytes=1,
-                     int8=True)
+                     out_bytes=jnp.dtype(out_dtype).itemsize, int8=True)
     bm, bk, bn = (spec.operands[0].block_shape[0],
                   spec.operands[0].block_shape[1],
                   spec.operands[1].block_shape[1])

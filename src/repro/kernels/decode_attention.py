@@ -29,14 +29,15 @@ once.  This kernel is the decode-side counterpart of ``flash_attention``:
 A fully-invalid slot (``start > pos``, e.g. a drained engine slot) returns
 exact zeros, mirroring the masked-row contract of ``flash_attention``.
 
-Paged mode (``pages=``): k/v are *page pools* ``[n_pages, page_size, K, d]``
-shared by every sequence, and a scalar-prefetched per-sequence page table
-``[B, npp]`` rides alongside ``pos``/``start``.  One k-block is one page and
-the BlockSpec index map follows the table — logical block ``ik`` of slot
-``b`` streams page ``pages[b, ik]`` from the pool — so the kernel body is
-bit-for-bit the linear layout over logical rows; the indirection lives
-entirely in the index map, and the same dead-block clipping bounds HBM
-traffic by the live length.
+Paged mode (``pages=``): k/v are head-major *page pools*
+``[n_pages, K, page_size, d]`` shared by every sequence, and a
+scalar-prefetched per-sequence page table ``[B, npp]`` rides alongside
+``pos``/``start``.  One k-block is one head's page and the BlockSpec index
+map follows the table — logical block ``ik`` of slot ``b`` streams page
+``pages[b, ik]`` from the pool — so the kernel body is bit-for-bit the
+linear layout over logical rows; the indirection lives entirely in the
+index map, and the same dead-block clipping bounds HBM traffic by the live
+length.
 """
 from __future__ import annotations
 
@@ -98,7 +99,7 @@ def fd_dense_spec(B: int, H: int, K: int, S: int, dq: int, dv: int, *,
             lo = jnp.minimum(start_ref[b] // bk_, nk - 1)
             hi = jnp.minimum(pos_ref[b] // bk_, nk - 1)  # pos >= S: dropped
             ik = jnp.clip(ik, lo, hi)
-        return (b, ik, kh, 0)
+        return (b, kh, ik, 0)
 
     def block_live(b, kh, ik, pos, start):
         return _fd_block_live(ik, pos[b], start[b], bk=bk_, layout=layout)
@@ -113,8 +114,8 @@ def fd_dense_spec(B: int, H: int, K: int, S: int, dq: int, dv: int, *,
         ),
         operands=(
             OperandSpec("q", (1, 1, Gp, dq), q_map, (B, K, 1, 1)),
-            OperandSpec("k", (1, bk_, 1, dq), kv_map, (B, nk, K, 1)),
-            OperandSpec("v", (1, bk_, 1, dv), kv_map, (B, nk, K, 1)),
+            OperandSpec("k", (1, 1, bk_, dq), kv_map, (B, K, nk, 1)),
+            OperandSpec("v", (1, 1, bk_, dv), kv_map, (B, K, nk, 1)),
             OperandSpec("o", (1, 1, Gp, dv), q_map, (B, K, 1, 1),
                         is_output=True),
         ),
@@ -140,7 +141,7 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
         lo = jnp.minimum(start_ref[b] // ps, npp - 1)
         hi = jnp.minimum(pos_ref[b] // ps, npp - 1)
         ik = jnp.clip(ik, lo, hi)
-        return (pages_ref[b, ik], 0, kh, 0)
+        return (pages_ref[b, ik], kh, 0, 0)
 
     def block_live(b, kh, ik, pos, start, pages):
         return _fd_block_live(ik, pos[b], start[b], bk=ps, layout="linear")
@@ -156,8 +157,8 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
         ),
         operands=(
             OperandSpec("q", (1, 1, Gp, dq), q_map, (B, K, 1, 1)),
-            OperandSpec("k", (1, ps, 1, dq), kv_map, (n_pages, 1, K, 1)),
-            OperandSpec("v", (1, ps, 1, dv), kv_map, (n_pages, 1, K, 1)),
+            OperandSpec("k", (1, 1, ps, dq), kv_map, (n_pages, K, 1, 1)),
+            OperandSpec("v", (1, 1, ps, dv), kv_map, (n_pages, K, 1, 1)),
             OperandSpec("o", (1, 1, Gp, dv), q_map, (B, K, 1, 1),
                         is_output=True),
         ),
@@ -196,8 +197,8 @@ def _fd_kernel(pos_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(block_live)
     def _block():
         q = q_ref[0, 0]       # [Gp, dq]
-        k = k_ref[0, :, 0]    # [bk, dq]  (cache-native [B, S, K, d] layout)
-        v = v_ref[0, :, 0]    # [bk, dv]
+        k = k_ref[0, 0]       # [bk, dq]  (head-major [B|P, K, S|ps, d])
+        v = v_ref[0, 0]       # [bk, dv]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32) * scale
         if softcap:
@@ -240,13 +241,13 @@ def _fd_kernel_paged(pos_ref, start_ref, pages_ref, *args, **kw):
 
 def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
                         scale, dv: int | None, interpret: bool):
-    """q: [B, H, dq]; k/v: page pools [P, ps, K, d]; pages: [B, npp] int32
-    -> [B, H, dv].  Logical row ``r`` of slot ``b`` lives at pool row
-    ``(pages[b, r // ps], r % ps)``; validity is the linear rule over
+    """q: [B, H, dq]; k/v: head-major page pools [P, K, ps, d]; pages:
+    [B, npp] int32 -> [B, H, dv].  Logical row ``r`` of slot ``b`` lives at
+    ``pool[pages[b, r // ps], :, r % ps]``; validity is the linear rule over
     logical rows ``[start, pos]``.  ``ps`` must be a multiple of 8
     (sublane alignment — enforced by ``EngineConfig``)."""
     B, H, dq = q.shape
-    ps, K = k.shape[1], k.shape[2]
+    K, ps = k.shape[1], k.shape[2]
     npp = pages.shape[1]
     dv = dv or v.shape[-1]
     G = H // K
@@ -297,9 +298,11 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
                  dv: int | None = None, pages=None, interpret: bool = False):
     """q: [B, H, dq]; k: [B, S, K, dq]; v: [B, S, K, >=dv] -> [B, H, dv].
 
-    k/v arrive in the engine's *native* slot-cache layout ``[B, S, K, d]``
-    (seq-major) — the kernel blocks the S axis directly, so the hot path
-    never transposes or copies the cache.  ``pos``/``start``: [B] int32
+    k/v arrive in the slot-cache layout ``[B, S, K, d]`` and are moved to
+    head-major ``[B, K, S, d]`` before the kernel, whose k/v blocks are one
+    head's ``[bk, d]`` tile (Mosaic tiles the last two block dims).  The
+    serving engine decodes through the paged pools, which are stored
+    head-major and need no such copy.  ``pos``/``start``: [B] int32
     per-slot validity bounds (broadcastable scalars accepted; ``start=None``
     means every row from 0 is live).  ``layout`` selects the validity rule:
     ``"linear"`` (global attention, rows ``[start, pos]`` live) or ``"ring"``
@@ -309,8 +312,8 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
     concatenated ``[latent | k_rope]`` cache as BOTH k and v, with the
     latent (the value) being the first ``kv_lora_rank`` columns.
 
-    ``pages`` switches to the paged cache: k/v become page pools
-    ``[n_pages, page_size, K, d]`` and ``pages`` the [B, npp] page table
+    ``pages`` switches to the paged cache: k/v become head-major page pools
+    ``[n_pages, K, page_size, d]`` and ``pages`` the [B, npp] page table
     (see :func:`_flash_decode_paged`); ``layout`` must be linear/paged —
     sliding windows under paging express validity through ``start``
     (``max(0, pos - window + 1)``), not a ring.
@@ -330,12 +333,8 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
     start = (jnp.zeros((B,), jnp.int32) if start is None
              else jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,)))
     shared = v is k  # MLA dual-operand form: one cache array, two BlockSpecs
-    if k.dtype != q.dtype:  # serving caches share the compute dtype: no-op
-        k = k.astype(q.dtype)
-    if shared:
-        v = k
-    elif v.dtype != q.dtype:
-        v = v.astype(q.dtype)
+    k = k.astype(q.dtype).transpose(0, 2, 1, 3)  # [B, K, S, d]
+    v = k if shared else v.astype(q.dtype).transpose(0, 2, 1, 3)
 
     # sublane-align the per-kv-head query group; padded rows are sliced off
     # (block sizing — the largest sublane-aligned divisor of S when S % bk
@@ -345,11 +344,11 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
     spec = fd_dense_spec(B, H, K, S, dq, dv, layout=layout, bk=bk)
-    bk_ = spec.operands[1].block_shape[1]
+    bk_ = spec.operands[1].block_shape[2]
     nk = spec.grid[2]
     Sp = nk * bk_
     if Sp != S:
-        pads = ((0, 0), (0, Sp - S), (0, 0), (0, 0))
+        pads = ((0, 0), (0, 0), (0, Sp - S), (0, 0))
         k = jnp.pad(k, pads)
         v = k if shared else jnp.pad(v, pads)
 

@@ -87,7 +87,7 @@ def fa_paged_spec(B: int, H: int, K: int, C: int, d: int, ps: int, npp: int,
         hi_c = (qstart_ref[b] + (iq + 1) * bq_ - 1) // ps
         hi = jnp.clip(jnp.minimum(hi_k, hi_c), 0, npp - 1)
         ik = jnp.minimum(ik, hi)
-        return (pages_ref[b, ik], 0, h // G, 0)
+        return (pages_ref[b, ik], h // G, 0, 0)
 
     def block_live(b, h, iq, ik, qstart, klen, pages):
         return _paged_block_live(iq, ik, qstart[b], klen[b], bq=bq_, ps=ps)
@@ -103,8 +103,8 @@ def fa_paged_spec(B: int, H: int, K: int, C: int, d: int, ps: int, npp: int,
         ),
         operands=(
             OperandSpec("q", (1, 1, bq_, d), q_map, (B, H, Cp // bq_, 1)),
-            OperandSpec("k", (1, ps, 1, d), kv_map, (n_pages, 1, K, 1)),
-            OperandSpec("v", (1, ps, 1, d), kv_map, (n_pages, 1, K, 1)),
+            OperandSpec("k", (1, 1, ps, d), kv_map, (n_pages, K, 1, 1)),
+            OperandSpec("v", (1, 1, ps, d), kv_map, (n_pages, K, 1, 1)),
             OperandSpec("o", (1, 1, bq_, d), q_map, (B, H, Cp // bq_, 1),
                         is_output=True),
         ),
@@ -203,8 +203,8 @@ def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, q_ref, k_ref, v_ref,
     @pl.when(block_live)
     def _block():
         q = q_ref[0, 0]       # [bq, d]
-        k = k_ref[0, :, 0]    # [ps, d]  (pool-native [P, ps, K, d] layout)
-        v = v_ref[0, :, 0]    # [ps, d]
+        k = k_ref[0, 0]       # [ps, d]  (head-major pool [P, K, ps, d])
+        v = v_ref[0, 0]       # [ps, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32) * scale
         if softcap:
@@ -233,11 +233,11 @@ def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, q_ref, k_ref, v_ref,
 
 def _flash_attention_paged(q, k, v, pages, q_start, k_len, *, window: int,
                            softcap: float, scale, bq: int, interpret: bool):
-    """q: [B, H, C, d] query chunk; k/v: page pools [P, ps, K, d];
-    pages: [B, npp] int32 -> [B, H, C, d].
+    """q: [B, H, C, d] query chunk; k/v: head-major page pools
+    [P, K, ps, d]; pages: [B, npp] int32 -> [B, H, C, d].
 
-    Chunked-prefill attention: logical row ``r`` of slot ``b`` lives at pool
-    row ``(pages[b, r // ps], r % ps)``; query row ``i`` sits at logical
+    Chunked-prefill attention: logical row ``r`` of slot ``b`` lives at
+    ``pool[pages[b, r // ps], :, r % ps]``; query row ``i`` sits at logical
     position ``q_start[b] + i`` and rows ``[0, k_len[b])`` are valid.  One
     k-block is one page and the BlockSpec index map follows the
     scalar-prefetched table (the ``flash_decode`` paged trick): dead pages —
@@ -245,7 +245,7 @@ def _flash_attention_paged(q, k, v, pages, q_start, k_len, *, window: int,
     remapped to a live page index so the repeated-visit DMA is elided, and
     their compute is skipped in-kernel."""
     B, H, C, d = q.shape
-    ps, K = k.shape[1], k.shape[2]
+    K, ps = k.shape[1], k.shape[2]
     npp = pages.shape[1]
     scale = scale if scale is not None else d ** -0.5
     q_start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
@@ -294,8 +294,8 @@ def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
     Fully-masked rows return zeros.
 
     ``pages`` switches to the *paged past* layout for chunked prefill: k/v
-    become page pools ``[n_pages, page_size, K, d]``, ``pages`` the [B, npp]
-    page table, and ``q_start``/``k_len`` [B] give the chunk's first query
+    become head-major page pools ``[n_pages, K, page_size, d]``, ``pages``
+    the [B, npp] page table, and ``q_start``/``k_len`` [B] give the chunk's first query
     position and the valid logical row count (see
     :func:`_flash_attention_paged`).  Paged attention is causal by
     definition — the chunk continues a causal prefix."""

@@ -59,8 +59,8 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     the suffix-prefill pattern over a cached prefix).
 
     ``pages`` ([B, npp] int32) switches to the chunked-prefill *paged past*
-    layout: k/v become page pools ``[n_pages, page_size, K, d]`` and
-    ``q_start``/``k_len`` [B] place the query chunk at absolute positions
+    layout: k/v become head-major page pools ``[n_pages, K, page_size, d]``
+    and ``q_start``/``k_len`` [B] place the query chunk at absolute positions
     ``q_start + i`` attending over logical rows ``[0, k_len)``."""
     if pages is not None:
         if mode == "reference":
@@ -93,8 +93,8 @@ def attend_decode(q, k, v, pos, start=None, *,
     (LINEAR global / RING sliding-window / PAGED block-table).  ``dv``
     narrows the value read to the first dv columns — MLA latent decode
     passes its concatenated ``[latent | k_rope]`` cache as both k and v.
-    ``pages`` ([B, npp] int32) switches k/v to page pools
-    ``[n_pages, page_size, K, d]`` indirected through the table.
+    ``pages`` ([B, npp] int32) switches k/v to head-major page pools
+    ``[n_pages, K, page_size, d]`` indirected through the table.
     """
     layout = str(layout)
     if mode == "reference":

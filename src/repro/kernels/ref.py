@@ -7,6 +7,15 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
+def gather_pages(pool, pages):
+    """Dense ``[B, npp * page_size, K, d]`` view of each sequence's rows in a
+    head-major page pool ``[n_pages, K, page_size, d]``; pages: [B, npp]."""
+    B, npp = pages.shape
+    g = pool[pages]  # [B, npp, K, ps, d]
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, npp * pool.shape[2],
+                                              pool.shape[1], pool.shape[3])
+
+
 def block_gemm_ref(a, b, out_dtype=None):
     """C = A @ B with f32 accumulation."""
     out_dtype = out_dtype or a.dtype
@@ -53,9 +62,9 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None,
 def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
                               scale=None, softcap=0.0):
     """Oracle for ``flash_attention(pages=...)``: a query *chunk* attending
-    over a paged past (chunked prefill).  q: [B,H,C,d]; k/v: page pools
-    [n_pages, page_size, K, d] (H % K == 0, GQA); pages: [B, npp] int32 page
-    tables; q_start/k_len: [B] int32 — query row ``i`` of slot ``b`` sits at
+    over a paged past (chunked prefill).  q: [B,H,C,d]; k/v: head-major
+    page pools [n_pages, K, page_size, d] (H % K == 0, GQA); pages: [B, npp]
+    int32 page tables; q_start/k_len: [B] int32 — query row ``i`` of slot ``b`` sits at
     logical position ``q_start[b] + i`` and attends causally over logical
     rows ``[0, k_len[b])`` (which include the chunk's own freshly-written
     keys).  The oracle gathers each slot's pages into a dense
@@ -65,7 +74,7 @@ def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
     unspecified (the engine slices them off)."""
     pages = jnp.asarray(pages, jnp.int32)
     B, H, C, d = q.shape
-    ps, K = k.shape[1], k.shape[2]
+    K, ps = k.shape[1], k.shape[2]
     npp = pages.shape[1]
     G = H // K
     S = npp * ps
@@ -73,13 +82,8 @@ def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
     q_start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
     k_len = jnp.broadcast_to(jnp.asarray(k_len, jnp.int32), (B,))
     shared = v is k
-    kd = k[pages].reshape(B, S, K, k.shape[-1])
-    kb = jnp.repeat(kd, G, axis=2)  # [B,S,H,d]
-    if shared:
-        vb = kb
-    else:
-        vd = v[pages].reshape(B, S, K, v.shape[-1])
-        vb = jnp.repeat(vd, G, axis=2)
+    kb = jnp.repeat(gather_pages(k, pages), G, axis=2)  # [B,S,H,d]
+    vb = kb if shared else jnp.repeat(gather_pages(v, pages), G, axis=2)
     s = jnp.einsum("bhqd,bshd->bhqs", q, kb,
                    preferred_element_type=F32) * scale
     if softcap:
@@ -108,20 +112,18 @@ def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
     (MLA passes one concatenated cache as both k and v).  All-invalid slots
     return zeros.
 
-    Paged path: ``pages`` [B, npp] int32 page tables over pools k/v of shape
-    [n_pages, page_size, K, d]; logical row ``r`` of slot ``b`` lives at
-    ``(pages[b, r // page_size], r % page_size)``.  The oracle gathers each
-    slot's pages into a dense [B, npp * page_size, K, d] cache and falls
-    through to the linear rule — the page table is pure indirection, the
-    validity semantics are unchanged."""
+    Paged path: ``pages`` [B, npp] int32 page tables over head-major pools
+    k/v of shape [n_pages, K, page_size, d]; logical row ``r`` of slot ``b``
+    lives at ``(pages[b, r // page_size], :, r % page_size)``.  The oracle
+    gathers each slot's pages into a dense [B, npp * page_size, K, d] cache
+    and falls through to the linear rule — the page table is pure
+    indirection, the validity semantics are unchanged."""
     if pages is not None:
         assert layout in ("linear", "paged"), layout
         pages = jnp.asarray(pages, jnp.int32)
-        B_, npp = pages.shape
-        ps = k.shape[1]
         shared = v is k
-        k = k[pages].reshape(B_, npp * ps, *k.shape[2:])
-        v = k if shared else v[pages].reshape(B_, npp * ps, *v.shape[2:])
+        k = gather_pages(k, pages)
+        v = k if shared else gather_pages(v, pages)
         layout = "linear"
     B, H, dq = q.shape
     S, K = k.shape[1], k.shape[2]

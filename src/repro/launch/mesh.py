@@ -17,11 +17,7 @@ import math
 import numpy as np
 
 import jax
-
-try:  # older jax releases have no AxisType / axis_types kwarg
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None, axes=None):
@@ -56,18 +52,11 @@ def make_device_mesh(shape, axes, devices=None):
         raise ValueError(f"mesh shape {tuple(shape)} needs {n} devices; "
                          f"only {len(devices)} available")
     arr = np.asarray(devices[:n]).reshape(tuple(shape))
-    if AxisType is None:
-        return jax.sharding.Mesh(arr, tuple(axes))
-    try:
-        return jax.sharding.Mesh(arr, tuple(axes),
-                                 axis_types=(AxisType.Auto,) * len(axes))
-    except TypeError:  # Mesh without the axis_types kwarg
-        return jax.sharding.Mesh(arr, tuple(axes))
+    return jax.sharding.Mesh(arr, tuple(axes),
+                             axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -5,8 +5,11 @@ into pages of the shared KV pool as they free up (common prompt prefixes
 share pages through the radix cache), so the decode batch stays full without
 ever recompiling.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b \
         --requests 16 --max-new 32 --rate 4
+
+``--reduced`` (the default) serves ``reduce_config(arch)``, a CPU-sized
+model; ``--no-reduced`` serves the published widths, for a TPU.
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduce_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import Engine, EngineConfig, bytes_tokenizer_encode
 
@@ -24,7 +28,10 @@ from repro.serving import Engine, EngineConfig, bytes_tokenizer_encode
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve reduce_config(arch) (default); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.7)
@@ -75,6 +82,7 @@ def main():
                          "pool.alloc / runner.mixed faults + rare NaN "
                          "logits) to exercise the degraded paths")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduce_config(get_config(args.arch)) if args.reduced \
         else get_config(args.arch)

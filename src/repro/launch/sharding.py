@@ -145,20 +145,20 @@ def tp_size(mesh: Mesh | None = None) -> int:
     return dict(mesh.shape).get("model", 1)
 
 
-def tp_shard_map(body, mesh: Mesh, in_specs, out_specs, axis: str = "model"):
-    """Partial-manual ``shard_map`` over the tensor-parallel axis only.
+def tp_shard_map(body, mesh: Mesh, in_specs, out_specs):
+    """``shard_map`` whose specs name only the tensor-parallel ``model``
+    axis.  Used for Pallas kernels (which have no SPMD partitioning rules —
+    each shard runs the unmodified kernel on its slice) and the MoE
+    expert-parallel block.
 
-    Other mesh axes (data/pod) stay under the auto partitioner, so callers
-    can spell specs purely in terms of ``model``.  Used for Pallas kernels
-    (which have no SPMD partitioning rules — each shard runs the unmodified
-    kernel on its slice) and the MoE expert-parallel block."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-        return jax.shard_map(body, mesh=mesh, axis_names={axis},
-                             in_specs=in_specs, out_specs=out_specs)
-    from repro.core.torus import shard_map as _shmap
-    auto = frozenset(mesh.axis_names) - {axis}
-    return _shmap(body, mesh=mesh, auto=auto, check_rep=False,
-                  in_specs=in_specs, out_specs=out_specs)
+    Every mesh axis is manual: Mosaic refuses a kernel left under the auto
+    partitioner on any axis, even one of size 1, so the data axis cannot
+    stay auto; the body sees the whole batch on every data replica.
+    ``pallas_call`` declares no varying-mesh-axes type for its outputs, so
+    the check that needs one (``check_vma``) is off."""
+    return jax.shard_map(body, mesh=mesh, axis_names=set(mesh.axis_names),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def batch_pspec(mesh: Mesh, batch_dim_divisor: int = 0) -> P:
@@ -209,11 +209,9 @@ def constrain(x, axes: tuple):
     # inside a shard_map manual region the ambient abstract mesh marks some
     # axes Manual; constraints there must target that mesh with the manual
     # axes dropped from the spec (they are already local)
-    am = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-    manual = set()
-    if am is not None and am.axis_names:
-        manual = {n for n, t in zip(am.axis_names, am.axis_types)
-                  if t == jax.sharding.AxisType.Manual}
+    am = jax.sharding.get_abstract_mesh()
+    manual = {n for n, t in zip(am.axis_names, am.axis_types)
+              if t == jax.sharding.AxisType.Manual}
     if manual:
         entries = []
         for e in spec:

@@ -184,9 +184,9 @@ def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *,
                            dv: int | None = None, pages=None):
     """kernel_mode-aware single-token decode core.
 
-    Cache-native layout in, model layout out: q [B,1,H,dq], cache k/v
-    [B,S,K,d] -> [B,1,H,dv] — the kernel blocks the cache's S axis
-    directly, so the hot path never transposes or copies it.
+    q [B,1,H,dq] and a slot cache k/v [B,S,K,d] (or, with ``pages``, a
+    head-major pool [P,K,ps,d]) -> [B,1,H,dv].  The paged pool is the
+    kernel's own layout, so the serving hot path never copies it.
     ``pos``/``start`` are the per-slot [B] validity bounds (cache row of
     the current token / first live row — sliding-window layers on a linear
     or paged cache pass ``max(0, pos - window + 1)``); ``layout`` is the
@@ -207,10 +207,11 @@ def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *,
     mesh, tp = _tp_mesh(cfg)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        hs = "model" if (q0.shape[1] % tp == 0 and k.shape[-2] % tp == 0) \
+        kax = 1 if pages is not None else 2  # pool [P,K,ps,d] / [B,S,K,d]
+        hs = "model" if (q0.shape[1] % tp == 0 and k.shape[kax] % tp == 0) \
             else None
         qspec = P(None, hs, None)            # q [B, H, dq]
-        kvspec = P(None, None, hs, None)     # [B, S, K, d] or pool [P, ps, K, d]
+        kvspec = P(*(hs if i == kax else None for i in range(4)))
         body = lambda qq, kk, vv, pp, ss, pg: call(qq, kk, vv, pp, ss, pages=pg)
         o = tp_shard_map(
             body, mesh,
@@ -417,24 +418,22 @@ def attn_cache_specs(cfg: ArchConfig, batch: int, seq: int, local: bool) -> dict
 
 
 def _page_row_write(pool, new_row, pages, pos):
-    """Scatter one row per sequence into a page pool.
+    """Scatter one row per sequence into a head-major page pool.
 
-    pool: [P, ps, ...]; new_row: [B, ...]; pages: [B, npp]; pos: [B].
-    Logical row ``pos`` of sequence ``b`` lands at pool row
-    ``(pages[b, pos // ps], pos % ps)``.  Rows whose page index would fall
-    off the table are dropped, never clamped (the engine errors on
+    pool: [P, K, ps, d]; new_row: [B, K, d]; pages: [B, npp]; pos: [B].
+    Logical row ``pos`` of sequence ``b`` lands at
+    ``pool[pages[b, pos // ps], :, pos % ps]``.  Rows whose page index would
+    fall off the table are dropped, never clamped (the engine errors on
     capacity overrun before this can matter)."""
-    P, ps = pool.shape[0], pool.shape[1]
+    P, ps = pool.shape[0], pool.shape[2]
     B = new_row.shape[0]
     npp = pages.shape[1]
     ipage = pos // ps
-    flat = jnp.where(ipage < npp,
-                     pages[jnp.arange(B), jnp.minimum(ipage, npp - 1)] * ps
-                     + pos % ps,
-                     P * ps)  # out of range -> dropped by mode="drop"
-    pooled = pool.reshape(P * ps, *pool.shape[2:])
-    pooled = pooled.at[flat].set(new_row.astype(pool.dtype), mode="drop")
-    return pooled.reshape(pool.shape)
+    page = jnp.where(ipage < npp,
+                     pages[jnp.arange(B), jnp.minimum(ipage, npp - 1)],
+                     P)  # out of range -> dropped by mode="drop"
+    return pool.at[page, :, pos % ps].set(new_row.astype(pool.dtype),
+                                          mode="drop")
 
 
 def attn_prefill(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
@@ -479,27 +478,23 @@ def attn_prefill(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
 def _page_rows_write(pool, new_rows, pages, pos0, n):
     """Scatter a *chunk* of rows per sequence into a page pool.
 
-    pool: [P, ps, ...]; new_rows: [B, C, ...]; pages: [B, npp]; pos0/n: [B].
-    Chunk row ``i`` of sequence ``b`` is logical row ``pos0[b] + i`` and
-    lands at pool row ``(pages[b, r // ps], r % ps)``.  Rows at ``i >= n[b]``
-    (the chunk's padding) and rows whose page index would fall off the table
-    are dropped, never clamped — same contract as :func:`_page_row_write`."""
-    P, ps = pool.shape[0], pool.shape[1]
-    B, C = new_rows.shape[0], new_rows.shape[1]
+    pool: [P, K, ps, d]; new_rows: [B, C, K, d]; pages: [B, npp];
+    pos0/n: [B].  Chunk row ``i`` of sequence ``b`` is logical row
+    ``r = pos0[b] + i`` and lands at ``pool[pages[b, r // ps], :, r % ps]``.
+    Rows at ``i >= n[b]`` (the chunk's padding) and rows whose page index
+    would fall off the table are dropped, never clamped — same contract as
+    :func:`_page_row_write`."""
+    P, ps = pool.shape[0], pool.shape[2]
+    C = new_rows.shape[1]
     npp = pages.shape[1]
     r = pos0[:, None] + jnp.arange(C, dtype=jnp.int32)[None]  # [B, C]
     ipage = r // ps
     ok = (jnp.arange(C)[None] < n[:, None]) & (ipage < npp)
-    flat = jnp.where(
-        ok,
-        jnp.take_along_axis(pages, jnp.minimum(ipage, npp - 1), axis=1) * ps
-        + r % ps,
-        P * ps)  # out of range -> dropped by mode="drop"
-    pooled = pool.reshape(P * ps, *pool.shape[2:])
-    pooled = pooled.at[flat.reshape(-1)].set(
-        new_rows.reshape(B * C, *new_rows.shape[2:]).astype(pool.dtype),
-        mode="drop")
-    return pooled.reshape(pool.shape)
+    page = jnp.where(
+        ok, jnp.take_along_axis(pages, jnp.minimum(ipage, npp - 1), axis=1),
+        P)  # out of range -> dropped by mode="drop"
+    return pool.at[page, :, r % ps].set(new_rows.astype(pool.dtype),
+                                        mode="drop")
 
 
 def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
@@ -508,7 +503,7 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
 
     x: [B, C, D] — a size-C chunk buffer holding ``chunk_len`` valid prompt
     rows (the rest is padding); ``positions``: [C] or [B, C] absolute
-    positions (``past_len + arange(C)``); cache: page pools [P, ps, K, dh];
+    positions (``past_len + arange(C)``); cache: page pools [P, K, ps, dh];
     pages: [B, npp] page tables; ``chunk_len``: scalar or [B] int32.
 
     Writes the chunk's post-RoPE KV straight through the page table (no
@@ -538,16 +533,15 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
     mesh, tp = _tp_mesh(cfg)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        hs = "model" if (qT.shape[1] % tp == 0 and k.shape[2] % tp == 0) \
+        hs = "model" if (qT.shape[1] % tp == 0 and k.shape[1] % tp == 0) \
             else None
         body = lambda qq, kk, vv, pg, qs, kl: call(qq, kk, vv, pages=pg,
                                                    q_start=qs, k_len=kl)
+        heads = P(None, hs, None, None)  # q [B,H,C,d]; pools [P,K,ps,d]
         o = tp_shard_map(
             body, mesh,
-            (P(None, hs, None, None), P(None, None, hs, None),
-             P(None, None, hs, None), P(None, None), P(None), P(None)),
-            P(None, hs, None, None))(qT, k, v, pages, pos0,
-                                     pos0 + chunk_len)
+            (heads, heads, heads, P(None, None), P(None), P(None)),
+            heads)(qT, k, v, pages, pos0, pos0 + chunk_len)
     else:
         o = call(qT, k, v, pages=pages, q_start=pos0, k_len=pos0 + chunk_len)
     o = o.transpose(0, 2, 1, 3)
@@ -570,7 +564,7 @@ def attn_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, local: bool,
     slot ``S-1``; the serving engine refuses to decode past capacity
     (explicit length error) before this can happen.
 
-    Paged (``pages`` given): the cache is a page pool [P, ps, K, dh] shared
+    Paged (``pages`` given): the cache is a page pool [P, K, ps, dh] shared
     across the batch; the write lands at the page-table row for ``pos`` and
     attention follows the table (CacheLayout.PAGED).  Sliding-window layers
     store full rows like global ones and window via the validity lower
@@ -699,8 +693,9 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
     which is passed as *both* keys (full width, qk dim ``kvr +
     qk_rope_dim``) and values (first ``kvr`` columns, selected by the
     BlockSpec — no slicing copy).  With ``pages`` the cache is a
-    [P, ps, kvr+dr] page pool written through the per-slot table, exactly
-    as in :func:`attn_decode`.
+    [P, ps, kvr+dr] page pool (the head-major [P, 1, ps, kvr+dr] pool of one
+    latent head) written through the per-slot table, exactly as in
+    :func:`attn_decode`.
     """
     dn, dv = cfg.qk_nope_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
@@ -715,8 +710,8 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
                           -1)[:, 0]
     if pages is not None:
         pages = jnp.asarray(pages, jnp.int32)
-        kv = _page_row_write(cache["kv"], row, pages, pos)
-        kv4 = kv[:, :, None]  # [P,ps,1,kvr+dr] pool; same array as k AND v
+        kv4 = _page_row_write(cache["kv"][:, None], row[:, None], pages, pos)
+        kv = kv4[:, 0]  # kv4 [P,1,ps,kvr+dr] pool: same array as k AND v
     else:
         bidx = jnp.arange(B)
         kv = cache["kv"].at[bidx, pos].set(row.astype(cache["kv"].dtype),

@@ -211,23 +211,27 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int):
 def paged_cache_specs(cfg: ArchConfig, max_batch: int, n_pages: int,
                       page_size: int, main_repeats: int | None = None) -> list:
     """Paged decode-cache spec tree: every ``kv_seq`` leaf becomes a page
-    *pool* ``[n_pages, page_size, ...]`` shared across sequences (per-sequence
-    page tables map logical rows to pool pages; page 0 is the engine's
-    reserved trash page).  Sliding-window layers get full-size pages like
-    global ones — under paging they window via the decode validity bound,
-    not a ring.  Leaves without a ``kv_seq`` axis (SSM state, cross-attn
-    image KV) stay slot-indexed ``[max_batch, ...]``."""
+    *pool* shared across sequences (per-sequence page tables map logical
+    rows to pool pages; page 0 is the engine's reserved trash page).  Pools
+    are head-major, ``[n_pages, K, page_size, d]`` for attention KV, so one
+    Pallas block is one head's ``[page_size, d]`` tile; MLA's headless
+    latent pool is ``[n_pages, page_size, d]``.  Sliding-window layers get
+    full-size pages like global ones — under paging they window via the
+    decode validity bound, not a ring.  Leaves without a ``kv_seq`` axis
+    (SSM state, cross-attn image KV) stay slot-indexed ``[max_batch, ...]``."""
     specs = cache_specs(cfg, max_batch, page_size, main_repeats)
 
     def to_pool(spec):
         if "kv_seq" not in spec.axes:
             return spec
-        b = spec.axes.index("batch")
-        s = spec.axes.index("kv_seq")
-        shape = list(spec.shape)
-        shape[b], shape[s] = n_pages, page_size  # window rings un-shrunk
-        axes = list(spec.axes)
+        axes, shape = list(spec.axes), list(spec.shape)
+        b, s = axes.index("batch"), axes.index("kv_seq")
         axes[b] = None  # the pool's page axis is not a batch axis
+        shape[b], shape[s] = n_pages, page_size  # window rings un-shrunk
+        if "kv_heads" in axes:  # [.., kv_seq, kv_heads, d] -> head-major
+            h = axes.index("kv_heads")
+            axes.insert(s, axes.pop(h))
+            shape.insert(s, shape.pop(h))
         return ParamSpec(tuple(shape), tuple(axes), "zeros", spec.dtype)
 
     return jax.tree.map(to_pool, specs, is_leaf=lambda x: isinstance(x, ParamSpec))

@@ -50,8 +50,8 @@ the exact-length whole-prompt path used by non-decomposable mixers
 (SSM / MLA / cross-attention), which cannot chunk.
 
 Cache layout (``EngineConfig.cache_spec()``, ``CacheLayout.PAGED``): every
-attention layer owns a ``[n_pages, page_size, ...]`` page pool allocated up
-front via ``model.paged_cache_specs``; each live sequence holds a page
+attention layer owns a head-major ``[n_pages, K, page_size, d]`` page pool
+allocated up front via ``model.paged_cache_specs``; each live sequence holds a page
 *table* (``[pages_per_seq]`` int32, shared logically across all layers —
 pages are allocated in lockstep) mapping logical KV rows to pool pages.
 Page 0 is the reserved *trash page*: retired batch rows keep their table
@@ -419,25 +419,21 @@ class ModelRunner:
 
     # -- exact-length whole-prompt prefill (non-decomposable mixers) ------
 
-    def _flat_rows(self, table, first: int, n: int):
-        """Pool-row indices of logical rows ``[first, first + n)``."""
-        j = jnp.arange(n, dtype=jnp.int32) + first
-        return table[j // self.page_size] * self.page_size + j % self.page_size
-
     def _scatter_new(self, caches, small, table, slot, n: int):
         """Write a whole-prompt prefill's outputs into the big cache: kv_seq
         leaves scatter their ``n`` rows to logical rows ``[0, n)`` through
         the page table; stateful leaves (SSM state, cross image-KV)
         overwrite batch row ``slot``."""
-        rows = self._flat_rows(table, 0, n)
+        j = jnp.arange(n, dtype=jnp.int32)
+        page, off = table[j // self.page_size], j % self.page_size
 
         def w(spec, pool, sm):
-            if "kv_seq" in spec.axes:
-                R, P, ps = pool.shape[0], pool.shape[1], pool.shape[2]
-                flat = pool.reshape(R, P * ps, *pool.shape[3:])
-                flat = flat.at[:, rows].set(sm[:, 0].astype(pool.dtype))
-                return flat.reshape(pool.shape)
-            return pool.at[:, slot].set(sm[:, 0].astype(pool.dtype))
+            rows = sm[:, 0].astype(pool.dtype)  # [R, n, (K,) d]
+            if "kv_seq" not in spec.axes:
+                return pool.at[:, slot].set(rows)
+            if "kv_heads" in spec.axes:  # head-major pool [R, P, K, ps, d]
+                return pool.at[:, page, :, off].set(jnp.moveaxis(rows, 1, 0))
+            return pool.at[:, page, off].set(rows)  # MLA pool [R, P, ps, d]
 
         return jax.tree.map(w, self.cache_specs, caches, small,
                             is_leaf=is_spec)

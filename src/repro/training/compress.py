@@ -30,8 +30,7 @@ def compressed_mean(g, axis_name: str, err=None):
     amax = lax.pmax(jnp.max(jnp.abs(gf)), axis_name)
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
-    from repro.core.torus import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     allq = lax.all_gather(q, axis_name)  # [n, ...] int8 on the wire
     mean = (jnp.sum(allq.astype(jnp.int32), axis=0).astype(F32) * scale) / n
     new_err = gf - q.astype(F32) * scale if err is not None else None

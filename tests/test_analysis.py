@@ -102,7 +102,7 @@ def test_j004_fires_on_large_baked_constant():
 
 
 def test_j005_fires_on_f64_leak():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         def bad(x):
             return x.astype(jnp.float64) * 2.0
 
@@ -239,7 +239,7 @@ def test_k001_fires_on_unclamped_table_read():
     spec = KernelSpec(
         "mut", (2, 4), scalars=(POS, PAGES),
         operands=(OperandSpec(
-            "kv", (1, 8), lambda b, ik, pos_ref, pages_ref:
+            "kv", (8, 128), lambda b, ik, pos_ref, pages_ref:
             (pages_ref[b, pos_ref[b] // 8], ik), (9, 4)),))
     fs = check_kernel_spec(spec)
     assert rules_of(fs) == {"K001"}
@@ -249,7 +249,7 @@ def test_k001_fires_on_unclamped_table_read():
 def test_k001_fires_on_oob_block_index():
     spec = KernelSpec(
         "mut", (2, 4), scalars=(),
-        operands=(OperandSpec("kv", (1, 8), lambda b, ik: (b, ik + 1),
+        operands=(OperandSpec("kv", (8, 128), lambda b, ik: (b, ik + 1),
                               (2, 4)),))
     assert rules_of(check_kernel_spec(spec)) == {"K001"}
 
@@ -258,7 +258,7 @@ def test_k002_fires_on_masked_but_not_remapped_blocks():
     # identity map + gating predicate: dead blocks still DMA
     spec = KernelSpec(
         "mut", (2, 4), scalars=(POS,),
-        operands=(OperandSpec("kv", (1, 8), lambda b, ik, pos: (b, ik),
+        operands=(OperandSpec("kv", (8, 128), lambda b, ik, pos: (b, ik),
                               (2, 4)),),
         block_live=lambda b, ik, pos: ik * 8 <= pos[b])
     assert rules_of(check_kernel_spec(spec)) == {"K002"}
@@ -267,7 +267,7 @@ def test_k002_fires_on_masked_but_not_remapped_blocks():
 def test_k003_fires_on_output_varying_along_reduction():
     spec = KernelSpec(
         "mut", (2, 4), scalars=(),
-        operands=(OperandSpec("o", (1, 8), lambda i, k: (i, k), (2, 4),
+        operands=(OperandSpec("o", (8, 128), lambda i, k: (i, k), (2, 4),
                               is_output=True),),
         reduction_axes=(1,))
     assert rules_of(check_kernel_spec(spec)) == {"K003"}
@@ -311,6 +311,52 @@ def test_paged_kv_map_oob_without_clamp():
         if op.name in ("k", "v") else op
         for op in spec.operands))
     assert "K002" in rules_of(check_kernel_spec(mutated))
+
+
+def _seed_seq_major(spec, kv_map):
+    """The paged spec as it shipped with a sequence-major pool
+    ``[n_pages, ps, K, d]``: k/v blocks ``(1, ps, 1, d)`` — one head picked
+    by a second-to-last block dim of 1 over K."""
+    def mutate(op):
+        if op.name not in ("k", "v"):
+            return op
+        _, _, ps, d = op.block_shape
+        n_pages, K = op.grid_blocks[0], op.grid_blocks[1]
+        return dataclasses.replace(op, block_shape=(1, ps, 1, d),
+                                   grid_blocks=(n_pages, 1, K, 1),
+                                   index_map=kv_map)
+    return dataclasses.replace(spec, operands=tuple(map(mutate,
+                                                        spec.operands)))
+
+
+@pytest.mark.parametrize("kernel", ["flash_decode", "flash_attention"])
+def test_k004_fires_on_seq_major_paged_blocks(kernel):
+    """Mutation: the sequence-major paged layout Mosaic refused to lower
+    for MHA/GQA (K > 1) — the checker must catch it on the CPU."""
+    from repro.kernels.decode_attention import fd_paged_spec
+    from repro.kernels.flash_attention import fa_paged_spec
+
+    if kernel == "flash_decode":
+        spec = _seed_seq_major(
+            fd_paged_spec(2, 4, 2, 128, 128, 16, 4, 9),
+            lambda b, kh, ik, pos, start, pages: (pages[b, 0], 0, kh, 0))
+    else:
+        spec = _seed_seq_major(
+            fa_paged_spec(2, 4, 2, 32, 128, 16, 4, 9),
+            lambda b, h, iq, ik, qs, kl, pages: (pages[b, 0], 0, h // 2, 0))
+    fs = [f for f in check_kernel_spec(spec) if f.rule == "K004"]
+    assert {f.message.split("'")[1] for f in fs} == {"k", "v"}
+
+
+def test_k004_fires_on_unaligned_gemm_block_and_accepts_full_dims():
+    from repro.kernels.block_gemm import gemm_spec
+
+    assert "K004" in rules_of(check_kernel_spec(
+        gemm_spec(256, 256, 256, block_shape=(100, 128, 128))))
+    # a ragged block equal to the whole (padded) dim is legal
+    full = KernelSpec("full", (1,), scalars=(), operands=(OperandSpec(
+        "x", (5, 24), lambda i: (0, 0), (1, 1)),))
+    assert check_kernel_spec(full) == []
 
 
 # ---------------------------------------------------------------------------

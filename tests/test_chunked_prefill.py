@@ -76,8 +76,8 @@ def _rand_paged(seed=0, B=2, H=4, K=2, C=16, ps=16, npp=3, d=16):
     rng = np.random.RandomState(seed)
     P = 1 + B * npp  # page 0 reserved
     q = rng.randn(B, H, C, d).astype(np.float32)
-    kp = rng.randn(P, ps, K, d).astype(np.float32)
-    vp = rng.randn(P, ps, K, d).astype(np.float32)
+    kp = rng.randn(P, K, ps, d).astype(np.float32)  # head-major pools
+    vp = rng.randn(P, K, ps, d).astype(np.float32)
     pages = np.zeros((B, npp), np.int32)
     for b in range(B):
         pages[b] = 1 + b * npp + rng.permutation(npp)
@@ -96,12 +96,13 @@ def test_paged_prefill_oracle_matches_dense(window, softcap):
     out = ref.flash_attention_paged_ref(q, kp, vp, pages, q_start, k_len,
                                         window=window, softcap=softcap)
     B, H, C, d = q.shape
-    G = H // kp.shape[2]
+    G = H // kp.shape[1]
     for b in range(B):
-        kd = kp[pages[b]].reshape(-1, *kp.shape[2:])[: int(k_len[b])]
-        vd = vp[pages[b]].reshape(-1, *vp.shape[2:])[: int(k_len[b])]
-        kb = jnp.repeat(kd.transpose(1, 0, 2), G, axis=0)[None]
-        vb = jnp.repeat(vd.transpose(1, 0, 2), G, axis=0)[None]
+        # [npp, K, ps, d] -> [K, npp * ps, d]: the sequence's rows per head
+        kd = kp[pages[b]].transpose(1, 0, 2, 3).reshape(kp.shape[1], -1, d)
+        vd = vp[pages[b]].transpose(1, 0, 2, 3).reshape(vp.shape[1], -1, d)
+        kb = jnp.repeat(kd[:, : int(k_len[b])], G, axis=0)[None]
+        vb = jnp.repeat(vd[:, : int(k_len[b])], G, axis=0)[None]
         dense = ref.flash_attention_ref(q[b: b + 1], kb, vb, causal=True,
                                         window=window, softcap=softcap)
         np.testing.assert_allclose(out[b], dense[0], atol=2e-5, rtol=2e-5)

@@ -4,8 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 from _prop import given, settings, st  # hypothesis, or deterministic fallback
 
-from repro.core.cgra import (CGRAConfig, MXU_DIM, select_block_shapes,
-                             simulate_gemm, simulate_transformer_layer)
+from repro.core.cgra import (CGRAConfig, MXU_DIM, TPU_SCOPED_VMEM_BYTES,
+                             select_block_shapes, simulate_gemm,
+                             simulate_transformer_layer)
 from repro.core.quant import compress_grad, dequantize, quantize
 
 
@@ -52,9 +53,12 @@ def test_ultra_low_power_class():
 @settings(max_examples=20, deadline=None)
 @given(m=st.integers(16, 2048), k=st.integers(16, 2048), n=st.integers(16, 2048))
 def test_prop_tile_mapper_fits_vmem(m, k, n):
-    bm, bk, bn = select_block_shapes(m, k, n, dtype_bytes=2)
+    bm, bk, bn = select_block_shapes(m, k, n, dtype_bytes=2, out_bytes=2)
     assert bm % MXU_DIM == bk % MXU_DIM == bn % MXU_DIM == 0
     assert 2 * (bm * bk + bk * bn) * 2 + bm * bn * 4 <= 8 * 1024 * 1024
+    # the double-buffered bf16 output block and the f32 dot result count too
+    assert (2 * (bm * bk + bk * bn) * 2 + 2 * bm * bn * (2 + 4)
+            <= TPU_SCOPED_VMEM_BYTES // 2)
 
 
 @settings(max_examples=20, deadline=None)

@@ -197,16 +197,17 @@ def test_radix_clear_releases_tree_refs():
 # ---------------------------------------------------------------------------
 
 def _paged_fixture(B=3, H=4, K=2, P=9, ps=8, d=16, dv=None, seed=3):
-    """Page pools + tables + the equivalent dense [B, S, K, d] caches."""
+    """Head-major page pools [P, K, ps, d] + tables + the equivalent dense
+    [B, S, K, d] caches."""
     rng = np.random.RandomState(seed)
     dv = dv or d
     npp = (P - 1) // B  # pages per sequence (page 0 reserved)
-    kp = rng.randn(P, ps, K, d).astype(np.float32) * 0.3
-    vp = rng.randn(P, ps, K, dv).astype(np.float32) * 0.3
+    kp = rng.randn(P, K, ps, d).astype(np.float32) * 0.3
+    vp = rng.randn(P, K, ps, dv).astype(np.float32) * 0.3
     # non-trivial tables: sequence b owns a scattered set of pages
     perm = rng.permutation(np.arange(1, P))[: B * npp].reshape(B, npp)
-    kd = kp[perm].reshape(B, npp * ps, K, d)
-    vd = vp[perm].reshape(B, npp * ps, K, dv)
+    kd = kp[perm].transpose(0, 1, 3, 2, 4).reshape(B, npp * ps, K, d)
+    vd = vp[perm].transpose(0, 1, 3, 2, 4).reshape(B, npp * ps, K, dv)
     q = jnp.asarray(rng.randn(B, H, d) * 0.3, jnp.float32)
     pos = jnp.asarray(rng.randint(0, npp * ps, B), jnp.int32)
     return (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(perm, jnp.int32),
@@ -255,7 +256,7 @@ def test_paged_kernel_mla_fused_operand():
     B, H, P, ps, kvr, dr = 2, 8, 7, 8, 32, 16
     rng = np.random.RandomState(9)
     q = jnp.asarray(rng.randn(B, H, kvr + dr) * 0.3, jnp.float32)
-    kv = jnp.asarray(rng.randn(P, ps, 1, kvr + dr) * 0.3, jnp.float32)
+    kv = jnp.asarray(rng.randn(P, 1, ps, kvr + dr) * 0.3, jnp.float32)
     tbl = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
     pos = jnp.asarray([7, 20], jnp.int32)
     got = flash_decode(q, kv, kv, pos, None, pages=tbl, scale=0.13, dv=kvr,
@@ -273,15 +274,15 @@ def test_paged_kernel_empty_and_fresh_slots():
     B, H, K, P, ps, d = 2, 4, 2, 5, 8, 16
     rng = np.random.RandomState(13)
     q = jnp.asarray(rng.randn(B, H, d) * 0.3, jnp.float32)
-    kp = jnp.asarray(rng.randn(P, ps, K, d) * 0.3, jnp.float32)
-    vp = jnp.asarray(rng.randn(P, ps, K, d) * 0.3, jnp.float32)
+    kp = jnp.asarray(rng.randn(P, K, ps, d) * 0.3, jnp.float32)
+    vp = jnp.asarray(rng.randn(P, K, ps, d) * 0.3, jnp.float32)
     tbl = jnp.asarray([[0, 0], [1, 2]], jnp.int32)
     pos = jnp.asarray([0, 0], jnp.int32)
     start = jnp.asarray([1, 0], jnp.int32)  # slot 0: start > pos -> empty
     got = flash_decode(q, kp, vp, pos, start, pages=tbl, interpret=True)
     assert np.all(np.asarray(got[0]) == 0.0)
     G = H // K
-    want1 = np.asarray(vp[1, 0])  # [K, d]: page 1, row 0
+    want1 = np.asarray(vp[1, :, 0])  # [K, d]: page 1, row 0
     np.testing.assert_allclose(np.asarray(got[1]).reshape(K, G, d),
                                np.broadcast_to(want1[:, None], (K, G, d)),
                                atol=2e-6)

@@ -16,10 +16,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax moved it to the top level
-        from jax import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import torus
     from repro.launch.mesh import make_mesh

@@ -1,0 +1,101 @@
+"""Compile guards: the serving path's Pallas kernels at olmo-1b widths,
+lowered and compiled for a described (not attached) TPU v5e by the TPU
+compiler installed beside JAX.
+
+Interpret-mode parity cannot show that Mosaic accepts a kernel's block
+shapes or its VMEM use; these compiles can, in about a second each, with no
+chip.  The topology is described inside a fixture, never at import: only
+one process may load the TPU library at a time, and the test workers all
+import this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.block_gemm import block_gemm, block_gemm_int8
+from repro.kernels.decode_attention import flash_decode
+from repro.kernels.flash_attention import flash_attention
+
+# olmo-1b (configs/olmo_1b.py) and the engine's paged pool around it
+D, H, K, DH, FF = 2048, 16, 16, 128, 8192
+B, MAX_LEN = 8, 1024
+# EngineConfig allows any multiple of 8 rows per page; 64 is its default
+PAGES = [8, 16, 64]
+BF16, I32, F32, I8 = jnp.bfloat16, jnp.int32, jnp.float32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back without one
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        cc.reset_cache()
+
+
+def _compiled_hlo(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _pool(page):
+    """Head-major page pool of a full batch, plus the trash page, and the
+    pages per sequence."""
+    npp = MAX_LEN // page
+    return ((B * npp + 1, K, page, DH), BF16), npp
+
+
+@pytest.mark.parametrize("page", PAGES)
+def test_flash_decode_paged(one_chip, page):
+    pool, npp = _pool(page)
+    hlo = _compiled_hlo(
+        one_chip,
+        lambda q, k, v, pos, pages: flash_decode(q, k, v, pos, pages=pages),
+        ((B, H, DH), BF16), pool, pool, ((B,), I32), ((B, npp), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention_dense(one_chip):
+    hlo = _compiled_hlo(one_chip, flash_attention, ((1, H, 512, DH), BF16),
+                        ((1, K, 512, DH), BF16), ((1, K, 512, DH), BF16))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("page", PAGES)
+def test_flash_attention_paged_past(one_chip, page):
+    C = 256  # one chunked-prefill chunk over the paged past
+    pool, npp = _pool(page)
+    hlo = _compiled_hlo(
+        one_chip,
+        lambda q, k, v, pages, qs, kl: flash_attention(
+            q, k, v, pages=pages, q_start=qs, k_len=kl),
+        ((1, H, C, DH), BF16), pool, pool, ((1, npp), I32), ((1,), I32),
+        ((1,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+# a decode batch, a long prefill chunk, a whole 2048-token prefill
+@pytest.mark.parametrize("M", [8, 512, 2048])
+def test_block_gemm(one_chip, M):
+    hlo = _compiled_hlo(one_chip, block_gemm, ((M, D), BF16), ((D, FF), BF16))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("M", [512, 2048])  # 512 once overran scoped VMEM
+def test_block_gemm_int8(one_chip, M):
+    hlo = _compiled_hlo(one_chip, block_gemm_int8, ((M, D), I8),
+                        ((D, FF), I8), ((M, 1), F32), ((1, FF), F32))
+    assert "tpu_custom_call" in hlo
