@@ -75,7 +75,7 @@ def main():
                for tail in ("keyword spotting", "wake word")]
     out, stats = eng.generate(prompts, max_new=8)
     print(f"served {len(out)} requests ({stats.tokens_out} tokens, "
-          f"{stats.tokens_per_s:.1f} tok/s decode, "
+          f"{stats.tokens_per_s:.1f} tok/s of compiled calls, "
           f"prefix_hit={eng.prefix_hit_rate:.0%}, "
           f"pages_used={eng.pool.num_used}/{eng.pool.n_pages - 1})")
 

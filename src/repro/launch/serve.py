@@ -132,13 +132,22 @@ def main():
     lat = sorted(r.latency_s for r in ok) or [0.0]
     p50 = lat[len(lat) // 2]
     p99 = lat[min(len(lat) - 1, int(len(lat) * 0.99))]
+    wait = sorted(r.admit_s - r.arrival_s for r in results
+                  if r.admit_s is not None) or [0.0]
+    w50 = wait[len(wait) // 2]
+    w90 = wait[min(len(wait) - 1, int(len(wait) * 0.9))]
+    hits = [r.prefix_hit for r in results if r.prefix_hit is not None]
+    ticks = stats.mixed_steps + stats.chunks
     print(f"arch={cfg.name} kernel_mode={eng.cfg.kernel_mode} "
           f"quant={eng.cfg.quant} requests={len(results)} ok={len(ok)} "
           f"batch={args.batch} pages={eng.pool.n_pages} "
           f"prefill={stats.prefill_s:.2f}s decode={stats.decode_s:.2f}s "
           f"throughput={stats.tokens_per_s:.1f} tok/s "
           f"prefix_hit={eng.prefix_hit_rate:.0%} "
-          f"p50={p50:.2f}s p99={p99:.2f}s")
+          f"p50={p50:.2f}s p99={p99:.2f}s "
+          f"queue_wait_p50={w50:.3f}s queue_wait_p90={w90:.3f}s "
+          f"host={1e3 * stats.host_s / max(ticks, 1):.2f}ms/tick "
+          f"prefix_rows/req={sum(hits) / max(len(hits), 1):.1f}")
     if (stats.preempted or stats.rejected or stats.deadline_expired
             or stats.cancelled or stats.faults_isolated):
         print(f"degraded: preempted={stats.preempted} "

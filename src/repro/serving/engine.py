@@ -102,6 +102,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core import round_up
@@ -158,6 +159,10 @@ class Request:
     first_token_s: float | None = None
     token_times: list[float] = field(default_factory=list)
     preemptions: int = 0
+    #: engine clock at the first admission (a preempted request keeps it)
+    admit_s: float | None = None
+    #: prompt rows the radix cache supplied at that admission
+    prefix_hit: int | None = None
 
     def full_prompt(self) -> list[int]:
         """Rows to prefill: the prompt plus any tokens generated before a
@@ -182,6 +187,11 @@ class RequestResult:
     #: backpressure hint on REJECTED results: seconds after which a retry
     #: plausibly finds queue room (estimated from in-flight progress)
     retry_after_s: float | None = None
+    #: engine clock at the first admission; ``None`` for a request that
+    #: never left the queue
+    admit_s: float | None = None
+    #: prompt rows the radix cache supplied at that admission
+    prefix_hit: int | None = None
 
     @property
     def ok(self) -> bool:
@@ -209,8 +219,15 @@ class RequestResult:
 
 @dataclass
 class ServeStats:
+    #: host seconds from dispatch to sync of the mixed steps / decode chunks
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    #: host seconds ``Engine.step`` spends outside its tick's dispatch and
+    #: sync: the ``engine.schedule`` and ``engine.emit`` spans, the latter
+    #: with the mixed step's read of its chunk's flag and first token (a
+    #: non-decomposable mixer's whole-prompt prefill runs inside admission,
+    #: so it counts here as well as in ``prefill_s``)
+    host_s: float = 0.0
     tokens_out: int = 0
     prefills: int = 0
     chunks: int = 0
@@ -227,12 +244,11 @@ class ServeStats:
 
     @property
     def tokens_per_s(self) -> float:
-        return self.tokens_out / self.decode_s if self.decode_s else 0.0
-
-    @property
-    def prefix_hit_rate(self) -> float:
-        return (self.prefix_hit_tokens / self.prefix_lookup_tokens
-                if self.prefix_lookup_tokens else 0.0)
+        """Tokens emitted per second of compiled calls: mixed steps emit
+        tokens too, so both ``prefill_s`` and ``decode_s`` count (host time
+        outside the calls does not)."""
+        busy = self.prefill_s + self.decode_s
+        return self.tokens_out / busy if busy else 0.0
 
 
 QUEUED = "queued"
@@ -250,6 +266,19 @@ class _Slot:
     seq: int = 0           # admission order (FIFO chunk scheduling)
     key: Any = None        # request PRNG key until the first sample commits
     token_times: list[float] = field(default_factory=list)
+
+
+@dataclass
+class _Tick:
+    """One compiled call as the schedule phase leaves it: the executable,
+    its host inputs after ``(params, caches)``, ``remaining`` at tick start,
+    the ``engine.dispatch`` span's arguments, and the mixed step's prompt
+    chunk ``(slot, rows)`` (``None`` for a decode chunk)."""
+    fn: Any
+    inputs: tuple
+    before: np.ndarray
+    span: dict
+    chunk: tuple[int, int] | None = None
 
 
 _LEGACY_KWARGS = ("max_len", "max_slots", "prefill_bucket", "decode_chunk",
@@ -591,7 +620,8 @@ class Scheduler:
         return RequestResult(
             req.rid, req.prompt, list(req.resume_tokens), req.arrival_s,
             req.first_token_s if req.first_token_s is not None else now,
-            now, token_times_s=list(req.token_times), finish_reason=reason)
+            now, token_times_s=list(req.token_times), finish_reason=reason,
+            admit_s=req.admit_s, prefix_hit=req.prefix_hit)
 
     def expire(self, now: float, stats: ServeStats):
         """Retire every request whose deadline has passed — queued requests
@@ -782,6 +812,8 @@ class Scheduler:
             self.queue.popleft()
             i = free_rows.pop(0)
             s = m.tokens  # cached prefix length (<= plen - 1)
+            if req.admit_s is None:  # a readmission keeps the first record
+                req.admit_s, req.prefix_hit = self.clock(), s
             shared = list(m.full_pages)  # pins transfer to slot ownership
             table = np.zeros(self.npp, np.int32)
             table[: len(shared)] = shared
@@ -888,7 +920,8 @@ class Scheduler:
         self.finished.append(RequestResult(
             s.req.rid, s.req.prompt, s.emitted, s.req.arrival_s,
             s.first_token_s, now, token_times_s=list(s.token_times),
-            finish_reason=reason))
+            finish_reason=reason, admit_s=s.req.admit_s,
+            prefix_hit=s.req.prefix_hit))
         self.slots[i] = None
         for pid in self.owned[i]:
             self.pool.decref(pid)  # radix-held pages survive at rc >= 1
@@ -1211,10 +1244,10 @@ class Engine:
                 chunk_nan = True
         return nanmask, chunk_nan
 
-    def _mixed_tick(self, i: int, n: int):
-        """Run the unified mixed step: ``n`` prompt rows of prefilling slot
-        ``i`` plus one decode step for every decoding slot."""
-        sched, runner = self.sched, self.runner
+    def _mixed_tick(self, i: int, n: int) -> _Tick:
+        """Prepare the unified mixed step: ``n`` prompt rows of prefilling
+        slot ``i`` plus one decode step for every decoding slot."""
+        sched = self.sched
         if self.chaos is not None and self.chaos.fire("runner.mixed"):
             # pre-dispatch: no host or device state touched yet, so the
             # tick can simply be skipped and retried next step
@@ -1229,62 +1262,87 @@ class Engine:
         sched.check_capacity(1, self.stats)
         dec_pages = sched.pages.copy()
         dec_pages[i] = 0  # prefilling slot's frozen decode row -> trash page
-        before = sched.remaining.copy()
         nanmask, chunk_nan = self._nan_targets()
-        t0 = time.time()
-        (runner.caches, tok0, key1, chunk_ok, cur, pos, remaining, keys,
-         toks, oks) = \
-            runner.mixed_fn(C, self.max_prefill_variants)(
-                runner.params, runner.caches, jnp.asarray(buf),
-                jnp.asarray(sched.pages[i: i + 1]), jnp.int32(slot.offset),
-                jnp.int32(n), jnp.float32(slot.req.temperature),
-                jnp.asarray(slot.key), jnp.asarray(chunk_nan),
-                jnp.asarray(dec_pages),
-                jnp.asarray(sched.cur), jnp.asarray(sched.pos),
-                jnp.asarray(sched.remaining), jnp.asarray(sched.temp),
-                jnp.asarray(sched.keys), jnp.asarray(nanmask))
-        toks, oks = np.asarray(toks), np.asarray(oks)
-        sched.cur, sched.pos = np.array(cur), np.array(pos)
-        sched.remaining, sched.keys = np.array(remaining), np.array(keys)
-        self.stats.prefill_s += time.time() - t0
-        self.stats.mixed_steps += 1
-        now = self._now()
-        self._emit(toks, oks, before, now)
-        if not bool(chunk_ok):
-            # poisoned prompt chunk: isolate the prefilling request (its
-            # pages were never published to the radix tree)
-            self.stats.faults_isolated += 1
-            sched.retire(i, now, FinishReason.FAULT)
-            return
-        slot.offset += n
-        if slot.offset == len(full):
-            sched.commit_prefill(i, int(tok0), key1, now, self.stats)
+        return self._tick(
+            self.runner.mixed_fn(C, self.max_prefill_variants),
+            (buf, sched.pages[i: i + 1], np.int32(slot.offset), np.int32(n),
+             np.float32(slot.req.temperature), slot.key, np.bool_(chunk_nan),
+             dec_pages, sched.cur, sched.pos, sched.remaining, sched.temp,
+             sched.keys, nanmask),
+            chunk=(i, n), kind="mixed", rid=slot.req.rid, past=slot.offset,
+            rows=n)
 
-    def _decode_tick(self):
-        """Run one fused decode chunk (no prefill work pending)."""
-        sched, runner = self.sched, self.runner
+    def _decode_tick(self) -> _Tick | None:
+        """Prepare one fused decode chunk (no prefill work pending);
+        ``None`` when every slot was preempted while growing."""
+        sched = self.sched
         if self.chaos is not None and self.chaos.fire("runner.mixed"):
             raise ChaosError("injected decode-chunk failure")
         if sched.lazy:
             sched.grow_for_decode(self.decode_chunk, self.stats)
         sched.check_capacity(self.decode_chunk, self.stats)
         if not sched.num_active:
-            return  # every slot was preempted while growing
-        before = sched.remaining.copy()
+            return None
         nanmask, _ = self._nan_targets()
+        return self._tick(
+            self.runner.decode_fn,
+            (sched.pages, sched.cur, sched.pos, sched.remaining, sched.temp,
+             sched.keys, nanmask), kind="decode")
+
+    def _tick(self, fn, inputs: tuple, chunk=None, **span) -> _Tick:
+        """A :class:`_Tick` whose dispatch span also counts the decoding
+        slots."""
+        span["slots"] = sum(s is not None and s.phase == DECODING
+                            for s in self.sched.slots)
+        return _Tick(fn, inputs, self.sched.remaining.copy(), span, chunk)
+
+    def _call(self, tick: _Tick) -> tuple[float, tuple]:
+        """Dispatch the tick's executable and read its results back.
+        Returns the host seconds of both (the runner's ``prefill_s`` /
+        ``decode_s``) and the tokens, their fault flags and the chunk's
+        sampled token, key and fault flag, left on the device (empty for a
+        decode chunk): :meth:`_finish` reads the flag, and the token and
+        key only when the chunk completes its prompt."""
+        sched, runner = self.sched, self.runner
         t0 = time.time()
-        (runner.caches, cur, pos, remaining, keys, toks, oks) = \
-            runner.decode_fn(
-                runner.params, runner.caches, jnp.asarray(sched.pages),
-                jnp.asarray(sched.cur), jnp.asarray(sched.pos),
-                jnp.asarray(sched.remaining), jnp.asarray(sched.temp),
-                jnp.asarray(sched.keys), jnp.asarray(nanmask))
-        toks, oks = np.asarray(toks), np.asarray(oks)
-        sched.cur, sched.pos = np.array(cur), np.array(pos)
-        sched.remaining, sched.keys = np.array(remaining), np.array(keys)
-        self.stats.decode_s += time.time() - t0
-        self.stats.chunks += 1
-        self._emit(toks, oks, before, self._now())
+        with TraceAnnotation("engine.dispatch", **tick.span):
+            runner.caches, *out = tick.fn(
+                runner.params, runner.caches,
+                *(jnp.asarray(x) for x in tick.inputs))
+        with TraceAnnotation("engine.sync"):
+            *chunk_out, cur, pos, remaining, keys, toks, oks = out
+            toks, oks = np.asarray(toks), np.asarray(oks)
+            sched.cur, sched.pos = np.array(cur), np.array(pos)
+            sched.remaining, sched.keys = np.array(remaining), np.array(keys)
+        dt = time.time() - t0
+        if tick.chunk is None:
+            self.stats.decode_s += dt
+            self.stats.chunks += 1
+        else:
+            self.stats.prefill_s += dt
+            self.stats.mixed_steps += 1
+        return dt, (toks, oks, chunk_out)
+
+    def _finish(self, tick: _Tick, toks, oks, chunk_out):
+        """Credit the tick's tokens; on a mixed step, advance the prompt
+        and commit it when the chunk completed it."""
+        sched = self.sched
+        now = self._now()
+        self._emit(toks, oks, tick.before, now)
+        if tick.chunk is None:
+            return
+        i, n = tick.chunk
+        tok0, key1, chunk_ok = chunk_out
+        if not bool(chunk_ok):
+            # poisoned prompt chunk: isolate the prefilling request (its
+            # pages were never published to the radix tree)
+            self.stats.faults_isolated += 1
+            sched.retire(i, now, FinishReason.FAULT)
+            return
+        slot = sched.slots[i]
+        slot.offset += n
+        if slot.offset == len(slot.req.full_prompt()):
+            sched.commit_prefill(i, int(tok0), key1, now, self.stats)
 
     def _emit(self, toks, oks, before, now: float):
         """Credit decoded tokens to their slots and retire finished ones.
@@ -1314,13 +1372,10 @@ class Engine:
             elif self.sched.remaining[i] == 0:
                 self.sched.retire(i, now)
 
-    def step(self) -> list[RequestResult]:
-        """One scheduling iteration: expire deadlines, admit, then run
-        either the unified mixed step (prompt chunk + one decode step each)
-        or a fused decode-only chunk.  Returns newly finished requests
-        (including rejected/cancelled/expired ones)."""
-        if self._closed:
-            raise RuntimeError("engine is closed; create a new Engine")
+    def _schedule(self) -> _Tick | None:
+        """Expire deadlines, admit, and prepare the tick: the unified mixed
+        step while a prompt is prefilling, else a fused decode-only chunk;
+        ``None`` when there is nothing to run."""
         sched = self.sched
         if self.chaos is not None:
             self.chaos.fire("clock.skew")  # may advance the injected clock
@@ -1330,15 +1385,38 @@ class Engine:
         try:
             nc = sched.next_chunk()
             if nc is not None:
-                self._mixed_tick(*nc)
-            elif self.num_active:
-                self._decode_tick()
+                return self._mixed_tick(*nc)
+            if self.num_active:
+                return self._decode_tick()
         except ChaosError:
             pass  # injected transient tick failure: nothing dispatched; retry
+        return None
+
+    def step(self) -> list[RequestResult]:
+        """One scheduling iteration, in phases the profiler sees as spans:
+        ``engine.schedule`` (:meth:`_schedule`), ``engine.dispatch`` and
+        ``engine.sync`` (the compiled call and its results read back), and
+        ``engine.emit`` (tokens credited, a completed prompt committed and
+        published to the radix tree, finished slots retired).  The host
+        time outside dispatch and sync adds to ``ServeStats.host_s``.
+        Returns newly finished requests (including rejected/cancelled/
+        expired ones)."""
+        if self._closed:
+            raise RuntimeError("engine is closed; create a new Engine")
+        sched = self.sched
+        t0 = time.time()
+        with TraceAnnotation("engine.schedule"):
+            tick = self._schedule()
+        busy = 0.0
+        if tick is not None:
+            busy, read = self._call(tick)
+            with TraceAnnotation("engine.emit"):
+                self._finish(tick, *read)
         if self.radix is not None:
             self.stats.prefix_hit_tokens = self.radix.hit_tokens
             self.stats.prefix_lookup_tokens = self.radix.lookup_tokens
         out, sched.finished = sched.finished, []
+        self.stats.host_s += time.time() - t0 - busy
         return out
 
     def run(self) -> list[RequestResult]:

@@ -68,6 +68,16 @@ def test_flash_decode_paged(one_chip, page):
     assert "tpu_custom_call" in hlo
 
 
+def test_flash_decode_dense(one_chip):
+    """The slot-cache decode (``flash_decode_<layout>``), for callers of
+    ``attend_decode`` without a page table."""
+    hlo = _compiled_hlo(
+        one_chip, lambda q, k, v, pos: flash_decode(q, k, v, pos),
+        ((B, H, DH), BF16), ((B, MAX_LEN, K, DH), BF16),
+        ((B, MAX_LEN, K, DH), BF16), ((B,), I32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_flash_attention_dense(one_chip):
     hlo = _compiled_hlo(one_chip, flash_attention, ((1, H, 512, DH), BF16),
                         ((1, K, 512, DH), BF16), ((1, K, 512, DH), BF16))
@@ -99,3 +109,4 @@ def test_block_gemm_int8(one_chip, M):
     hlo = _compiled_hlo(one_chip, block_gemm_int8, ((M, D), I8),
                         ((D, FF), I8), ((M, 1), F32), ((1, FF), F32))
     assert "tpu_custom_call" in hlo
+
