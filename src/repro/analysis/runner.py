@@ -262,7 +262,16 @@ def check_kernels(name: str, report: Report) -> None:
             if cfg.window_size:
                 specs.append(fd_dense_spec(_B, H, K, cfg.window_size, d, d,
                                            layout="ring"))
-            specs.append(fd_paged_spec(_B, H, K, d, d, ps, npp, n_pages))
+            # the paged decode's head block is sized from the pool's dtype;
+            # MLA decodes one fused [latent | rope] pool, read as v to kvr
+            isz = jnp.dtype(cfg.compute_dtype).itemsize
+            if cfg.use_mla:
+                dq = cfg.kv_lora_rank + cfg.qk_rope_dim
+                specs.append(fd_paged_spec(_B, H, 1, dq, cfg.kv_lora_rank,
+                                           ps, npp, n_pages, itemsize=isz))
+            else:
+                specs.append(fd_paged_spec(_B, H, K, d, d, ps, npp, n_pages,
+                                           itemsize=isz))
     for spec in specs:
         report.extend(check_kernel_spec(spec, ctx))
         report.checked.append(f"{ctx} kernel={spec.name}")

@@ -22,9 +22,9 @@ once.  This kernel is the decode-side counterpart of ``flash_attention``:
   ``pos`` in-kernel (entry ``j`` holds row ``pos - ((pos - j) mod S)``), so
   wrapped caches need no reordering in HBM;
 - GQA folds the G query heads that share a kv head into the sublane axis
-  (one [G, d] x [d, bk] MXU call per block — no KV broadcast), and the
-  qk/v head dims may differ (MLA's latent-space decode: qk = kvr + rope,
-  v = kvr).
+  (one [G, d] x [d, bk] MXU call per kv head of a block, batched over the
+  block's heads — no KV broadcast), and the qk/v head dims may differ
+  (MLA's latent-space decode: qk = kvr + rope, v = kvr).
 
 A fully-invalid slot (``start > pos``, e.g. a drained engine slot) returns
 exact zeros, mirroring the masked-row contract of ``flash_attention``.
@@ -32,9 +32,12 @@ exact zeros, mirroring the masked-row contract of ``flash_attention``.
 Paged mode (``pages=``): k/v are head-major *page pools*
 ``[n_pages, K, page_size, d]`` shared by every sequence, and a
 scalar-prefetched per-sequence page table ``[B, npp]`` rides alongside
-``pos``/``start``.  One k-block is one head's page and the BlockSpec index
-map follows the table — logical block ``ik`` of slot ``b`` streams page
-``pages[b, ik]`` from the pool — so the kernel body is bit-for-bit the
+``pos``/``start``.  One k-block is one page of ``Kb`` heads — all the KV
+heads the kernel sees (the local ones under tensor parallelism) unless their
+double-buffered blocks would pass :data:`KV_VMEM_BUDGET` — so a grid step
+streams a whole page of a slot and scores it with head-batched dots.  The
+BlockSpec index map follows the table — logical block ``ik`` of slot ``b``
+streams page ``pages[b, ik]`` from the pool — so the kernel body is the
 linear layout over logical rows; the indirection lives entirely in the
 index map, and the same dead-block clipping bounds HBM traffic by the live
 length.
@@ -53,6 +56,8 @@ from repro.kernels.spec import KernelSpec, OperandSpec, ScalarSpec, provenance
 
 F32 = jnp.float32
 NEG = -1e30
+#: VMEM bytes the paged kernel's double-buffered k and v blocks may take
+KV_VMEM_BUDGET = 8 << 20
 
 
 def _fd_block_live(ik, p_b, s_b, *, bk: int, layout: str):
@@ -125,12 +130,25 @@ def fd_dense_spec(B: int, H: int, K: int, S: int, dq: int, dv: int, *,
     )
 
 
+def fd_paged_heads(K: int, dq: int, dv: int, ps: int, itemsize: int) -> int:
+    """KV heads per block of the paged kernel: all ``K`` unless the
+    double-buffered k and v blocks would pass :data:`KV_VMEM_BUDGET`, else
+    the largest divisor of ``K`` that fits."""
+    per_head = 2 * ps * (dq + dv) * itemsize
+    return max([kb for kb in range(1, K + 1)
+                if K % kb == 0 and kb * per_head <= KV_VMEM_BUDGET] or [1])
+
+
 def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
-                  npp: int, n_pages: int) -> KernelSpec:
-    """Grid/BlockSpec contract of the paged ``flash_decode`` kernel."""
+                  npp: int, n_pages: int, itemsize: int = 2) -> KernelSpec:
+    """Grid/BlockSpec contract of the paged ``flash_decode`` kernel: one
+    grid step per (slot, head block, logical page), a head block being
+    :func:`fd_paged_heads` of the ``K`` heads."""
     G = H // K
     Gp = round_up(G, 8)
     S = npp * ps
+    Kb = fd_paged_heads(K, dq, dv, ps, itemsize)
+    nh = K // Kb
 
     def q_map(b, kh, ik, *_):
         return (b, kh, 0, 0)
@@ -149,17 +167,17 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
     src_file, src_line = provenance(kv_map)
     return KernelSpec(
         name="flash_decode_paged",
-        grid=(B, K, npp),
+        grid=(B, nh, npp),
         scalars=(
             ScalarSpec("pos", (B,), 0, S),
             ScalarSpec("start", (B,), 0, S),
             ScalarSpec("pages", (B, npp), 0, n_pages - 1),
         ),
         operands=(
-            OperandSpec("q", (1, 1, Gp, dq), q_map, (B, K, 1, 1)),
-            OperandSpec("k", (1, 1, ps, dq), kv_map, (n_pages, K, 1, 1)),
-            OperandSpec("v", (1, 1, ps, dv), kv_map, (n_pages, K, 1, 1)),
-            OperandSpec("o", (1, 1, Gp, dv), q_map, (B, K, 1, 1),
+            OperandSpec("q", (1, Kb, Gp, dq), q_map, (B, nh, 1, 1)),
+            OperandSpec("k", (1, Kb, ps, dq), kv_map, (n_pages, nh, 1, 1)),
+            OperandSpec("v", (1, Kb, ps, dv), kv_map, (n_pages, nh, 1, 1)),
+            OperandSpec("o", (1, Kb, Gp, dv), q_map, (B, nh, 1, 1),
                         is_output=True),
         ),
         block_live=block_live,
@@ -171,11 +189,14 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
 def _fd_kernel(pos_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                m_ref, l_ref, acc_ref, *, nk: int, bk: int, S: int,
                layout: str, softcap: float, scale: float):
-    """One (batch-slot, kv-head, k-block) grid step.
+    """One (batch-slot, kv-head block, k-block) grid step.
 
-    ``S`` is the unpadded cache capacity; rows ``>= S`` are grid padding.
-    ``pos_ref``/``start_ref`` are the scalar-prefetched per-slot validity
-    bounds (cache row of the current token / first live row).
+    Blocks carry a leading head axis of ``Kb`` heads (one in the dense
+    kernel) scored with head-batched dots; the ``m``/``l``/``acc`` scratch
+    is per head.  ``S`` is the unpadded cache capacity; rows ``>= S`` are
+    grid padding.  ``pos_ref``/``start_ref`` are the scalar-prefetched
+    per-slot validity bounds (cache row of the current token / first live
+    row).
     """
     b = pl.program_id(0)
     ik = pl.program_id(2)
@@ -196,14 +217,14 @@ def _fd_kernel(pos_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(block_live)
     def _block():
-        q = q_ref[0, 0]       # [Gp, dq]
-        k = k_ref[0, 0]       # [bk, dq]  (head-major [B|P, K, S|ps, d])
-        v = v_ref[0, 0]       # [bk, dv]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        q = q_ref[0]       # [Kb, Gp, dq]
+        k = k_ref[0]       # [Kb, bk, dq]  (head-major [B|P, K, S|ps, d])
+        v = v_ref[0]       # [Kb, bk, dv]
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=F32) * scale
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        j = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        j = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         if layout == "ring":
             # entry j holds absolute row pos - ((pos - j) mod S): the last S
             # writes, with entry (pos mod S) freshly holding row pos
@@ -222,14 +243,15 @@ def _fd_kernel(pos_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.where(m_new > NEG * 0.5, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=F32)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=F32)
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _store():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _fd_kernel_paged(pos_ref, start_ref, pages_ref, *args, **kw):
@@ -268,7 +290,9 @@ def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
     qg = q.reshape(B, K, G, dq)
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    spec = fd_paged_spec(B, H, K, dq, dv, ps, npp, k.shape[0])
+    spec = fd_paged_spec(B, H, K, dq, dv, ps, npp, k.shape[0],
+                         itemsize=q.dtype.itemsize)
+    Kb = spec.operands[1].block_shape[1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # pos, start, pages
@@ -278,9 +302,9 @@ def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
         out_specs=pl.BlockSpec(spec.outputs[0].block_shape,
                                spec.outputs[0].index_map),
         scratch_shapes=[
-            pltpu.VMEM((Gp, 1), F32),
-            pltpu.VMEM((Gp, 1), F32),
-            pltpu.VMEM((Gp, dv), F32),
+            pltpu.VMEM((Kb, Gp, 1), F32),
+            pltpu.VMEM((Kb, Gp, 1), F32),
+            pltpu.VMEM((Kb, Gp, dv), F32),
         ],
     )
     out = pl.pallas_call(
@@ -360,9 +384,9 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
         out_specs=pl.BlockSpec(spec.outputs[0].block_shape,
                                spec.outputs[0].index_map),
         scratch_shapes=[
-            pltpu.VMEM((Gp, 1), F32),
-            pltpu.VMEM((Gp, 1), F32),
-            pltpu.VMEM((Gp, dv), F32),
+            pltpu.VMEM((1, Gp, 1), F32),
+            pltpu.VMEM((1, Gp, 1), F32),
+            pltpu.VMEM((1, Gp, dv), F32),
         ],
     )
     out = pl.pallas_call(

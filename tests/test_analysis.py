@@ -320,8 +320,8 @@ def _seed_seq_major(spec, kv_map):
     def mutate(op):
         if op.name not in ("k", "v"):
             return op
-        _, _, ps, d = op.block_shape
-        n_pages, K = op.grid_blocks[0], op.grid_blocks[1]
+        _, kb, ps, d = op.block_shape
+        n_pages, K = op.grid_blocks[0], op.grid_blocks[1] * kb
         return dataclasses.replace(op, block_shape=(1, ps, 1, d),
                                    grid_blocks=(n_pages, 1, K, 1),
                                    index_map=kv_map)

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels import decode_attention
 from repro.kernels.decode_attention import flash_decode
 from repro.serving import PagePool, RadixCache
 
@@ -224,17 +225,84 @@ def test_paged_ref_equals_dense_linear():
                                atol=1e-6, rtol=1e-6)
 
 
-@pytest.mark.parametrize("softcap,with_start", [(0.0, False), (25.0, True)])
-def test_paged_kernel_matches_oracle(softcap, with_start):
-    q, kp, vp, tbl, _, _, pos = _paged_fixture()
-    start = (jnp.minimum(pos, jnp.asarray([3, 0, 11], jnp.int32))
-             if with_start else None)
+def _slot_mix(K, G, ps, dq, dv, seed):
+    """Five slots over head-major pools [P, K, ps, d] of ``npp = 3`` pages
+    each: a drained slot (trash page, ``start > pos``), a windowed slot
+    (``start > 0``, the window across a page boundary), a slot at capacity
+    (``pos == S - 1``), a frozen slot on the trash page (``pos == S``) and
+    a plain one.  ``dv < dq`` is MLA's form: one fused pool as k and v."""
+    B, npp = 5, 3
+    S, P = npp * ps, 1 + 3 * npp
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(B, K * G, dq) * 0.3, jnp.float32)
+    kp = jnp.asarray(rng.randn(P, K, ps, dq) * 0.3, jnp.float32)
+    vp = kp if dv < dq else jnp.asarray(rng.randn(P, K, ps, dq) * 0.3,
+                                        jnp.float32)
+    live = rng.permutation(np.arange(1, P)).reshape(3, npp)
+    tbl = jnp.asarray(np.stack([np.zeros(npp), live[0], live[1],
+                                np.zeros(npp), live[2]]), jnp.int32)
+    w = ps + 5
+    pw = S - ps - 3
+    pos = jnp.asarray([0, pw, S - 1, S, int(rng.randint(0, S))], jnp.int32)
+    start = jnp.asarray([1, pw - w + 1, 0, 0, 0], jnp.int32)
+    return q, kp, vp, tbl, pos, start
+
+
+# (K, G, page rows, qk dim, v dim): MLA's latent pool (K = 1, v narrowed),
+# GQA, MHA; each at pages of 8, 16 and 64 rows
+_HEADS = [(1, 4, 48, 32), (4, 4, 16, 16), (8, 8, 16, 16), (16, 1, 16, 16)]
+_CASES = [pytest.param(0.0, False, None, id="0.0-False"),
+          pytest.param(25.0, True, None, id="25.0-True")] + [
+    pytest.param(25.0 if K == 16 else 0.0, True, (K, G, ps, dq, dv),
+                 id=f"K{K}-G{G}-ps{ps}" + ("-mla" if dv < dq else ""))
+    for K, G, dq, dv in _HEADS for ps in (8, 16, 64)]
+
+
+@pytest.mark.parametrize("softcap,with_start,case", _CASES)
+def test_paged_kernel_matches_oracle(softcap, with_start, case):
+    if case is None:
+        q, kp, vp, tbl, _, _, pos = _paged_fixture()
+        start = (jnp.minimum(pos, jnp.asarray([3, 0, 11], jnp.int32))
+                 if with_start else None)
+        dv = None
+    else:
+        K, G, ps, dq, dv = case
+        q, kp, vp, tbl, pos, start = _slot_mix(K, G, ps, dq, dv, seed=K + ps)
     want = ref.flash_decode_ref(q, kp, vp, pos, start, pages=tbl,
-                                softcap=softcap)
+                                softcap=softcap, dv=dv)
     got = flash_decode(q, kp, vp, pos, start, pages=tbl, softcap=softcap,
-                       interpret=True)
+                       dv=dv, interpret=True)
+    if case is not None:
+        assert np.all(np.asarray(got[0]) == 0.0)  # drained: exact zeros
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("budget_heads", [1, 2, 4])
+def test_paged_kernel_head_blocks(monkeypatch, budget_heads):
+    """A VMEM budget that holds fewer than all K heads splits them into
+    blocks of the largest divisor that fits; the result is unchanged."""
+    K, G, ps, d = 8, 2, 16, 16
+    monkeypatch.setattr(decode_attention, "KV_VMEM_BUDGET",
+                        budget_heads * 2 * ps * 2 * d * 4)
+    spec = decode_attention.fd_paged_spec(5, K * G, K, d, d, ps, 3, 10,
+                                          itemsize=4)
+    assert spec.grid == (5, K // budget_heads, 3)
+    assert spec.operands[1].block_shape == (1, budget_heads, ps, d)
+    q, kp, vp, tbl, pos, start = _slot_mix(K, G, ps, d, d, seed=21)
+    want = ref.flash_decode_ref(q, kp, vp, pos, start, pages=tbl)
+    got = flash_decode(q, kp, vp, pos, start, pages=tbl, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+
+
+def test_paged_heads_largest_divisor_in_budget():
+    fit = decode_attention.fd_paged_heads
+    assert fit(16, 128, 128, 64, 2) == 16  # olmo-1b at 64-row bf16 pages
+    # 12 heads, a budget of 5 heads' blocks: 4 is the largest divisor
+    big = decode_attention.KV_VMEM_BUDGET // 5 // (2 * 2 * 2)
+    assert fit(12, big, big, 1, 2) == 4
+    assert fit(7, big, big, 1, 2) == 1  # no divisor but 1 fits
 
 
 def test_paged_kernel_window_via_start():

@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.block_gemm import block_gemm, block_gemm_int8
-from repro.kernels.decode_attention import flash_decode
+from repro.kernels.decode_attention import fd_paged_spec, flash_decode
 from repro.kernels.flash_attention import flash_attention
 
 # olmo-1b (configs/olmo_1b.py) and the engine's paged pool around it
@@ -60,7 +60,11 @@ def _pool(page):
 
 @pytest.mark.parametrize("page", PAGES)
 def test_flash_decode_paged(one_chip, page):
+    """One grid step per (slot, page) streams the page of all K heads."""
     pool, npp = _pool(page)
+    spec = fd_paged_spec(B, H, K, DH, DH, page, npp, pool[0][0])
+    assert spec.grid == (B, 1, npp)
+    assert [op.block_shape for op in spec.inputs[1:]] == [(1, K, page, DH)] * 2
     hlo = _compiled_hlo(
         one_chip,
         lambda q, k, v, pos, pages: flash_decode(q, k, v, pos, pages=pages),
