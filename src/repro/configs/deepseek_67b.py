@@ -1,6 +1,11 @@
 """deepseek-67b — dense llama-arch GQA.
 
-[arXiv:2401.02954; hf] 95L d_model=8192 64H (GQA kv=8) d_ff=22016 vocab=102400.
+Follows DeepSeek LLM 67B Base's config.json
+(https://huggingface.co/deepseek-ai/deepseek-llm-67b-base; arXiv:2401.02954):
+95L d_model=8192 64H (GQA kv=8) d_ff=22016 vocab=102400, RMSNorm eps 1e-6,
+RoPE theta 10000, 4096 positions, untied head.  The benchmarked cut (16
+layers as one pipeline stage, 4-way tensor parallel on one four-chip host)
+is ``bench/configs/deepseek-67b-tp4.json``.
 """
 from repro.configs.base import ArchConfig
 

@@ -138,6 +138,7 @@ def main():
     w90 = wait[min(len(wait) - 1, int(len(wait) * 0.9))]
     hits = [r.prefix_hit for r in results if r.prefix_hit is not None]
     ticks = stats.mixed_steps + stats.chunks
+    mesh = eng.config.mesh
     print(f"arch={cfg.name} kernel_mode={eng.cfg.kernel_mode} "
           f"quant={eng.cfg.quant} requests={len(results)} ok={len(ok)} "
           f"batch={args.batch} pages={eng.pool.n_pages} "
@@ -147,7 +148,11 @@ def main():
           f"p50={p50:.2f}s p99={p99:.2f}s "
           f"queue_wait_p50={w50:.3f}s queue_wait_p90={w90:.3f}s "
           f"host={1e3 * stats.host_s / max(ticks, 1):.2f}ms/tick "
-          f"prefix_rows/req={sum(hits) / max(len(hits), 1):.1f}")
+          f"prefix_rows/req={sum(hits) / max(len(hits), 1):.1f} "
+          f"mesh={f'{mesh.data}x{mesh.model}' if mesh else 'none'} "
+          f"tp={eng.runner.tp} "
+          f"collective={stats.collective_bytes / 1e6 / max(ticks, 1):.3f}"
+          f"MB/tick")
     if (stats.preempted or stats.rejected or stats.deadline_expired
             or stats.cancelled or stats.faults_isolated):
         print(f"degraded: preempted={stats.preempted} "

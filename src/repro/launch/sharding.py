@@ -161,6 +161,41 @@ def tp_shard_map(body, mesh: Mesh, in_specs, out_specs):
                          check_vma=False)
 
 
+def psum_bytes(closed) -> int:
+    """Bytes per device that the ``psum`` all-reduces of a traced program
+    carry (``jitted.trace(*args).jaxpr``): the size of each ``psum``'s
+    result inside the manual regions, where shapes are per device, times
+    the trip count of every ``scan`` around it.  For a dense model these
+    are the f32 sums after the row-parallel GEMMs (``wo``, ``w_down``).
+    A ``while`` body counts once and a ``cond`` its largest branch;
+    collectives that XLA's partitioner adds outside ``shard_map`` are not
+    in the jaxpr and are not counted."""
+    def walk(jaxpr) -> int:
+        total = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "psum":
+                total += sum(v.aval.size * v.aval.dtype.itemsize
+                             for v in eqn.outvars)
+                continue
+            subs = []
+            for val in eqn.params.values():
+                for item in (val if isinstance(val, (tuple, list)) else [val]):
+                    if hasattr(item, "consts") and hasattr(item, "jaxpr"):
+                        subs.append(item.jaxpr)  # ClosedJaxpr
+                    elif hasattr(item, "eqns"):
+                        subs.append(item)        # open Jaxpr
+            inner = [walk(s) for s in subs]
+            if eqn.primitive.name == "cond":
+                total += max(inner, default=0)
+            elif eqn.primitive.name == "scan":
+                total += int(eqn.params["length"]) * sum(inner)
+            else:
+                total += sum(inner)
+        return total
+
+    return walk(closed.jaxpr)
+
+
 def batch_pspec(mesh: Mesh, batch_dim_divisor: int = 0) -> P:
     axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
     return P(axes if len(axes) > 1 else (axes[0] if axes else None))

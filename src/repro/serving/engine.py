@@ -92,6 +92,7 @@ from __future__ import annotations
 import functools
 import time
 import warnings
+import weakref
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from enum import Enum
@@ -106,7 +107,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core import round_up
-from repro.launch.sharding import activation_mesh, tree_pspecs
+from repro.launch.sharding import activation_mesh, psum_bytes, tree_pspecs
 from repro.models import model as M
 from repro.models.params import is_spec
 from repro.serving.chaos import ChaosError, ChaosInjector
@@ -228,6 +229,11 @@ class ServeStats:
     #: non-decomposable mixer's whole-prompt prefill runs inside admission,
     #: so it counts here as well as in ``prefill_s``)
     host_s: float = 0.0
+    #: bytes per device that the all-reduces of the dispatched mixed steps
+    #: and decode chunks carried, each call counted from the shapes its
+    #: executable was traced with (``launch.sharding.psum_bytes``); 0
+    #: without a mesh
+    collective_bytes: int = 0
     tokens_out: int = 0
     prefills: int = 0
     chunks: int = 0
@@ -309,24 +315,45 @@ class ModelRunner:
         self.mesh = (config.mesh.build()
                      if config.mesh is not None and config.mesh.size > 1
                      else None)
+        #: the mesh's model (tensor-parallel) axis; 1 without a mesh
+        self.tp = 1
         if self.mesh is not None:
             params = M.shard_params(cfg, params, self.mesh)
+            self.tp = dict(self.mesh.shape)["model"]
         self.params = params
         self.cache_specs = M.paged_cache_specs(cfg, config.max_batch,
                                                config.n_pages,
                                                config.page_size)
-        self.caches = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype or cfg.compute_dtype),
-            self.cache_specs, is_leaf=is_spec)
+
+        def zeros(spec, sharding=None):
+            return jnp.zeros(spec.shape, spec.dtype or cfg.compute_dtype,
+                             device=sharding)
+
+        # under a mesh each device allocates only its shard: a whole pool
+        # made on one device first would hold the model's full KV there
+        trees = [self.cache_specs]
         if self.mesh is not None:
-            self.caches = jax.tree.map(
-                jax.device_put, self.caches,
-                tree_pspecs(self.cache_specs, self.mesh))
+            trees.append(tree_pspecs(self.cache_specs, self.mesh))
+        self.caches = jax.tree.map(zeros, *trees, is_leaf=is_spec)
         self.decode_fn = jax.jit(self._traced(self._decode_chunk),
                                  donate_argnums=(1,))
         self.copy_fn = jax.jit(self._traced(self._copy_page),
                                donate_argnums=(0,))
         self.fns: OrderedDict[tuple, Any] = OrderedDict()
+        # weak keys: an executable the LRU above evicts is not kept alive
+        self._psum_bytes: weakref.WeakKeyDictionary = (
+            weakref.WeakKeyDictionary())
+
+    def psum_bytes(self, fn, args) -> int:
+        """Bytes per device that one call of the jitted ``fn`` on ``args``
+        all-reduces, counted once per executable from the jaxpr its first
+        call traces (``jit``'s trace cache then serves the call itself)."""
+        if self.mesh is None:
+            return 0
+        n = self._psum_bytes.get(fn)
+        if n is None:
+            n = self._psum_bytes[fn] = psum_bytes(fn.trace(*args).jaxpr)
+        return n
 
     def _traced(self, fn):
         """Trace-time mesh context: the model's ``constrain`` calls (and the
@@ -1294,6 +1321,7 @@ class Engine:
         slots."""
         span["slots"] = sum(s is not None and s.phase == DECODING
                             for s in self.sched.slots)
+        span["tp"] = self.runner.tp
         return _Tick(fn, inputs, self.sched.remaining.copy(), span, chunk)
 
     def _call(self, tick: _Tick) -> tuple[float, tuple]:
@@ -1306,9 +1334,10 @@ class Engine:
         sched, runner = self.sched, self.runner
         t0 = time.time()
         with TraceAnnotation("engine.dispatch", **tick.span):
-            runner.caches, *out = tick.fn(
-                runner.params, runner.caches,
-                *(jnp.asarray(x) for x in tick.inputs))
+            args = (runner.params, runner.caches,
+                    *(jnp.asarray(x) for x in tick.inputs))
+            self.stats.collective_bytes += runner.psum_bytes(tick.fn, args)
+            runner.caches, *out = tick.fn(*args)
         with TraceAnnotation("engine.sync"):
             *chunk_out, cur, pos, remaining, keys, toks, oks = out
             toks, oks = np.asarray(toks), np.asarray(oks)

@@ -117,6 +117,7 @@ def test_every_tick_holds_its_four_phases_in_order(traced):
         kinds.append(args["kind"])
         assert args["kind"] == ("mixed" if mixed else "decode")
         assert "slots" in args
+        assert args["tp"] == 1  # no mesh: no tensor parallelism
         if mixed:
             assert {"rid", "past", "rows"} <= set(args)
     assert {"mixed", "decode"} <= set(kinds)

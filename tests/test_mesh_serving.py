@@ -17,6 +17,7 @@ import textwrap
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.serving import EngineConfig, MeshSpec
 
@@ -200,3 +201,28 @@ def test_mesh_spec_build_single_device_ok():
     # a 1x1 spec builds on any host — the degenerate mesh used by tests
     mesh = MeshSpec(1, 1).build()
     assert dict(mesh.shape) == {"data": 1, "model": 1}
+
+
+def test_psum_bytes_counts_scan_trips_and_the_larger_branch():
+    """Per-device result bytes of every ``psum`` in the manual regions:
+    a scan of 3 around one f32 [8, 16] sum, then a ``cond`` whose larger
+    branch sums twice."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.sharding import psum_bytes, tp_shard_map
+
+    mesh = MeshSpec(1, 1).build()
+    row = tp_shard_map(lambda x, w: lax.psum(x @ w, "model"), mesh,
+                       (P(None, "model"), P("model", None)), P())
+
+    def step(x, w, flag):
+        def body(c, _):
+            return c + row(x, w), None
+        c, _ = lax.scan(body, jnp.zeros((8, 16)), None, length=3)
+        return lax.cond(flag, lambda c: c + row(x, w) + row(x, w),
+                        lambda c: c + row(x, w), c)
+
+    x, w = jnp.ones((8, 32)), jnp.ones((32, 16))
+    traced = jax.jit(step).trace(x, w, True)
+    assert psum_bytes(traced.jaxpr) == (3 + 2) * 8 * 16 * 4

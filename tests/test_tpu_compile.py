@@ -114,3 +114,45 @@ def test_block_gemm_int8(one_chip, M):
                         ((D, FF), I8), ((M, 1), F32), ((1, FF), F32))
     assert "tpu_custom_call" in hlo
 
+
+# deepseek-67b-tp4 (bench/configs/deepseek-67b-tp4.json): one chip's share
+# of a layer of 8192 under 4-way tensor parallelism, 16 of 64 query heads
+# over 2 of 8 KV heads (G = 8), 5504 of 22016 FFN columns, 25600 of 102400
+# vocabulary rows; a full decode batch of 32 and a 256-row prompt chunk
+# beside it (288 rows), over a pool of 1025 64-row pages
+TP_K, TP_H, TP_PAGES, TP_B, TP_NPP = 2, 16, 1025, 32, 32
+TP_GEMMS = [(8192, 2048), (8192, 256), (8192, 5504), (5504, 8192),
+            (2048, 8192), (8192, 25600)]
+
+
+@pytest.mark.parametrize("M", [32, 288])
+@pytest.mark.parametrize("KN", TP_GEMMS, ids=lambda kn: f"{kn[0]}x{kn[1]}")
+def test_block_gemm_tp4_shard(one_chip, M, KN):
+    """q, k/v, gate/up, down, o and the head (f32 logits) of one shard."""
+    Kd, N = KN
+    out = F32 if N == 25600 else None
+    hlo = _compiled_hlo(one_chip,
+                        lambda a, b: block_gemm(a, b, out_dtype=out),
+                        ((M, Kd), BF16), ((Kd, N), BF16))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_decode_paged_tp4_shard(one_chip):
+    pool = ((TP_PAGES, TP_K, 64, DH), BF16)
+    hlo = _compiled_hlo(
+        one_chip,
+        lambda q, k, v, pos, pages: flash_decode(q, k, v, pos, pages=pages),
+        ((TP_B, TP_H, DH), BF16), pool, pool, ((TP_B,), I32),
+        ((TP_B, TP_NPP), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention_paged_tp4_shard(one_chip):
+    pool = ((TP_PAGES, TP_K, 64, DH), BF16)
+    hlo = _compiled_hlo(
+        one_chip,
+        lambda q, k, v, pages, qs, kl: flash_attention(
+            q, k, v, pages=pages, q_start=qs, k_len=kl),
+        ((1, TP_H, 256, DH), BF16), pool, pool, ((1, TP_NPP), I32),
+        ((1,), I32), ((1,), I32))
+    assert "tpu_custom_call" in hlo
