@@ -100,7 +100,8 @@ def _untileable(op: OperandSpec) -> List[str]:
     dims = list(zip(op.block_shape, op.grid_blocks))[-2:]
     for (blk, n), tile in zip(dims, TPU_TILE[-len(dims):]):
         if blk % tile and n != 1:  # one block along the dim = the whole dim
-            arr = tuple(b * g for b, g in zip(op.block_shape, op.grid_blocks))
+            arr = tuple((b or 1) * g  # None: a squeezed dim of one
+                        for b, g in zip(op.block_shape, op.grid_blocks))
             out.append(f"block {tuple(op.block_shape)} of '{op.name}' over "
                        f"array {arr}: block dim {blk} is neither a multiple "
                        f"of {tile} nor the whole dim {blk * n}")

@@ -29,18 +29,20 @@ once.  This kernel is the decode-side counterpart of ``flash_attention``:
 A fully-invalid slot (``start > pos``, e.g. a drained engine slot) returns
 exact zeros, mirroring the masked-row contract of ``flash_attention``.
 
-Paged mode (``pages=``): k/v are head-major *page pools*
-``[n_pages, K, page_size, d]`` shared by every sequence, and a
-scalar-prefetched per-sequence page table ``[B, npp]`` rides alongside
-``pos``/``start``.  One k-block is one page of ``Kb`` heads — all the KV
-heads the kernel sees (the local ones under tensor parallelism) unless their
-double-buffered blocks would pass :data:`KV_VMEM_BUDGET` — so a grid step
+Paged mode (``pages=``): k/v are head-major *page pools* stacked over the
+model's layers, ``[L, n_pages, K, page_size, d]``, shared by every sequence;
+a scalar-prefetched per-sequence page table ``[B, npp]`` and the index of
+the layer to read ride alongside ``pos``/``start``, so the caller's layer
+loop never slices a layer's pool out of the stack.  One k-block is one page
+of ``Kb`` heads — all the KV heads the kernel sees (the local ones under
+tensor parallelism) unless their double-buffered blocks would pass
+:data:`KV_VMEM_BUDGET` — so a grid step
 streams a whole page of a slot and scores it with head-batched dots.  The
 BlockSpec index map follows the table — logical block ``ik`` of slot ``b``
 streams page ``pages[b, ik]`` from the pool — so the kernel body is the
-linear layout over logical rows; the indirection lives entirely in the
-index map, and the same dead-block clipping bounds HBM traffic by the live
-length.
+linear layout over logical rows; the indirection (layer and page) lives
+entirely in the index map, and the same dead-block clipping bounds HBM
+traffic by the live length.
 """
 from __future__ import annotations
 
@@ -52,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import round_up
+from repro.kernels.ref import layer_pools
 from repro.kernels.spec import KernelSpec, OperandSpec, ScalarSpec, provenance
 
 F32 = jnp.float32
@@ -140,10 +143,12 @@ def fd_paged_heads(K: int, dq: int, dv: int, ps: int, itemsize: int) -> int:
 
 
 def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
-                  npp: int, n_pages: int, itemsize: int = 2) -> KernelSpec:
+                  npp: int, n_pages: int, itemsize: int = 2,
+                  layers: int = 1) -> KernelSpec:
     """Grid/BlockSpec contract of the paged ``flash_decode`` kernel: one
     grid step per (slot, head block, logical page), a head block being
-    :func:`fd_paged_heads` of the ``K`` heads."""
+    :func:`fd_paged_heads` of the ``K`` heads, read from layer ``layer`` of
+    ``layers`` stacked pools (the block's layer dim is squeezed)."""
     G = H // K
     Gp = round_up(G, 8)
     S = npp * ps
@@ -153,15 +158,15 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
     def q_map(b, kh, ik, *_):
         return (b, kh, 0, 0)
 
-    def kv_map(b, kh, ik, pos_ref, start_ref, pages_ref):
+    def kv_map(b, kh, ik, pos_ref, start_ref, pages_ref, layer_ref):
         # dead logical blocks revisit a live page (repeat index -> the DMA
         # is elided), exactly like the dense linear layout's clipping
         lo = jnp.minimum(start_ref[b] // ps, npp - 1)
         hi = jnp.minimum(pos_ref[b] // ps, npp - 1)
         ik = jnp.clip(ik, lo, hi)
-        return (pages_ref[b, ik], kh, 0, 0)
+        return (layer_ref[0], pages_ref[b, ik], kh, 0, 0)
 
-    def block_live(b, kh, ik, pos, start, pages):
+    def block_live(b, kh, ik, pos, start, pages, layer):
         return _fd_block_live(ik, pos[b], start[b], bk=ps, layout="linear")
 
     src_file, src_line = provenance(kv_map)
@@ -172,11 +177,14 @@ def fd_paged_spec(B: int, H: int, K: int, dq: int, dv: int, ps: int,
             ScalarSpec("pos", (B,), 0, S),
             ScalarSpec("start", (B,), 0, S),
             ScalarSpec("pages", (B, npp), 0, n_pages - 1),
+            ScalarSpec("layer", (1,), 0, layers - 1),
         ),
         operands=(
             OperandSpec("q", (1, Kb, Gp, dq), q_map, (B, nh, 1, 1)),
-            OperandSpec("k", (1, Kb, ps, dq), kv_map, (n_pages, nh, 1, 1)),
-            OperandSpec("v", (1, Kb, ps, dv), kv_map, (n_pages, nh, 1, 1)),
+            OperandSpec("k", (None, 1, Kb, ps, dq), kv_map,
+                        (layers, n_pages, nh, 1, 1)),
+            OperandSpec("v", (None, 1, Kb, ps, dv), kv_map,
+                        (layers, n_pages, nh, 1, 1)),
             OperandSpec("o", (1, Kb, Gp, dv), q_map, (B, nh, 1, 1),
                         is_output=True),
         ),
@@ -254,22 +262,25 @@ def _fd_kernel(pos_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
-def _fd_kernel_paged(pos_ref, start_ref, pages_ref, *args, **kw):
-    """Paged entry: the page table is consumed by the BlockSpec index map
-    only — the kernel body works in logical rows and never sees it."""
-    del pages_ref
+def _fd_kernel_paged(pos_ref, start_ref, pages_ref, layer_ref, *args, **kw):
+    """Paged entry: the page table and the layer index are consumed by the
+    BlockSpec index map only — the kernel body works in logical rows and
+    never sees them."""
+    del pages_ref, layer_ref
     _fd_kernel(pos_ref, start_ref, *args, **kw)
 
 
-def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
-                        scale, dv: int | None, interpret: bool):
-    """q: [B, H, dq]; k/v: head-major page pools [P, K, ps, d]; pages:
-    [B, npp] int32 -> [B, H, dv].  Logical row ``r`` of slot ``b`` lives at
-    ``pool[pages[b, r // ps], :, r % ps]``; validity is the linear rule over
-    logical rows ``[start, pos]``.  ``ps`` must be a multiple of 8
+def _flash_decode_paged(q, k, v, pos, start, pages, layer, *,
+                        softcap: float, scale, dv: int | None,
+                        interpret: bool):
+    """q: [B, H, dq]; k/v: head-major page pools stacked over layers
+    [L, P, K, ps, d]; pages: [B, npp] int32; layer: int32 scalar ->
+    [B, H, dv].  Logical row ``r`` of slot ``b`` lives at
+    ``pool[layer, pages[b, r // ps], :, r % ps]``; validity is the linear
+    rule over logical rows ``[start, pos]``.  ``ps`` must be a multiple of 8
     (sublane alignment — enforced by ``EngineConfig``)."""
     B, H, dq = q.shape
-    K, ps = k.shape[1], k.shape[2]
+    K, ps = k.shape[2], k.shape[3]
     npp = pages.shape[1]
     dv = dv or v.shape[-1]
     G = H // K
@@ -278,6 +289,7 @@ def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
     start = (jnp.zeros((B,), jnp.int32) if start is None
              else jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,)))
     pages = jnp.asarray(pages, jnp.int32)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     shared = v is k  # MLA dual-operand form
     if k.dtype != q.dtype:
         k = k.astype(q.dtype)
@@ -290,12 +302,12 @@ def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
     qg = q.reshape(B, K, G, dq)
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    spec = fd_paged_spec(B, H, K, dq, dv, ps, npp, k.shape[0],
-                         itemsize=q.dtype.itemsize)
-    Kb = spec.operands[1].block_shape[1]
+    spec = fd_paged_spec(B, H, K, dq, dv, ps, npp, k.shape[1],
+                         itemsize=q.dtype.itemsize, layers=k.shape[0])
+    Kb = spec.operands[1].block_shape[2]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # pos, start, pages
+        num_scalar_prefetch=4,  # pos, start, pages, layer
         grid=spec.grid,
         in_specs=[pl.BlockSpec(op.block_shape, op.index_map)
                   for op in spec.inputs],
@@ -313,13 +325,14 @@ def _flash_decode_paged(q, k, v, pos, start, pages, *, softcap: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, Gp, dv), q.dtype),
         interpret=interpret,
-    )(pos, start, pages, qg, k, v)
+    )(pos, start, pages, layer, qg, k, v)
     return out[:, :, :G].reshape(B, H, dv)
 
 
 def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
                  softcap: float = 0.0, scale=None, bk: int = 128,
-                 dv: int | None = None, pages=None, interpret: bool = False):
+                 dv: int | None = None, pages=None, layer=None,
+                 interpret: bool = False):
     """q: [B, H, dq]; k: [B, S, K, dq]; v: [B, S, K, >=dv] -> [B, H, dv].
 
     k/v arrive in the slot-cache layout ``[B, S, K, d]`` and are moved to
@@ -337,15 +350,18 @@ def flash_decode(q, k, v, pos, start=None, *, layout: str = "linear",
     latent (the value) being the first ``kv_lora_rank`` columns.
 
     ``pages`` switches to the paged cache: k/v become head-major page pools
-    ``[n_pages, K, page_size, d]`` and ``pages`` the [B, npp] page table
-    (see :func:`_flash_decode_paged`); ``layout`` must be linear/paged —
-    sliding windows under paging express validity through ``start``
+    stacked over layers, ``[L, n_pages, K, page_size, d]``, read at the
+    int32 scalar ``layer`` (one layer's bare ``[n_pages, K, page_size, d]``
+    pools with ``layer=None``), and ``pages`` the [B, npp] page table (see
+    :func:`_flash_decode_paged`); ``layout`` must be linear/paged — sliding
+    windows under paging express validity through ``start``
     (``max(0, pos - window + 1)``), not a ring.
     """
     if pages is not None:
         assert layout in ("linear", "paged"), \
             f"paged decode is linear-validity only, got layout={layout!r}"
-        return _flash_decode_paged(q, k, v, pos, start, pages,
+        k, v, layer = layer_pools(k, v, layer)
+        return _flash_decode_paged(q, k, v, pos, start, pages, layer,
                                    softcap=softcap, scale=scale, dv=dv,
                                    interpret=interpret)
     B, H, dq = q.shape

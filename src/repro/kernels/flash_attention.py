@@ -19,6 +19,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import round_up
+from repro.kernels.ref import layer_pools
 from repro.kernels.spec import KernelSpec, OperandSpec, ScalarSpec, provenance
 
 F32 = jnp.float32
@@ -65,8 +66,11 @@ def fa_dense_spec(B: int, H: int, K: int, Sq: int, Sk: int, d: int, *,
 
 
 def fa_paged_spec(B: int, H: int, K: int, C: int, d: int, ps: int, npp: int,
-                  n_pages: int, *, bq: int = 128) -> KernelSpec:
-    """Grid/BlockSpec contract of the paged chunk-prefill attention kernel.
+                  n_pages: int, *, bq: int = 128,
+                  layers: int = 1) -> KernelSpec:
+    """Grid/BlockSpec contract of the paged chunk-prefill attention kernel,
+    reading layer ``layer`` of ``layers`` stacked pools (the block's layer
+    dim is squeezed).
 
     Scalar domains are hostile: ``q_start``/``k_len`` range over the full
     logical capacity (including ``k_len == 0`` — an empty chunk — and
@@ -80,16 +84,16 @@ def fa_paged_spec(B: int, H: int, K: int, C: int, d: int, ps: int, npp: int,
     def q_map(b, h, iq, ik, *_):
         return (b, h, iq, 0)
 
-    def kv_map(b, h, iq, ik, qstart_ref, klen_ref, pages_ref):
+    def kv_map(b, h, iq, ik, qstart_ref, klen_ref, pages_ref, layer_ref):
         # dead logical pages revisit a live one (repeat index -> the DMA is
         # elided); the kernel gates their compute via _paged_block_live
         hi_k = (klen_ref[b] - 1) // ps
         hi_c = (qstart_ref[b] + (iq + 1) * bq_ - 1) // ps
         hi = jnp.clip(jnp.minimum(hi_k, hi_c), 0, npp - 1)
         ik = jnp.minimum(ik, hi)
-        return (pages_ref[b, ik], h // G, 0, 0)
+        return (layer_ref[0], pages_ref[b, ik], h // G, 0, 0)
 
-    def block_live(b, h, iq, ik, qstart, klen, pages):
+    def block_live(b, h, iq, ik, qstart, klen, pages, layer):
         return _paged_block_live(iq, ik, qstart[b], klen[b], bq=bq_, ps=ps)
 
     src_file, src_line = provenance(kv_map)
@@ -100,11 +104,14 @@ def fa_paged_spec(B: int, H: int, K: int, C: int, d: int, ps: int, npp: int,
             ScalarSpec("q_start", (B,), 0, S),
             ScalarSpec("k_len", (B,), 0, S),
             ScalarSpec("pages", (B, npp), 0, n_pages - 1),
+            ScalarSpec("layer", (1,), 0, layers - 1),
         ),
         operands=(
             OperandSpec("q", (1, 1, bq_, d), q_map, (B, H, Cp // bq_, 1)),
-            OperandSpec("k", (1, 1, ps, d), kv_map, (n_pages, K, 1, 1)),
-            OperandSpec("v", (1, 1, ps, d), kv_map, (n_pages, K, 1, 1)),
+            OperandSpec("k", (None, 1, 1, ps, d), kv_map,
+                        (layers, n_pages, K, 1, 1)),
+            OperandSpec("v", (None, 1, 1, ps, d), kv_map,
+                        (layers, n_pages, K, 1, 1)),
             OperandSpec("o", (1, 1, bq_, d), q_map, (B, H, Cp // bq_, 1),
                         is_output=True),
         ),
@@ -169,8 +176,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, q_ref, k_ref, v_ref,
-                     o_ref, m_ref, l_ref, acc_ref, *, nk: int, bq: int,
+def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, layer_ref, q_ref, k_ref,
+                     v_ref, o_ref, m_ref, l_ref, acc_ref, *, nk: int, bq: int,
                      ps: int, window: int, softcap: float, scale: float):
     """One (batch, head, q-block, page) grid step of chunked-prefill
     attention over a paged past.
@@ -179,11 +186,11 @@ def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, q_ref, k_ref, v_ref,
     ``qstart[b] + i`` and attend causally over logical rows
     ``[0, klen[b])`` of the page pool — which include the chunk's own keys,
     written through the page table before the kernel runs.  The page table
-    itself is consumed only by the BlockSpec index map (``pages_ref`` never
-    appears here): the kernel body works in logical rows, exactly like the
-    dense ``_fa_kernel``, with validity from the prefetched scalars instead
-    of a suffix-alignment offset."""
-    del pages_ref
+    and the layer index are consumed only by the BlockSpec index map
+    (``pages_ref``/``layer_ref`` never appear here): the kernel body works
+    in logical rows, exactly like the dense ``_fa_kernel``, with validity
+    from the prefetched scalars instead of a suffix-alignment offset."""
+    del pages_ref, layer_ref
     b = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -231,39 +238,44 @@ def _fa_kernel_paged(qstart_ref, klen_ref, pages_ref, q_ref, k_ref, v_ref,
                        ).astype(o_ref.dtype)
 
 
-def _flash_attention_paged(q, k, v, pages, q_start, k_len, *, window: int,
-                           softcap: float, scale, bq: int, interpret: bool):
-    """q: [B, H, C, d] query chunk; k/v: head-major page pools
-    [P, K, ps, d]; pages: [B, npp] int32 -> [B, H, C, d].
+def _flash_attention_paged(q, k, v, pages, q_start, k_len, layer, *,
+                           window: int, softcap: float, scale, bq: int,
+                           interpret: bool):
+    """q: [B, H, C, d] query chunk; k/v: head-major page pools stacked over
+    layers [L, P, K, ps, d]; pages: [B, npp] int32; layer: int32 scalar ->
+    [B, H, C, d].
 
     Chunked-prefill attention: logical row ``r`` of slot ``b`` lives at
-    ``pool[pages[b, r // ps], :, r % ps]``; query row ``i`` sits at logical
-    position ``q_start[b] + i`` and rows ``[0, k_len[b])`` are valid.  One
+    ``pool[layer, pages[b, r // ps], :, r % ps]``; query row ``i`` sits at
+    logical position ``q_start[b] + i`` and rows ``[0, k_len[b])`` are
+    valid.  One
     k-block is one page and the BlockSpec index map follows the
     scalar-prefetched table (the ``flash_decode`` paged trick): dead pages —
     beyond the valid rows or beyond the q-block's causal horizon — are
     remapped to a live page index so the repeated-visit DMA is elided, and
     their compute is skipped in-kernel."""
     B, H, C, d = q.shape
-    K, ps = k.shape[1], k.shape[2]
+    K, ps = k.shape[2], k.shape[3]
     npp = pages.shape[1]
     scale = scale if scale is not None else d ** -0.5
     q_start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
     k_len = jnp.broadcast_to(jnp.asarray(k_len, jnp.int32), (B,))
     pages = jnp.asarray(pages, jnp.int32)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     if k.dtype != q.dtype:  # serving pools share the compute dtype: no-op
         k = k.astype(q.dtype)
     if v.dtype != q.dtype:
         v = v.astype(q.dtype)
 
-    spec = fa_paged_spec(B, H, K, C, d, ps, npp, k.shape[0], bq=bq)
+    spec = fa_paged_spec(B, H, K, C, d, ps, npp, k.shape[1], bq=bq,
+                         layers=k.shape[0])
     bq_ = spec.outputs[0].block_shape[2]
     Cp = spec.grid[2] * bq_
     if Cp != C:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Cp - C), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # q_start, k_len, pages
+        num_scalar_prefetch=4,  # q_start, k_len, pages, layer
         grid=spec.grid,
         in_specs=[pl.BlockSpec(op.block_shape, op.index_map)
                   for op in spec.inputs],
@@ -281,27 +293,31 @@ def _flash_attention_paged(q, k, v, pages, q_start, k_len, *, window: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Cp, d), q.dtype),
         interpret=interpret,
-    )(q_start, k_len, pages, q, k, v)
+    )(q_start, k_len, pages, layer, q, k, v)
     return out[:, :, :C]
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
                     scale=None, softcap=0.0, pages=None, q_start=None,
-                    k_len=None, interpret=False):
+                    k_len=None, layer=None, interpret=False):
     """q: [B,H,Sq,d]; k/v: [B,K,Sk,d] with H % K == 0 (GQA folded in the
     BlockSpec index map).  Arbitrary Sq/Sk: ragged shapes are padded up to
     the block grid and sliced back (padded keys are masked out in-kernel).
     Fully-masked rows return zeros.
 
     ``pages`` switches to the *paged past* layout for chunked prefill: k/v
-    become head-major page pools ``[n_pages, K, page_size, d]``, ``pages``
-    the [B, npp] page table, and ``q_start``/``k_len`` [B] give the chunk's first query
-    position and the valid logical row count (see
-    :func:`_flash_attention_paged`).  Paged attention is causal by
-    definition — the chunk continues a causal prefix."""
+    become head-major page pools stacked over layers,
+    ``[L, n_pages, K, page_size, d]``, read at the int32 scalar ``layer``
+    (one layer's bare ``[n_pages, K, page_size, d]`` pools with
+    ``layer=None``), ``pages`` the [B, npp] page table, and
+    ``q_start``/``k_len`` [B] give the chunk's first query position and the
+    valid logical row count (see :func:`_flash_attention_paged`).  Paged
+    attention is causal by definition — the chunk continues a causal
+    prefix."""
     if pages is not None:
         assert causal, "paged chunk-prefill attention is causal by definition"
-        return _flash_attention_paged(q, k, v, pages, q_start, k_len,
+        k, v, layer = layer_pools(k, v, layer)
+        return _flash_attention_paged(q, k, v, pages, q_start, k_len, layer,
                                       window=window, softcap=softcap,
                                       scale=scale, bq=bq, interpret=interpret)
     B, H, Sq, d = q.shape

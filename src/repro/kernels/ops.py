@@ -52,24 +52,26 @@ def cgra_matmul_int8(a_q, b_q, a_scale, b_scale, mode: str = "reference",
 
 
 def attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-              pages=None, q_start=None, k_len=None,
+              pages=None, q_start=None, k_len=None, layer=None,
               mode: str = "reference", bq=128, bk=128):
     """q: [B,H,Sq,d]; k/v: [B,K,Sk,d] (GQA: H % K == 0).  Ragged Sq/Sk ok;
     causal masking aligns the last query with the last key (``Sq < Sk`` is
     the suffix-prefill pattern over a cached prefix).
 
     ``pages`` ([B, npp] int32) switches to the chunked-prefill *paged past*
-    layout: k/v become head-major page pools ``[n_pages, K, page_size, d]``
-    and ``q_start``/``k_len`` [B] place the query chunk at absolute positions
-    ``q_start + i`` attending over logical rows ``[0, k_len)``."""
+    layout: k/v become head-major page pools stacked over layers
+    ``[L, n_pages, K, page_size, d]`` read at the int32 scalar ``layer``,
+    and ``q_start``/``k_len`` [B] place the query chunk at absolute
+    positions ``q_start + i`` attending over logical rows ``[0, k_len)``."""
     if pages is not None:
         if mode == "reference":
             return ref.flash_attention_paged_ref(q, k, v, pages, q_start,
                                                  k_len, window=window,
-                                                 softcap=softcap)
+                                                 softcap=softcap, layer=layer)
         return flash_attention(q, k, v, pages=pages, q_start=q_start,
-                               k_len=k_len, window=window, softcap=softcap,
-                               bq=bq, interpret=(mode == "interpret"))
+                               k_len=k_len, layer=layer, window=window,
+                               softcap=softcap, bq=bq,
+                               interpret=(mode == "interpret"))
     if mode == "reference":
         G = q.shape[1] // k.shape[1]
         kb = jnp.repeat(k, G, axis=1)
@@ -83,7 +85,7 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 def attend_decode(q, k, v, pos, start=None, *,
                   layout: str | CacheLayout = CacheLayout.LINEAR,
-                  softcap=0.0, scale=None, dv=None, pages=None,
+                  softcap=0.0, scale=None, dv=None, pages=None, layer=None,
                   mode: str = "reference", bk=128):
     """Batched single-token decode over a slot-indexed KV cache.
 
@@ -94,13 +96,14 @@ def attend_decode(q, k, v, pos, start=None, *,
     narrows the value read to the first dv columns — MLA latent decode
     passes its concatenated ``[latent | k_rope]`` cache as both k and v.
     ``pages`` ([B, npp] int32) switches k/v to head-major page pools
-    ``[n_pages, K, page_size, d]`` indirected through the table.
+    stacked over layers, ``[L, n_pages, K, page_size, d]``, indirected
+    through the table and read at the int32 scalar ``layer``.
     """
     layout = str(layout)
     if mode == "reference":
         return ref.flash_decode_ref(q, k, v, pos, start, layout=layout,
                                     softcap=softcap, scale=scale, dv=dv,
-                                    pages=pages)
+                                    pages=pages, layer=layer)
     return flash_decode(q, k, v, pos, start, layout=layout, softcap=softcap,
-                        scale=scale, dv=dv, bk=bk, pages=pages,
+                        scale=scale, dv=dv, bk=bk, pages=pages, layer=layer,
                         interpret=(mode == "interpret"))

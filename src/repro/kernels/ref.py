@@ -7,13 +7,28 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
-def gather_pages(pool, pages):
-    """Dense ``[B, npp * page_size, K, d]`` view of each sequence's rows in a
-    head-major page pool ``[n_pages, K, page_size, d]``; pages: [B, npp]."""
+def layer_pools(k, v, layer):
+    """The paged kernels' operands: head-major pools stacked over layers,
+    ``[L, n_pages, K, page_size, d]``, and the int32 index of the layer to
+    read.  A caller holding one layer's bare ``[n_pages, K, page_size, d]``
+    pools passes ``layer=None``: they are read as a stack of one, at layer 0.
+    ``v is k`` (MLA's one fused pool) is kept."""
+    if layer is None:
+        shared = v is k
+        k, layer = k[None], 0
+        v = k if shared else v[None]
+    return k, v, jnp.asarray(layer, jnp.int32)
+
+
+def gather_pages(pool, pages, layer):
+    """Dense ``[B, npp * page_size, K, d]`` view of each sequence's rows in
+    layer ``layer`` of stacked head-major page pools
+    ``[L, n_pages, K, page_size, d]``; pages: [B, npp].  One gather of the
+    table's pages — the layer's pool is never sliced out whole."""
     B, npp = pages.shape
-    g = pool[pages]  # [B, npp, K, ps, d]
-    return g.transpose(0, 1, 3, 2, 4).reshape(B, npp * pool.shape[2],
-                                              pool.shape[1], pool.shape[3])
+    g = pool[layer, pages]  # [B, npp, K, ps, d]
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, npp * pool.shape[3],
+                                              pool.shape[2], pool.shape[4])
 
 
 def block_gemm_ref(a, b, out_dtype=None):
@@ -60,11 +75,13 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None,
 
 
 def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
-                              scale=None, softcap=0.0):
+                              scale=None, softcap=0.0, layer=None):
     """Oracle for ``flash_attention(pages=...)``: a query *chunk* attending
     over a paged past (chunked prefill).  q: [B,H,C,d]; k/v: head-major
-    page pools [n_pages, K, page_size, d] (H % K == 0, GQA); pages: [B, npp]
-    int32 page tables; q_start/k_len: [B] int32 — query row ``i`` of slot ``b`` sits at
+    page pools stacked over layers [L, n_pages, K, page_size, d], read at
+    ``layer`` (bare [n_pages, K, page_size, d] pools with ``layer=None``;
+    H % K == 0, GQA); pages: [B, npp] int32 page tables; q_start/k_len: [B]
+    int32 — query row ``i`` of slot ``b`` sits at
     logical position ``q_start[b] + i`` and attends causally over logical
     rows ``[0, k_len[b])`` (which include the chunk's own freshly-written
     keys).  The oracle gathers each slot's pages into a dense
@@ -73,17 +90,19 @@ def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
     past the chunk's valid length are the caller's padding; their output is
     unspecified (the engine slices them off)."""
     pages = jnp.asarray(pages, jnp.int32)
+    shared = v is k
+    k, v, layer = layer_pools(k, v, layer)
     B, H, C, d = q.shape
-    K, ps = k.shape[1], k.shape[2]
+    K, ps = k.shape[2], k.shape[3]
     npp = pages.shape[1]
     G = H // K
     S = npp * ps
     scale = scale if scale is not None else d ** -0.5
     q_start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
     k_len = jnp.broadcast_to(jnp.asarray(k_len, jnp.int32), (B,))
-    shared = v is k
-    kb = jnp.repeat(gather_pages(k, pages), G, axis=2)  # [B,S,H,d]
-    vb = kb if shared else jnp.repeat(gather_pages(v, pages), G, axis=2)
+    kb = jnp.repeat(gather_pages(k, pages, layer), G, axis=2)  # [B,S,H,d]
+    vb = kb if shared else jnp.repeat(gather_pages(v, pages, layer), G,
+                                      axis=2)
     s = jnp.einsum("bhqd,bshd->bhqs", q, kb,
                    preferred_element_type=F32) * scale
     if softcap:
@@ -102,7 +121,8 @@ def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
 
 
 def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
-                     softcap=0.0, scale=None, dv=None, pages=None):
+                     softcap=0.0, scale=None, dv=None, pages=None,
+                     layer=None):
     """Oracle for ``flash_decode``: batched single-token decode over a
     slot-indexed cache in its native layout.  q: [B,H,dq]; k: [B,S,K,dq];
     v: [B,S,K,>=dv]; pos/start: [B] int32 (broadcastable).  ``layout``:
@@ -113,8 +133,10 @@ def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
     return zeros.
 
     Paged path: ``pages`` [B, npp] int32 page tables over head-major pools
-    k/v of shape [n_pages, K, page_size, d]; logical row ``r`` of slot ``b``
-    lives at ``(pages[b, r // page_size], :, r % page_size)``.  The oracle
+    k/v stacked over layers, [L, n_pages, K, page_size, d], read at
+    ``layer`` (bare [n_pages, K, page_size, d] pools with ``layer=None``);
+    logical row ``r`` of slot ``b`` lives at
+    ``(layer, pages[b, r // page_size], :, r % page_size)``.  The oracle
     gathers each slot's pages into a dense [B, npp * page_size, K, d] cache
     and falls through to the linear rule — the page table is pure
     indirection, the validity semantics are unchanged."""
@@ -122,8 +144,9 @@ def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
         assert layout in ("linear", "paged"), layout
         pages = jnp.asarray(pages, jnp.int32)
         shared = v is k
-        k = gather_pages(k, pages)
-        v = k if shared else gather_pages(v, pages)
+        k, v, layer = layer_pools(k, v, layer)
+        k = gather_pages(k, pages, layer)
+        v = k if shared else gather_pages(v, pages, layer)
         layout = "linear"
     B, H, dq = q.shape
     S, K = k.shape[1], k.shape[2]

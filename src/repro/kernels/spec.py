@@ -41,7 +41,9 @@ class OperandSpec:
 
     ``grid_blocks`` is the number of valid blocks per array dimension, i.e.
     ``padded_dim_size // block_shape[d]`` — the index map must return a block
-    index in ``[0, grid_blocks[d])`` for every dimension ``d``.
+    index in ``[0, grid_blocks[d])`` for every dimension ``d``.  A
+    ``block_shape`` entry of ``None`` is a squeezed dim (Pallas' own
+    convention): a block of one, absent from the kernel's ref.
     """
 
     name: str

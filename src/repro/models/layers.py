@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 import functools
+import math
 
 from repro.configs.base import ArchConfig
 from repro.core import round_up
@@ -181,12 +182,14 @@ def dispatch_attend(cfg: ArchConfig, q, k, v, q_pos, k_pos, *, causal: bool,
 def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *,
                            layout: str | CacheLayout = CacheLayout.LINEAR,
                            softcap: float = 0.0, scale=None,
-                           dv: int | None = None, pages=None):
+                           dv: int | None = None, pages=None, layer=None):
     """kernel_mode-aware single-token decode core.
 
-    q [B,1,H,dq] and a slot cache k/v [B,S,K,d] (or, with ``pages``, a
-    head-major pool [P,K,ps,d]) -> [B,1,H,dv].  The paged pool is the
-    kernel's own layout, so the serving hot path never copies it.
+    q [B,1,H,dq] and a slot cache k/v [B,S,K,d] (or, with ``pages``,
+    head-major pools stacked over layers [L,P,K,ps,d], read at the int32
+    scalar ``layer``) -> [B,1,H,dv].  The stacked paged pool is the
+    kernel's own layout, so the serving hot path never copies it, nor
+    slices a layer out of it.
     ``pos``/``start`` are the per-slot [B] validity bounds (cache row of
     the current token / first live row — sliding-window layers on a linear
     or paged cache pass ``max(0, pos - window + 1)``); ``layout`` is the
@@ -207,18 +210,19 @@ def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *,
     mesh, tp = _tp_mesh(cfg)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        kax = 1 if pages is not None else 2  # pool [P,K,ps,d] / [B,S,K,d]
+        kax = 2  # stacked pool [L,P,K,ps,d] / slot cache [B,S,K,d]
         hs = "model" if (q0.shape[1] % tp == 0 and k.shape[kax] % tp == 0) \
             else None
         qspec = P(None, hs, None)            # q [B, H, dq]
-        kvspec = P(*(hs if i == kax else None for i in range(4)))
-        body = lambda qq, kk, vv, pp, ss, pg: call(qq, kk, vv, pp, ss, pages=pg)
+        kvspec = P(*(hs if i == kax else None for i in range(k.ndim)))
+        body = lambda qq, kk, vv, pp, ss, pg, ly: call(qq, kk, vv, pp, ss,
+                                                       pages=pg, layer=ly)
         o = tp_shard_map(
             body, mesh,
-            (qspec, kvspec, kvspec, P(None), P(None), P(None, None)),
-            qspec)(q0, k, v, pos, start, pages)
+            (qspec, kvspec, kvspec, P(None), P(None), P(None, None), P()),
+            qspec)(q0, k, v, pos, start, pages, layer)
     else:
-        o = call(q0, k, v, pos, start, pages=pages)
+        o = call(q0, k, v, pos, start, pages=pages, layer=layer)
     return o[:, None]
 
 
@@ -417,23 +421,50 @@ def attn_cache_specs(cfg: ArchConfig, batch: int, seq: int, local: bool) -> dict
     }
 
 
-def _page_row_write(pool, new_row, pages, pos):
-    """Scatter one row per sequence into a head-major page pool.
+def _page_row_write(pools: tuple, new_rows: tuple, pages, pos, layer):
+    """Write one row per sequence into layer ``layer`` of head-major page
+    pools stacked over layers, in place.
 
-    pool: [P, K, ps, d]; new_row: [B, K, d]; pages: [B, npp]; pos: [B].
+    pools: [L, P, K, ps, d] each (k and v, written in one loop); new_rows:
+    [B, K, d] each; pages: [B, npp]; pos: [B]; layer: int32 scalar.
     Logical row ``pos`` of sequence ``b`` lands at
-    ``pool[pages[b, pos // ps], :, pos % ps]``.  Rows whose page index would
-    fall off the table are dropped, never clamped (the engine errors on
-    capacity overrun before this can matter)."""
-    P, ps = pool.shape[0], pool.shape[2]
-    B = new_row.shape[0]
+    ``pool[layer, pages[b, pos // ps], :, pos % ps]``.  Rows whose page
+    index would fall off the table are dropped, never clamped (the engine
+    errors on capacity overrun before this can matter).
+
+    A loop over the sequences, each step a read-modify-write of the
+    ``[K, tile, d]`` block of whole sublane tiles that holds the row, with
+    ``dynamic_slice`` / ``dynamic_update_slice``: XLA updates the stack in
+    place and keeps its row-major layout, which the attention kernels read.
+    A scatter (``.at[].set`` with the page and row indices apart) lowers as
+    a transpose and a copy of the whole pool; a single-row update makes the
+    TPU compiler lay the stack out head-minor and copy it to and from the
+    kernels' layout.  A loop step's read and write see one version of the
+    pool; unrolled, XLA fuses each read with the write before it, and so
+    copies the pool."""
+    _, _, _, ps, _ = pools[0].shape
     npp = pages.shape[1]
+    rows = tuple(r.astype(p.dtype) for r, p in zip(new_rows, pools))
     ipage = pos // ps
-    page = jnp.where(ipage < npp,
-                     pages[jnp.arange(B), jnp.minimum(ipage, npp - 1)],
-                     P)  # out of range -> dropped by mode="drop"
-    return pool.at[page, :, pos % ps].set(new_row.astype(pool.dtype),
-                                          mode="drop")
+    ok = ipage < npp
+    page = jnp.take_along_axis(pages, jnp.minimum(ipage, npp - 1)[:, None],
+                               axis=1)[:, 0]
+    tile = math.gcd(ps, 16)  # a bf16 sublane tile, or the page
+    t = jnp.arange(tile, dtype=jnp.int32)
+
+    def write(b, pools):
+        off = pos[b] % ps
+        at = (layer, page[b], 0, off - off % tile, 0)
+        hit = (ok[b] & (t == off % tile))[:, None]
+        out = []
+        for pool, row in zip(pools, rows):
+            _, _, K, _, d = pool.shape
+            old = lax.dynamic_slice(pool, at, (1, 1, K, tile, d))
+            new = jnp.where(hit, row[b][None, None, :, None], old)
+            out.append(lax.dynamic_update_slice(pool, new, at))
+        return tuple(out)
+
+    return lax.fori_loop(0, pos.shape[0], write, tuple(pools))
 
 
 def attn_prefill(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
@@ -475,35 +506,61 @@ def attn_prefill(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
     return out, {"k": k, "v": v}
 
 
-def _page_rows_write(pool, new_rows, pages, pos0, n):
-    """Scatter a *chunk* of rows per sequence into a page pool.
+def _page_rows_write(pools: tuple, new_rows: tuple, pages, pos0, n, layer):
+    """Write a *chunk* of rows per sequence into layer ``layer`` of stacked
+    head-major page pools, in place.
 
-    pool: [P, K, ps, d]; new_rows: [B, C, K, d]; pages: [B, npp];
-    pos0/n: [B].  Chunk row ``i`` of sequence ``b`` is logical row
-    ``r = pos0[b] + i`` and lands at ``pool[pages[b, r // ps], :, r % ps]``.
-    Rows at ``i >= n[b]`` (the chunk's padding) and rows whose page index
-    would fall off the table are dropped, never clamped — same contract as
-    :func:`_page_row_write`."""
-    P, ps = pool.shape[0], pool.shape[2]
-    C = new_rows.shape[1]
+    pools: [L, P, K, ps, d] each (k and v, written in one loop); new_rows:
+    [B, C, K, d] each; pages: [B, npp]; pos0/n: [B]; layer: int32 scalar.
+    Chunk row ``i`` of sequence ``b`` is logical row ``r = pos0[b] + i`` and
+    lands at ``pool[layer, pages[b, r // ps], :, r % ps]``.  Rows at
+    ``i >= n[b]`` (the chunk's padding) and rows whose page index would fall
+    off the table are dropped, never clamped — same contract as
+    :func:`_page_row_write`.
+
+    A loop over each sequence's pages that a run of ``C`` rows can touch:
+    each step reads the page's ``[K, ps, d]`` tile, merges its chunk rows
+    and writes it back (``dynamic_slice`` / ``dynamic_update_slice``, in
+    place, as in :func:`_page_row_write`)."""
+    _, _, _, ps, _ = pools[0].shape
+    B, C = new_rows[0].shape[:2]
     npp = pages.shape[1]
-    r = pos0[:, None] + jnp.arange(C, dtype=jnp.int32)[None]  # [B, C]
-    ipage = r // ps
-    ok = (jnp.arange(C)[None] < n[:, None]) & (ipage < npp)
-    page = jnp.where(
-        ok, jnp.take_along_axis(pages, jnp.minimum(ipage, npp - 1), axis=1),
-        P)  # out of range -> dropped by mode="drop"
-    return pool.at[page, :, r % ps].set(new_rows.astype(pool.dtype),
-                                        mode="drop")
+    nj = (C + ps - 2) // ps + 1  # pages a run of C rows can touch
+    # head-major chunks, padded by a page each side so that every page's
+    # rows are one in-bounds slice: page j of b starts at chunk row
+    # j * ps - pos0[b] % ps
+    rows = tuple(jnp.pad(r.astype(p.dtype).transpose(0, 2, 1, 3),
+                         ((0, 0), (0, 0), (ps, ps), (0, 0)))
+                 for r, p in zip(new_rows, pools))
+    t = jnp.arange(ps, dtype=jnp.int32)
+
+    def write(bj, pools):
+        b, j = bj // nj, bj % nj
+        ipage, off = pos0[b] // ps + j, pos0[b] % ps
+        i = j * ps - off + t  # chunk row of each row of the page
+        ok = (i >= 0) & (i < jnp.minimum(n[b], C)) & (ipage < npp)
+        at = (layer, pages[b, jnp.minimum(ipage, npp - 1)], 0, 0, 0)
+        out = []
+        for pool, r in zip(pools, rows):
+            _, _, K, _, d = pool.shape
+            old = lax.dynamic_slice(pool, at, (1, 1, K, ps, d))
+            new = lax.dynamic_slice(r, (b, 0, j * ps - off + ps, 0),
+                                    (1, K, ps, d))
+            out.append(lax.dynamic_update_slice(
+                pool, jnp.where(ok[:, None], new, old[0])[None], at))
+        return tuple(out)
+
+    return lax.fori_loop(0, B * nj, write, tuple(pools))
 
 
 def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
-                       local: bool, pages, chunk_len):
+                       local: bool, pages, chunk_len, layer):
     """Chunked prefill over a paged past: one fixed-size prompt chunk.
 
     x: [B, C, D] — a size-C chunk buffer holding ``chunk_len`` valid prompt
     rows (the rest is padding); ``positions``: [C] or [B, C] absolute
-    positions (``past_len + arange(C)``); cache: page pools [P, K, ps, dh];
+    positions (``past_len + arange(C)``); cache: page pools stacked over
+    layers [L, P, K, ps, dh], this layer at the int32 scalar ``layer``;
     pages: [B, npp] page tables; ``chunk_len``: scalar or [B] int32.
 
     Writes the chunk's post-RoPE KV straight through the page table (no
@@ -512,7 +569,7 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
     via the paged flash-attention layout.  Padding rows beyond ``chunk_len``
     produce garbage outputs the caller must ignore (the engine only reads
     the last valid row); their KV writes are dropped.  Returns
-    (out, updated page pools)."""
+    (out, updated stacked page pools)."""
     B, C = x.shape[0], x.shape[1]
     q, k_new, v_new = _qkv(cfg, p, x, x)
     theta = cfg.rope_theta if not local else 10_000.0
@@ -524,8 +581,8 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
     pages = jnp.asarray(pages, jnp.int32)
     chunk_len = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (B,))
     pos0 = positions[:, 0]
-    k = _page_rows_write(cache["k"], k_new, pages, pos0, chunk_len)
-    v = _page_rows_write(cache["v"], v_new, pages, pos0, chunk_len)
+    k, v = _page_rows_write((cache["k"], cache["v"]), (k_new, v_new), pages,
+                            pos0, chunk_len, layer)
     window = cfg.window_size if local else 0
     call = functools.partial(kernel_attention, window=window,
                              softcap=cfg.logit_softcap, mode=cfg.kernel_mode)
@@ -533,17 +590,19 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
     mesh, tp = _tp_mesh(cfg)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        hs = "model" if (qT.shape[1] % tp == 0 and k.shape[1] % tp == 0) \
+        hs = "model" if (qT.shape[1] % tp == 0 and k.shape[2] % tp == 0) \
             else None
-        body = lambda qq, kk, vv, pg, qs, kl: call(qq, kk, vv, pages=pg,
-                                                   q_start=qs, k_len=kl)
-        heads = P(None, hs, None, None)  # q [B,H,C,d]; pools [P,K,ps,d]
+        body = lambda qq, kk, vv, pg, qs, kl, ly: call(
+            qq, kk, vv, pages=pg, q_start=qs, k_len=kl, layer=ly)
+        heads = P(None, hs, None, None)  # q [B,H,C,d]
+        pools = P(None, None, hs, None, None)  # [L,P,K,ps,d]
         o = tp_shard_map(
             body, mesh,
-            (heads, heads, heads, P(None, None), P(None), P(None)),
-            heads)(qT, k, v, pages, pos0, pos0 + chunk_len)
+            (heads, pools, pools, P(None, None), P(None), P(None), P()),
+            heads)(qT, k, v, pages, pos0, pos0 + chunk_len, layer)
     else:
-        o = call(qT, k, v, pages=pages, q_start=pos0, k_len=pos0 + chunk_len)
+        o = call(qT, k, v, pages=pages, q_start=pos0, k_len=pos0 + chunk_len,
+                 layer=layer)
     o = o.transpose(0, 2, 1, 3)
     o = constrain(o, ("batch", None, "heads", None))
     out = dense_proj(cfg, o.reshape(*o.shape[:-2], -1), p["wo"],
@@ -552,7 +611,7 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
 
 
 def attn_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, local: bool,
-                pages=None):
+                pages=None, layer=None):
     """One-token decode.  x: [B,1,D]; pos: scalar int32 or [B] int32 (cache
     row of the current token, per batch slot — continuous batching runs
     every slot at its own offset).
@@ -564,11 +623,13 @@ def attn_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, local: bool,
     slot ``S-1``; the serving engine refuses to decode past capacity
     (explicit length error) before this can happen.
 
-    Paged (``pages`` given): the cache is a page pool [P, K, ps, dh] shared
-    across the batch; the write lands at the page-table row for ``pos`` and
-    attention follows the table (CacheLayout.PAGED).  Sliding-window layers
-    store full rows like global ones and window via the validity lower
-    bound ``start = max(0, pos - window + 1)`` — no ring under paging.
+    Paged (``pages`` given): the cache is page pools stacked over layers
+    [L, P, K, ps, dh], shared across the batch, this layer at the int32
+    scalar ``layer``; the write lands in place at the page-table row for
+    ``pos`` and attention follows the table (CacheLayout.PAGED).
+    Sliding-window layers store full rows like global ones and window via
+    the validity lower bound ``start = max(0, pos - window + 1)`` — no ring
+    under paging.
 
     The attention core routes through :func:`dispatch_attend_decode`;
     RoPE is pre-applied to cached keys, so scores need no position
@@ -584,12 +645,12 @@ def attn_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, local: bool,
     window = cfg.window_size if local else 0
     if pages is not None:
         pages = jnp.asarray(pages, jnp.int32)
-        k = _page_row_write(cache["k"], k_new[:, 0], pages, pos)
-        v = _page_row_write(cache["v"], v_new[:, 0], pages, pos)
+        k, v = _page_row_write((cache["k"], cache["v"]),
+                               (k_new[:, 0], v_new[:, 0]), pages, pos, layer)
         start = jnp.maximum(pos - window + 1, 0) if window else None
         o = dispatch_attend_decode(cfg, q, k, v, pos, start,
                                    layout=CacheLayout.PAGED, pages=pages,
-                                   softcap=cfg.logit_softcap)
+                                   layer=layer, softcap=cfg.logit_softcap)
     else:
         S = cache["k"].shape[1]
         ring = bool(local and cfg.window_size)
@@ -693,9 +754,9 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
     which is passed as *both* keys (full width, qk dim ``kvr +
     qk_rope_dim``) and values (first ``kvr`` columns, selected by the
     BlockSpec — no slicing copy).  With ``pages`` the cache is a
-    [P, ps, kvr+dr] page pool (the head-major [P, 1, ps, kvr+dr] pool of one
-    latent head) written through the per-slot table, exactly as in
-    :func:`attn_decode`.
+    [P, ps, kvr+dr] page pool (read as a stack of one head-major
+    [1, P, 1, ps, kvr+dr] pool of one latent head, at layer 0) written
+    through the per-slot table, exactly as in :func:`attn_decode`.
     """
     dn, dv = cfg.qk_nope_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
@@ -710,13 +771,16 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
                           -1)[:, 0]
     if pages is not None:
         pages = jnp.asarray(pages, jnp.int32)
-        kv4 = _page_row_write(cache["kv"][:, None], row[:, None], pages, pos)
-        kv = kv4[:, 0]  # kv4 [P,1,ps,kvr+dr] pool: same array as k AND v
+        layer = jnp.int32(0)
+        kv4, = _page_row_write((cache["kv"][None, :, None],), (row[:, None],),
+                               pages, pos, layer)
+        kv = kv4[0, :, 0]  # kv4 [1,P,1,ps,kvr+dr]: same array as k AND v
     else:
         bidx = jnp.arange(B)
         kv = cache["kv"].at[bidx, pos].set(row.astype(cache["kv"].dtype),
                                            mode="drop")
         kv4 = kv[:, :, None]  # [B,S,1,kvr+dr]; same array as k AND v
+        layer = None
     wkv_b = p["wkv_b"].astype(cfg.compute_dtype)  # [kvr, H, dn+dv]
     wk, wv = wkv_b[..., :dn], wkv_b[..., dn:]
     # absorb: q_lat[b,h,r] = sum_d q_nope[b,h,d] wk[r,h,d]
@@ -726,7 +790,8 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
     o_lat = dispatch_attend_decode(
         cfg, q_cat, kv4, kv4, pos, None,
         layout=CacheLayout.PAGED if pages is not None else CacheLayout.LINEAR,
-        pages=pages, scale=(dn + cfg.qk_rope_dim) ** -0.5, dv=kvr)
+        pages=pages, layer=layer, scale=(dn + cfg.qk_rope_dim) ** -0.5,
+        dv=kvr)
     o = jnp.einsum("bshr,rhd->bshd", o_lat, wv,  # expand to v space
                    preferred_element_type=F32).astype(o_lat.dtype)
     out = dense_proj(cfg, o.reshape(*o.shape[:-2], -1), p["wo"],

@@ -274,18 +274,22 @@ def pad_cache_len(cfg: ArchConfig, caches, new_len: int,
 
 def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x, *, positions,
                  img, mode: str, cache=None, pos=None, pages=None,
-                 full_kv: bool = False, attn_chunk: int = 0, chunk_len=None):
+                 full_kv: bool = False, attn_chunk: int = 0, chunk_len=None,
+                 layer=None):
     """Returns (x, new_cache, aux).
 
-    decode: ``cache`` is the layer's KV cache (slot-indexed, or a page pool
-    when ``pages`` [B, npp] is given).  prefill: ``cache``, if set, is the
+    decode: ``cache`` is the layer's KV cache (slot-indexed, or, when
+    ``pages`` [B, npp] is given, page pools: the head-major attention pools
+    stacked over the stage's layers, this one at the int32 scalar
+    ``layer`` — see :func:`_carried`).  prefill: ``cache``, if set, is the
     layer's *past* KV ({"k","v"} [B, s, K, dh], post-RoPE — a radix-cache
     prefix hit) and ``positions`` must already be offset by ``s``;
     ``full_kv`` keeps sliding-window layers' full linear KV (paged serving)
     instead of the rolled ring.  chunk (chunked prefill): ``cache`` is the
     layer's page *pools*, ``pages`` the [B, npp] tables, ``chunk_len`` the
     valid rows in the chunk buffer — only prefix-decomposable mixers
-    (pure attention) support it; SSM/MLA/cross raise."""
+    (pure attention) support it; SSM/MLA/cross raise.  The stacked pools
+    come back updated in place."""
     aux = jnp.zeros((), F32)
     h = L.apply_norm(cfg, p["norm1"], x)
     new_cache = None
@@ -318,7 +322,8 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x, *, positions,
                                       "cross-attention image KV")
         if mode == "decode":
             m, sc = L.attn_decode(cfg, mp["self"], {"k": cache["k"], "v": cache["v"]},
-                                  h, pos, local=False, pages=pages)
+                                  h, pos, local=False, pages=pages,
+                                  layer=layer)
         elif mode == "prefill":
             m, sc = L.attn_prefill(cfg, mp["self"], h, positions, local=False,
                                    attn_chunk=attn_chunk)
@@ -336,12 +341,14 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x, *, positions,
     else:
         if mode == "decode":
             m, new_cache = L.attn_decode(cfg, p["mixer"], cache, h, pos,
-                                         local=local, pages=pages)
+                                         local=local, pages=pages,
+                                         layer=layer)
         elif mode == "chunk":
             m, new_cache = L.attn_chunk_prefill(cfg, p["mixer"], cache, h,
                                                 positions, local=local,
                                                 pages=pages,
-                                                chunk_len=chunk_len)
+                                                chunk_len=chunk_len,
+                                                layer=layer)
         elif mode == "prefill":
             m, new_cache = L.attn_prefill(cfg, p["mixer"], h, positions, local=local,
                                           attn_chunk=attn_chunk, past_kv=cache,
@@ -374,49 +381,78 @@ def _remat(cfg: ArchConfig, fn):
     return jax.checkpoint(fn)  # "full": save nothing
 
 
+def _carried(cfg: ArchConfig, spec: LayerSpec, mode: str, pages) -> set:
+    """Keys of a layer's cache that stay one stacked buffer ``[L, ...]``
+    through the stage's layer loop: the head-major attention page pools
+    (``kv_seq`` and ``kv_heads`` axes) of a paged decode or chunk step.
+    Each layer writes its rows into the stack in place and the attention
+    kernels read it at the layer's index, so no layer's pool is sliced out
+    or stacked back.  Every other leaf (MLA's latent pool, SSM state,
+    cross-attention image KV, dense caches) is sliced per layer."""
+    if pages is None or mode not in ("decode", "chunk"):
+        return set()
+    return {k for k, s in _layer_cache_specs(cfg, spec, 1, 1).items()
+            if "kv_seq" in s.axes and "kv_heads" in s.axes}
+
+
 def _apply_stage(cfg: ArchConfig, stage: Stage, sp, x, *, positions, img,
                  mode: str, caches=None, pos=None, pages=None,
                  full_kv: bool = False, attn_chunk: int = 0, chunk_len=None,
                  aux0=None):
-    """Scan `stage.repeats` iterations of the layer group."""
+    """Scan `stage.repeats` iterations of the layer group.  Stacked page
+    pools (:func:`_carried`) ride in the carry, indexed by the layer; every
+    other cache leaf is sliced per layer (``xs``) and stacked back (``ys``)."""
     group = stage.group
+    keep = {str(gi): _carried(cfg, spec, mode, pages)
+            for gi, spec in enumerate(group)}
+    stacks, sliced = {}, caches
+    if caches is not None:
+        stacks = {g: {k: c[k] for k in keep[g]} for g, c in caches.items()}
+        sliced = {g: {k: v for k, v in c.items() if k not in keep[g]}
+                  for g, c in caches.items()}
 
     def body(carry, xs):
-        xc, aux = carry
+        xc, aux, stacks = carry
         xc = constrain(xc, ("batch", "seq", "embed"))  # pin the residual stream
-        lp, lc = xs
-        new_caches = {}
+        lp, lc, r = xs
+        new_caches, new_stacks = {}, dict(stacks)
         for gi, spec in enumerate(group):
-            c_in = None if lc is None else lc[str(gi)]
-            xc, nc, a = _apply_layer(cfg, spec, lp[str(gi)], xc,
+            g = str(gi)
+            c_in = None if lc is None else dict(lc[g], **stacks[g])
+            xc, nc, a = _apply_layer(cfg, spec, lp[g], xc,
                                      positions=positions, img=img, mode=mode,
                                      cache=c_in, pos=pos, pages=pages,
                                      full_kv=full_kv, attn_chunk=attn_chunk,
-                                     chunk_len=chunk_len)
+                                     chunk_len=chunk_len, layer=r)
             if nc is not None:
-                new_caches[str(gi)] = nc
+                new_caches[g] = {k: v for k, v in nc.items()
+                                 if k not in keep[g]}
+                if g in stacks:
+                    new_stacks[g] = {k: nc[k] for k in keep[g]}
             aux = aux + a
         ys = new_caches if new_caches else None
-        return (xc, aux), ys
+        return (xc, aux, new_stacks), ys
 
     if mode == "train":
         body = _remat(cfg, body)
-    xs = (sp, caches)
+    xs = (sp, sliced, jnp.arange(stage.repeats, dtype=jnp.int32))
     if cfg.scan_layers:
-        (x, aux), ys = lax.scan(body, (x, aux0), xs)
-        return x, aux, ys
-    # unrolled path: identical math, no `while` in HLO — used by the roofline
-    # cost compiles, where XLA's cost analysis counts a scan body only once.
-    aux = aux0
-    ys_list = []
-    for r in range(stage.repeats):
-        xs_r = jax.tree.map(lambda a: a[r], xs)
-        (x, aux), ys_r = body((x, aux), xs_r)
-        ys_list.append(ys_r)
-    if ys_list and ys_list[0] is not None:
-        ys = jax.tree.map(lambda *a: jnp.stack(a), *ys_list)
+        (x, aux, stacks), ys = lax.scan(body, (x, aux0, stacks), xs)
     else:
+        # unrolled path: identical math, no `while` in HLO — used by the
+        # roofline cost compiles, where XLA's cost analysis counts a scan
+        # body only once.
+        aux = aux0
+        ys_list = []
+        for r in range(stage.repeats):
+            xs_r = jax.tree.map(lambda a: a[r], xs)
+            (x, aux, stacks), ys_r = body((x, aux, stacks), xs_r)
+            ys_list.append(ys_r)
         ys = None
+        if ys_list and ys_list[0] is not None:
+            ys = jax.tree.map(lambda *a: jnp.stack(a), *ys_list)
+    if ys is not None:
+        ys = {g: dict(c, **stacks.get(g, {})) for g, c in ys.items()}
     return x, aux, ys
 
 

@@ -303,8 +303,8 @@ def test_paged_kv_map_oob_without_clamp():
     spec = fd_paged_spec(2, 4, 2, 64, 64, 16, 4, 9)
     assert any(op.name == "k" for op in spec.operands)
 
-    def unclamped(b, kh, ik, pos_ref, start_ref, pages_ref):
-        return (pages_ref[b, ik], 0, kh, 0)  # no [lo, hi] clamp
+    def unclamped(b, kh, ik, pos_ref, start_ref, pages_ref, layer_ref):
+        return (layer_ref[0], pages_ref[b, ik], kh, 0, 0)  # no [lo, hi] clamp
 
     mutated = dataclasses.replace(spec, operands=tuple(
         dataclasses.replace(op, index_map=unclamped)
@@ -315,15 +315,16 @@ def test_paged_kv_map_oob_without_clamp():
 
 def _seed_seq_major(spec, kv_map):
     """The paged spec as it shipped with a sequence-major pool
-    ``[n_pages, ps, K, d]``: k/v blocks ``(1, ps, 1, d)`` — one head picked
-    by a second-to-last block dim of 1 over K."""
+    ``[L, n_pages, ps, K, d]``: k/v blocks ``(-, 1, ps, 1, d)`` — one head
+    picked by a second-to-last block dim of 1 over K."""
     def mutate(op):
         if op.name not in ("k", "v"):
             return op
-        _, kb, ps, d = op.block_shape
-        n_pages, K = op.grid_blocks[0], op.grid_blocks[1] * kb
-        return dataclasses.replace(op, block_shape=(1, ps, 1, d),
-                                   grid_blocks=(n_pages, 1, K, 1),
+        _, _, kb, ps, d = op.block_shape
+        layers, n_pages = op.grid_blocks[0], op.grid_blocks[1]
+        K = op.grid_blocks[2] * kb
+        return dataclasses.replace(op, block_shape=(None, 1, ps, 1, d),
+                                   grid_blocks=(layers, n_pages, 1, K, 1),
                                    index_map=kv_map)
     return dataclasses.replace(spec, operands=tuple(map(mutate,
                                                         spec.operands)))
@@ -339,11 +340,13 @@ def test_k004_fires_on_seq_major_paged_blocks(kernel):
     if kernel == "flash_decode":
         spec = _seed_seq_major(
             fd_paged_spec(2, 4, 2, 128, 128, 16, 4, 9),
-            lambda b, kh, ik, pos, start, pages: (pages[b, 0], 0, kh, 0))
+            lambda b, kh, ik, pos, start, pages, layer: (
+                layer[0], pages[b, 0], 0, kh, 0))
     else:
         spec = _seed_seq_major(
             fa_paged_spec(2, 4, 2, 32, 128, 16, 4, 9),
-            lambda b, h, iq, ik, qs, kl, pages: (pages[b, 0], 0, h // 2, 0))
+            lambda b, h, iq, ik, qs, kl, pages, layer: (
+                layer[0], pages[b, 0], 0, h // 2, 0))
     fs = [f for f in check_kernel_spec(spec) if f.rule == "K004"]
     assert {f.message.split("'")[1] for f in fs} == {"k", "v"}
 

@@ -108,18 +108,38 @@ def test_paged_prefill_oracle_matches_dense(window, softcap):
         np.testing.assert_allclose(out[b], dense[0], atol=2e-5, rtol=2e-5)
 
 
+def _stack_layers(pool, layer, n=3, seed=0):
+    """``pool`` as layer ``layer`` of ``n`` stacked pools whose other layers
+    hold other values: a read that ignores the layer index reads those."""
+    rng = np.random.RandomState(seed)
+    layers = [jnp.asarray(rng.randn(*pool.shape), pool.dtype)
+              for _ in range(n)]
+    layers[layer] = pool
+    return jnp.stack(layers)
+
+
+@pytest.mark.parametrize("layer", [None, 0, 2])
 @pytest.mark.parametrize("window,softcap",
                          [(0, 0.0), (20, 0.0), (0, 15.0), (12, 9.0)])
-def test_paged_prefill_kernel_matches_oracle(window, softcap):
+def test_paged_prefill_kernel_matches_oracle(window, softcap, layer):
     """Interpret-mode Pallas kernel (scalar-prefetch page-table index map,
-    dead-block DMA elision) == the jnp oracle."""
+    dead-block DMA elision) == the jnp oracle.  ``layer``: the pools are
+    read as layer 0 or 2 of a stack of three whose other layers hold other
+    values (``None``: one layer's bare pools)."""
     q, kp, vp, pages, q_start, k_len = _rand_paged(seed=1)
     want = ref.flash_attention_paged_ref(q, kp, vp, pages, q_start, k_len,
                                          window=window, softcap=softcap)
+    if layer is not None:
+        kp, vp = (_stack_layers(kp, layer, seed=1),
+                  _stack_layers(vp, layer, seed=2))
     got = flash_attention(q, kp, vp, pages=pages, q_start=q_start,
-                          k_len=k_len, window=window, softcap=softcap,
-                          interpret=True)
+                          k_len=k_len, layer=layer, window=window,
+                          softcap=softcap, interpret=True)
+    oracle = ref.flash_attention_paged_ref(q, kp, vp, pages, q_start, k_len,
+                                           window=window, softcap=softcap,
+                                           layer=layer)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(oracle, want, atol=2e-5, rtol=2e-5)
 
 
 def test_paged_prefill_kernel_shared_kv_and_tail():

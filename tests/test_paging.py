@@ -288,7 +288,7 @@ def test_paged_kernel_head_blocks(monkeypatch, budget_heads):
     spec = decode_attention.fd_paged_spec(5, K * G, K, d, d, ps, 3, 10,
                                           itemsize=4)
     assert spec.grid == (5, K // budget_heads, 3)
-    assert spec.operands[1].block_shape == (1, budget_heads, ps, d)
+    assert spec.operands[1].block_shape == (None, 1, budget_heads, ps, d)
     q, kp, vp, tbl, pos, start = _slot_mix(K, G, ps, d, d, seed=21)
     want = ref.flash_decode_ref(q, kp, vp, pos, start, pages=tbl)
     got = flash_decode(q, kp, vp, pos, start, pages=tbl, interpret=True)
@@ -305,17 +305,85 @@ def test_paged_heads_largest_divisor_in_budget():
     assert fit(7, big, big, 1, 2) == 1  # no divisor but 1 fits
 
 
-def test_paged_kernel_window_via_start():
+def _stack_layers(pool, layer, n=3, seed=0):
+    """``pool`` as layer ``layer`` of ``n`` stacked pools whose other layers
+    hold other values: a read that ignores the layer index reads those."""
+    rng = np.random.RandomState(seed)
+    layers = [jnp.asarray(rng.randn(*pool.shape), pool.dtype)
+              for _ in range(n)]
+    layers[layer] = pool
+    return jnp.stack(layers)
+
+
+@pytest.mark.parametrize("layer", [None, 0, 2])
+def test_paged_kernel_window_via_start(layer):
     """Sliding windows under paging express validity as start = pos - w + 1
     over logical rows (no ring) — must equal the dense ring-free oracle
-    restricted to the window."""
+    restricted to the window.  ``layer``: the pools are read as layer 0 or
+    2 of a stack of three, through the kernel's layer index (``None``: one
+    layer's bare pools)."""
     q, kp, vp, tbl, kd, vd, pos = _paged_fixture(seed=5)
+    if layer is not None:
+        kp, vp = (_stack_layers(kp, layer, seed=1),
+                  _stack_layers(vp, layer, seed=2))
     w = 10
     start = jnp.maximum(pos - w + 1, 0)
-    got = flash_decode(q, kp, vp, pos, start, pages=tbl, interpret=True)
+    got = flash_decode(q, kp, vp, pos, start, pages=tbl, layer=layer,
+                       interpret=True)
+    oracle = ref.flash_decode_ref(q, kp, vp, pos, start, pages=tbl,
+                                  layer=layer)
     want = ref.flash_decode_ref(q, kd, vd, pos, start, layout="linear")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-6, rtol=1e-5)
+    for out in (got, oracle):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("ps", [8, 32])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_page_row_writes_land_in_place(kind, ps):
+    """The model's KV row writes into layer 1 of three stacked pools: each
+    row lands at ``(layer, pages[b, r // ps], :, r % ps)``; other layers,
+    rows and pages keep their values; rows off the end of the table and a
+    chunk's padding rows are dropped, never clamped.  Pages of 32 rows are
+    written a 16-row tile at a time, pages of 8 whole."""
+    from repro.models.layers import _page_row_write, _page_rows_write
+
+    L, P, K, d, npp, layer = 3, 9, 2, 4, 3, 1
+    rng = np.random.RandomState(ps)
+    pools = [rng.randn(L, P, K, ps, d).astype(np.float32) for _ in range(2)]
+    S = npp * ps
+    if kind == "decode":
+        # a row mid-page, one at the table's last row, one past its end
+        # (dropped) and a retired slot whose table is the trash page
+        tbl = np.array([[1, 2, 3], [4, 5, 6], [7, 8, 8], [0, 0, 0]])
+        pos = np.array([5, S - 1, S, 3])
+        rows = [rng.randn(4, K, d).astype(np.float32) for _ in range(2)]
+        got = _page_row_write(tuple(map(jnp.asarray, pools)),
+                              tuple(map(jnp.asarray, rows)),
+                              jnp.asarray(tbl, jnp.int32),
+                              jnp.asarray(pos, jnp.int32), jnp.int32(layer))
+        writes = [(b, pos[b], b, 0) for b in range(4)]
+    else:
+        # a chunk from mid-page with padding after 9 rows, and one that
+        # runs off the end of its table
+        C = ps + 4
+        tbl = np.array([[1, 2, 3], [4, 5, 6]])
+        pos0, n = np.array([5, S - 6]), np.array([9, C])
+        rows = [rng.randn(2, C, K, d).astype(np.float32) for _ in range(2)]
+        got = _page_rows_write(tuple(map(jnp.asarray, pools)),
+                               tuple(map(jnp.asarray, rows)),
+                               jnp.asarray(tbl, jnp.int32),
+                               jnp.asarray(pos0, jnp.int32),
+                               jnp.asarray(n, jnp.int32), jnp.int32(layer))
+        writes = [(b, pos0[b] + i, b, i) for b in range(2)
+                  for i in range(n[b])]
+    for pool, row, out in zip(pools, rows, got):
+        want = pool.copy()
+        for b, r, src, i in writes:
+            if r // ps < npp:
+                want[layer, tbl[b, r // ps], :, r % ps] = (
+                    row[src] if kind == "decode" else row[src, i])
+        np.testing.assert_array_equal(np.asarray(out), want)
 
 
 def test_paged_kernel_mla_fused_operand():

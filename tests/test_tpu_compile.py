@@ -26,7 +26,7 @@ BF16, I32, F32, I8 = jnp.bfloat16, jnp.int32, jnp.float32, jnp.int8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -41,9 +41,14 @@ def one_chip():
         enabled = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
         jax.config.update("jax_enable_compilation_cache", enabled)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_hlo(one_chip, fn, *shapes):
@@ -64,7 +69,8 @@ def test_flash_decode_paged(one_chip, page):
     pool, npp = _pool(page)
     spec = fd_paged_spec(B, H, K, DH, DH, page, npp, pool[0][0])
     assert spec.grid == (B, 1, npp)
-    assert [op.block_shape for op in spec.inputs[1:]] == [(1, K, page, DH)] * 2
+    assert ([op.block_shape for op in spec.inputs[1:]]
+            == [(None, 1, K, page, DH)] * 2)
     hlo = _compiled_hlo(
         one_chip,
         lambda q, k, v, pos, pages: flash_decode(q, k, v, pos, pages=pages),
@@ -156,3 +162,68 @@ def test_flash_attention_paged_tp4_shard(one_chip):
         ((1, TP_H, 256, DH), BF16), pool, pool, ((1, TP_NPP), I32),
         ((1,), I32), ((1,), I32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_paged_steps_keep_pools_in_place(topo, chips):
+    """The decode chunk and the mixed step as the chip runs them (Pallas
+    kernels, bf16, 64-row pages of 128-wide heads; 2 layers of 2048 and a
+    small vocabulary), on one chip and on a 1x4 mesh: the stacked KV pools
+    are written in place and read by the kernels in the layout they are
+    stored in, with no whole-pool copy, slice or transpose
+    (``tests/test_paged_pool_inplace.py`` has the scan).  A row-sized
+    update once made the compiler store the stack head-minor and copy it
+    to and from the kernels' layout every layer."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.configs.base import ArchConfig
+    from repro.launch.mesh import make_device_mesh
+    from repro.launch.sharding import tree_pspecs
+    from repro.models import model as M
+    from repro.models.params import shape_tree
+    from repro.serving import EngineConfig
+    from repro.serving.engine import ModelRunner
+    from test_paged_pool_inplace import pool_ops
+
+    cfg = ArchConfig(name="olmo-2l", family="dense", num_layers=2,
+                     d_model=D, num_heads=H, num_kv_heads=K, head_dim=DH,
+                     d_ff=512, vocab_size=512,
+                     norm_type="layernorm_nonparam", param_dtype=BF16,
+                     compute_dtype=BF16, kernel_mode="pallas")
+    Bs, npp, page, C = 4, 4, 64, 128
+    n_pages = Bs * npp + 1
+    r = ModelRunner(cfg, None, EngineConfig(
+        max_batch=Bs, max_len=npp * page, page_size=page, n_pages=n_pages,
+        chunk_tokens=C, decode_chunk=2, kernel_mode="pallas"))
+    rep = SingleDeviceSharding(topo.devices[0])
+    if chips > 1:
+        # the runner's steps take its mesh when they are wrapped for jit
+        r.mesh = make_device_mesh((1, chips), ("data", "model"),
+                                  devices=topo.devices)
+        r.tp = chips
+        r.decode_fn = jax.jit(r._traced(r._decode_chunk), donate_argnums=(1,))
+        rep = NamedSharding(r.mesh, PartitionSpec())
+
+    def sds(specs, dtype):
+        shapes = shape_tree(specs, dtype)
+        where = (tree_pspecs(specs, r.mesh) if chips > 1
+                 else jax.tree.map(lambda _: rep, shapes))
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, where)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    state = (sds(M.param_specs(cfg), BF16), sds(r.cache_specs, BF16))
+    dec = (arg((Bs, npp), I32), arg((Bs,), I32), arg((Bs,), I32),
+           arg((Bs,), I32), arg((Bs,), F32), arg((Bs, 2), jnp.uint32),
+           arg((Bs,), jnp.bool_))
+    mixed = (arg((1, C), I32), arg((1, npp), I32), arg((), I32),
+             arg((), I32), arg((), F32), arg((2,), jnp.uint32),
+             arg((), jnp.bool_), *dec)
+    for fn, args in ((r.decode_fn, dec), (r.mixed_fn(C, 1), mixed)):
+        hlo = fn.lower(*state, *args).compile().as_text()
+        assert "tpu_custom_call" in hlo
+        assert pool_ops(hlo, (n_pages, K, page, DH), layers=2,
+                        tp=chips) == []
