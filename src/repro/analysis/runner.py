@@ -14,7 +14,6 @@ promotion bug this tool exists to catch) and the requested kernel mode and
 quantization."""
 from __future__ import annotations
 
-import functools
 from typing import Iterable, Optional, Sequence
 
 import jax
@@ -116,7 +115,7 @@ def check_cell(name: str, mode: str, quant: str, report: Report,
     keys = jnp.zeros((_B, 2), jnp.uint32)
 
     def pfx(p, batch):
-        return M.prefill(eng.cfg, p, batch, full_kv=True)[0]
+        return M.prefill(eng.cfg, p, batch)[0]
 
     _lint_entry(report, pfx, (runner.params, _batch(eng.cfg)),
                 f"{base} entry=prefill", logits=True)
@@ -149,8 +148,8 @@ def check_cell(name: str, mode: str, quant: str, report: Report,
         wp_args = (runner.params, caches, jnp.zeros((1, n), jnp.int32),
                    jnp.zeros(npp, jnp.int32), jnp.int32(0), jnp.float32(0.0),
                    keys[0])
-        _lint_entry(report, functools.partial(runner._whole_prefill, n),
-                    wp_args, f"{base} entry=whole_prefill", donate=(1,))
+        _lint_entry(report, runner._whole_prefill, wp_args,
+                    f"{base} entry=whole_prefill", donate=(1,))
     else:
         # cross-attention prefill requires the image batch, which the
         # engine's tokens-only whole-prompt path cannot supply — the model's
@@ -224,10 +223,8 @@ def check_sharded(name: str, report: Report, params=None) -> None:
         wp_args = (runner.params, caches, jnp.zeros((1, n), jnp.int32),
                    jnp.zeros(npp, jnp.int32), jnp.int32(0), jnp.float32(0.0),
                    keys[0])
-        _lint_entry(report,
-                    runner._traced(functools.partial(runner._whole_prefill,
-                                                     n)),
-                    wp_args, f"{base} entry=whole_prefill", donate=(1,))
+        _lint_entry(report, runner._traced(runner._whole_prefill), wp_args,
+                    f"{base} entry=whole_prefill", donate=(1,))
         hlo = runner.whole_prefill_fn(n, 1).lower(*wp_args).compile() \
                     .as_text()
         report.extend(lint_hlo(hlo, shapes, f"{base} entry=whole_prefill "
@@ -241,7 +238,7 @@ def check_kernels(name: str, report: Report) -> None:
     Mode/quant-independent: the specs describe grid/index-map geometry,
     which is fixed by the architecture + engine cache geometry."""
     from repro.kernels.block_gemm import gemm_spec
-    from repro.kernels.decode_attention import fd_dense_spec, fd_paged_spec
+    from repro.kernels.decode_attention import fd_paged_spec
     from repro.kernels.flash_attention import fa_dense_spec, fa_paged_spec
 
     cfg = analysis_config(name, "reference", "none")
@@ -257,11 +254,6 @@ def check_kernels(name: str, report: Report) -> None:
         specs.append(fa_dense_spec(_B, H, K, _S, _S, d))
         if cfg.kind == "decoder":
             specs.append(fa_paged_spec(_B, H, K, ps, d, ps, npp, n_pages))
-            specs.append(fd_dense_spec(_B, H, K, ec.max_len, d, d,
-                                       layout="linear"))
-            if cfg.window_size:
-                specs.append(fd_dense_spec(_B, H, K, cfg.window_size, d, d,
-                                           layout="ring"))
             # the paged decode's head block is sized from the pool's dtype;
             # MLA decodes one fused [latent | rope] pool, read as v to kvr
             isz = jnp.dtype(cfg.compute_dtype).itemsize

@@ -8,7 +8,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.cache import CacheLayout
 from repro.kernels import ref
 from repro.kernels.block_gemm import block_gemm, block_gemm_int8
 from repro.kernels.decode_attention import flash_decode
@@ -83,27 +82,22 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                            interpret=(mode == "interpret"))
 
 
-def attend_decode(q, k, v, pos, start=None, *,
-                  layout: str | CacheLayout = CacheLayout.LINEAR,
-                  softcap=0.0, scale=None, dv=None, pages=None, layer=None,
-                  mode: str = "reference", bk=128):
-    """Batched single-token decode over a slot-indexed KV cache.
+def attend_decode(q, k, v, pos, start=None, *, pages, layer=None,
+                  softcap=0.0, scale=None, dv=None, mode: str = "reference"):
+    """Batched single-token decode over the paged KV pools.
 
-    Cache-native layout (no hot-path transposes): q: [B,H,dq];
-    k: [B,S,K,dq]; v: [B,S,K,>=dv] -> [B,H,dv].  ``pos``/``start`` are the
-    per-slot [B] validity bounds; ``layout`` is the :class:`CacheLayout`
-    (LINEAR global / RING sliding-window / PAGED block-table).  ``dv``
-    narrows the value read to the first dv columns — MLA latent decode
-    passes its concatenated ``[latent | k_rope]`` cache as both k and v.
-    ``pages`` ([B, npp] int32) switches k/v to head-major page pools
-    stacked over layers, ``[L, n_pages, K, page_size, d]``, indirected
-    through the table and read at the int32 scalar ``layer``.
+    q: [B,H,dq]; k/v: head-major page pools stacked over layers
+    ``[L, n_pages, K, page_size, d]``, indirected through the [B, npp]
+    int32 page tables ``pages`` and read at the int32 scalar ``layer``
+    -> [B,H,dv].  ``pos``/``start`` are the per-slot [B] validity bounds
+    (logical rows ``[start, pos]`` are live).  ``dv`` narrows the value read
+    to the first dv columns — MLA latent decode passes its fused
+    ``[latent | k_rope]`` pool as both k and v.
     """
-    layout = str(layout)
     if mode == "reference":
-        return ref.flash_decode_ref(q, k, v, pos, start, layout=layout,
-                                    softcap=softcap, scale=scale, dv=dv,
-                                    pages=pages, layer=layer)
-    return flash_decode(q, k, v, pos, start, layout=layout, softcap=softcap,
-                        scale=scale, dv=dv, bk=bk, pages=pages, layer=layer,
+        return ref.flash_decode_ref(q, k, v, pos, start, pages=pages,
+                                    layer=layer, softcap=softcap, scale=scale,
+                                    dv=dv)
+    return flash_decode(q, k, v, pos, start, pages=pages, layer=layer,
+                        softcap=softcap, scale=scale, dv=dv,
                         interpret=(mode == "interpret"))

@@ -120,34 +120,25 @@ def flash_attention_paged_ref(q, k, v, pages, q_start, k_len, *, window=0,
                       preferred_element_type=F32).astype(vb.dtype)
 
 
-def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
-                     softcap=0.0, scale=None, dv=None, pages=None,
-                     layer=None):
-    """Oracle for ``flash_decode``: batched single-token decode over a
-    slot-indexed cache in its native layout.  q: [B,H,dq]; k: [B,S,K,dq];
-    v: [B,S,K,>=dv]; pos/start: [B] int32 (broadcastable).  ``layout``:
-    "linear" (rows ``[start, pos]`` live) or "ring" (entry j holds absolute
-    row ``pos - ((pos - j) mod S)``; live iff that row is
-    ``>= max(start, 0)``).  ``dv`` reads only the first dv value columns
-    (MLA passes one concatenated cache as both k and v).  All-invalid slots
-    return zeros.
-
-    Paged path: ``pages`` [B, npp] int32 page tables over head-major pools
-    k/v stacked over layers, [L, n_pages, K, page_size, d], read at
-    ``layer`` (bare [n_pages, K, page_size, d] pools with ``layer=None``);
-    logical row ``r`` of slot ``b`` lives at
-    ``(layer, pages[b, r // page_size], :, r % page_size)``.  The oracle
-    gathers each slot's pages into a dense [B, npp * page_size, K, d] cache
-    and falls through to the linear rule — the page table is pure
-    indirection, the validity semantics are unchanged."""
-    if pages is not None:
-        assert layout in ("linear", "paged"), layout
-        pages = jnp.asarray(pages, jnp.int32)
-        shared = v is k
-        k, v, layer = layer_pools(k, v, layer)
-        k = gather_pages(k, pages, layer)
-        v = k if shared else gather_pages(v, pages, layer)
-        layout = "linear"
+def flash_decode_ref(q, k, v, pos, start=None, *, pages, layer=None,
+                     softcap=0.0, scale=None, dv=None):
+    """Oracle for ``flash_decode``: batched single-token decode over paged
+    pools.  q: [B,H,dq]; k/v: head-major pools stacked over layers
+    [L, n_pages, K, page_size, d], read at ``layer`` (bare
+    [n_pages, K, page_size, d] pools with ``layer=None``); pages: [B, npp]
+    int32 page tables; pos/start: [B] int32 (broadcastable).  Logical row
+    ``r`` of slot ``b`` lives at
+    ``(layer, pages[b, r // page_size], :, r % page_size)`` and rows
+    ``[start, pos]`` are live.  The oracle gathers each slot's pages into a
+    dense [B, npp * page_size, K, d] cache and applies that rule there — the
+    page table is pure indirection.  ``dv`` reads only the first dv value
+    columns (MLA passes one fused pool as both k and v).  All-invalid slots
+    return zeros."""
+    pages = jnp.asarray(pages, jnp.int32)
+    shared = v is k
+    k, v, layer = layer_pools(k, v, layer)
+    k = gather_pages(k, pages, layer)
+    v = k if shared else gather_pages(v, pages, layer)
     B, H, dq = q.shape
     S, K = k.shape[1], k.shape[2]
     G = H // K
@@ -162,11 +153,7 @@ def flash_decode_ref(q, k, v, pos, start=None, *, layout="linear",
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     j = jnp.arange(S)[None, :]
-    if layout == "ring":
-        a = pos[:, None] - jnp.mod(pos[:, None] - j, S)
-        valid = (a >= 0) & (a >= start[:, None])
-    else:
-        valid = (j >= start[:, None]) & (j <= pos[:, None])
+    valid = (j >= start[:, None]) & (j <= pos[:, None])
     vm = valid[:, None, None, :]
     s = jnp.where(vm, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)

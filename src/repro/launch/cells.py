@@ -17,6 +17,7 @@ from repro.configs.base import ArchConfig, ShapeConfig
 from repro.launch import sharding as SH
 from repro.models import model as M
 from repro.models.params import ParamSpec, shape_tree
+from repro.serving.config import EngineConfig
 from repro.training.optimizer import AdamWConfig
 from repro.training.step import make_train_step, state_shapes
 
@@ -123,24 +124,29 @@ def build_cell(cfg0: ArchConfig, shape: ShapeConfig, mesh: Mesh, *,
                  _batch_shardings(cfg, shape, mesh))
         return Cell(fn, (ps, bt), in_sh, (), cfg)
 
-    # decode
-    def fn(params, caches, token, pos):
-        return M.decode_step(cfg, params, caches, token, pos,
+    # decode: one token per slot over the paged cache, each slot holding
+    # a full table of the serving engine's default pages
+    page = EngineConfig.page_size
+    npp = -(-S // page)
+
+    def fn(params, caches, token, pos, pages):
+        return M.decode_step(cfg, params, caches, token, pos, pages=pages,
                              main_repeats=main_repeats)
     ps = M.param_shapes(cfg, main_repeats)
-    cs_specs = M.cache_specs(cfg, B, S, main_repeats)
+    cs_specs = M.paged_cache_specs(cfg, B, B * npp + 1, page, main_repeats)
     cs = shape_tree(cs_specs, cfg.compute_dtype)
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
     pos = jax.ShapeDtypeStruct((), jnp.int32)
+    pages = jax.ShapeDtypeStruct((B, npp), jnp.int32)
     in_sh = (_params_shardings(cfg, mesh, main_repeats),
              SH.tree_pspecs(cs_specs, mesh, fsdp=False,
                             profile=SH.profile_for(cfg)),
              NamedSharding(mesh, SH.batch_pspec(mesh) if B > 1 else P()),
-             SH.replicated(mesh))
+             SH.replicated(mesh), SH.replicated(mesh))
     # token sharding: batch axis must divide B
     dp = 1
     for a in ("pod", "data"):
         dp *= mesh.shape.get(a, 1)
     if B % dp:
-        in_sh = (in_sh[0], in_sh[1], SH.replicated(mesh), in_sh[3])
-    return Cell(fn, (ps, cs, tok, pos), in_sh, (1,), cfg)
+        in_sh = (in_sh[0], in_sh[1], SH.replicated(mesh), *in_sh[3:])
+    return Cell(fn, (ps, cs, tok, pos, pages), in_sh, (1,), cfg)

@@ -1,12 +1,12 @@
 from repro.models import layers, model, params, ssd  # noqa: F401
 from repro.models.model import (  # noqa: F401
-    cache_shapes,
-    cache_specs,
     decode_step,
     init,
-    init_cache,
+    init_paged_cache,
     loss_fn,
+    paged_cache_specs,
     param_shapes,
     param_specs,
     prefill,
+    write_prefill,
 )

@@ -12,7 +12,7 @@ All dense projections funnel through :func:`dense_proj` (which also serves
 int8 ``QTensor`` weights, ``cfg.quant == "w8a8"``), forward/prefill
 attention through :func:`dispatch_attend`, and single-token decode
 attention through :func:`dispatch_attend_decode` (the flash-decode kernel
-over the slot-indexed KV cache); see DESIGN.md §2/§6.
+over the paged KV pools); see DESIGN.md §2/§6.
 """
 from __future__ import annotations
 
@@ -26,7 +26,6 @@ import math
 
 from repro.configs.base import ArchConfig
 from repro.core import round_up
-from repro.core.cache import CacheLayout
 from repro.core.gemm import cgra_gemm, cgra_gemm_w8a8
 from repro.core.quant import QTensor
 from repro.kernels.ops import attend_decode as kernel_attend_decode
@@ -146,8 +145,8 @@ def dispatch_attend(cfg: ArchConfig, q, k, v, q_pos, k_pos, *, causal: bool,
 
     The flash kernel path covers the contiguous self/cross-attention pattern
     used by forward/prefill (positions are aranges with the last query
-    aligned with the last key — ``Sq < Sk`` is suffix prefill over a cached
-    prefix), preserving GQA grouping, sliding windows and logit softcap.
+    aligned with the last key), preserving GQA grouping, sliding windows and
+    logit softcap.
     The jnp ``attend`` stays the oracle for ``kernel_mode="reference"`` and
     for the roofline ATTN_STUB traffic stand-in; MLA keeps ``attend``
     unconditionally (its q/v head dims differ, which the prefill kernel
@@ -179,38 +178,34 @@ def dispatch_attend(cfg: ArchConfig, q, k, v, q_pos, k_pos, *, causal: bool,
     return o.transpose(0, 2, 1, 3)
 
 
-def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *,
-                           layout: str | CacheLayout = CacheLayout.LINEAR,
-                           softcap: float = 0.0, scale=None,
-                           dv: int | None = None, pages=None, layer=None):
+def dispatch_attend_decode(cfg: ArchConfig, q, k, v, pos, start, *, pages,
+                           layer, softcap: float = 0.0, scale=None,
+                           dv: int | None = None):
     """kernel_mode-aware single-token decode core.
 
-    q [B,1,H,dq] and a slot cache k/v [B,S,K,d] (or, with ``pages``,
-    head-major pools stacked over layers [L,P,K,ps,d], read at the int32
-    scalar ``layer``) -> [B,1,H,dv].  The stacked paged pool is the
-    kernel's own layout, so the serving hot path never copies it, nor
-    slices a layer out of it.
-    ``pos``/``start`` are the per-slot [B] validity bounds (cache row of
-    the current token / first live row — sliding-window layers on a linear
-    or paged cache pass ``max(0, pos - window + 1)``); ``layout`` is the
-    :class:`CacheLayout` validity rule; ``dv`` narrows the value read (MLA
-    passes one concatenated cache as both k and v); ``pages`` ([B, npp])
-    switches k/v to page pools indirected through the per-slot page table.
+    q [B,1,H,dq] and head-major page pools k/v stacked over layers
+    [L,P,K,ps,d], read at the int32 scalar ``layer`` through the [B, npp]
+    page tables ``pages`` -> [B,1,H,dv].  The stacked pool is the kernel's
+    own layout, so the serving hot path never copies it, nor slices a layer
+    out of it.
+    ``pos``/``start`` are the per-slot [B] validity bounds (logical row of
+    the current token / first live row — sliding-window layers pass
+    ``max(0, pos - window + 1)``); ``dv`` narrows the value read (MLA
+    passes one fused pool as both k and v).
     Routes to the jnp oracle (``reference``) or the flash-decode Pallas
-    kernel (``interpret`` | ``pallas``), which streams only live k-blocks.
+    kernel (``interpret`` | ``pallas``), which streams only live pages.
     Under a mesh the kernel runs per-KV-head-shard inside ``shard_map``
     (page tables / validity bounds replicated, head fold untouched — each
     shard keeps its full query groups); MLA's fused single-KV-head pool and
     other non-divisible head counts run replicated.
     """
     q0 = q[:, 0]
-    call = functools.partial(kernel_attend_decode, layout=layout,
-                             softcap=softcap, scale=scale, dv=dv,
-                             mode=cfg.kernel_mode)
+    call = functools.partial(kernel_attend_decode, softcap=softcap,
+                             scale=scale, dv=dv, mode=cfg.kernel_mode)
     mesh, tp = _tp_mesh(cfg)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        kax = 2  # stacked pool [L,P,K,ps,d] / slot cache [B,S,K,d]
+        kax = 2  # stacked pool [L,P,K,ps,d]
         hs = "model" if (q0.shape[1] % tp == 0 and k.shape[kax] % tp == 0) \
             else None
         qspec = P(None, hs, None)            # q [B, H, dq]
@@ -412,12 +407,11 @@ def attn_forward(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
                       shard=("row", cfg.padded_heads))
 
 
-def attn_cache_specs(cfg: ArchConfig, batch: int, seq: int, local: bool) -> dict:
+def attn_cache_specs(cfg: ArchConfig, batch: int, seq: int) -> dict:
     K, dh = cfg.num_kv_heads, cfg.head_dim
-    S = min(seq, cfg.window_size) if (local and cfg.window_size) else seq
     return {
-        "k": ParamSpec((batch, S, K, dh), ("batch", "kv_seq", "kv_heads", "qk"), "zeros"),
-        "v": ParamSpec((batch, S, K, dh), ("batch", "kv_seq", "kv_heads", "qk"), "zeros"),
+        "k": ParamSpec((batch, seq, K, dh), ("batch", "kv_seq", "kv_heads", "qk"), "zeros"),
+        "v": ParamSpec((batch, seq, K, dh), ("batch", "kv_seq", "kv_heads", "qk"), "zeros"),
     }
 
 
@@ -468,41 +462,22 @@ def _page_row_write(pools: tuple, new_rows: tuple, pages, pos, layer):
 
 
 def attn_prefill(cfg: ArchConfig, p: dict, x, positions, *, local: bool,
-                 attn_chunk: int = 0, past_kv=None, full_cache: bool = False):
-    """Returns (out, cache).  Cache keys are post-RoPE (standard practice).
-
-    ``positions``: [S] absolute positions of the prompt rows (for suffix
-    prefill over a cached prefix of length ``s``, ``s + arange(S)``).
-    ``past_kv`` ({"k","v"}: [B, s, K, dh], post-RoPE) is that prefix's KV,
-    gathered from the paged cache — attention runs over the dense
-    concat(past, new) with the last query aligned with the last key, and
-    the returned cache holds only the NEW rows (the caller owns the prefix
-    pages already).  ``full_cache`` keeps sliding-window layers' full
-    linear k/v instead of the rolled ring (the paged engine stores every
-    row and windows via decode validity).
-    """
+                 attn_chunk: int = 0):
+    """Whole-prompt prefill.  Returns (out, cache): the prompt's full
+    post-RoPE KV rows, ``{"k","v"}`` [B, S, K, dh] — sliding-window layers
+    included, since the paged cache stores every row and windows at decode
+    time through the validity bound.  ``positions``: [S] absolute positions
+    of the prompt rows."""
     q, k, v = _qkv(cfg, p, x, x)
     theta = cfg.rope_theta if not local else 10_000.0
     q = rope(q, positions, theta)
     k = rope(k, positions, theta)
     window = cfg.window_size if local else 0
-    k_all, v_all = k, v
-    if past_kv is not None:
-        k_all = jnp.concatenate([past_kv["k"].astype(k.dtype), k], axis=1)
-        v_all = jnp.concatenate([past_kv["v"].astype(v.dtype), v], axis=1)
-    k_pos = jnp.arange(k_all.shape[1], dtype=jnp.int32)
-    o = dispatch_attend(cfg, q, k_all, v_all, positions, k_pos, causal=True,
+    o = dispatch_attend(cfg, q, k, v, positions, positions, causal=True,
                         window=window, chunk=attn_chunk,
                         softcap=cfg.logit_softcap)
     out = dense_proj(cfg, o.reshape(*o.shape[:-2], -1), p["wo"],
                      shard=("row", cfg.padded_heads))
-    if window and not full_cache and past_kv is None and k.shape[1] > window:
-        # ring-buffer cache: keep the last `window` keys, rolled so entry
-        # (pos % window) holds absolute position pos — decode continues the
-        # ring seamlessly
-        S = k.shape[1]
-        k = jnp.roll(k[:, -window:], (S - window) % window, axis=1)
-        v = jnp.roll(v[:, -window:], (S - window) % window, axis=1)
     return out, {"k": k, "v": v}
 
 
@@ -611,59 +586,38 @@ def attn_chunk_prefill(cfg: ArchConfig, p: dict, cache: dict, x, positions, *,
 
 
 def attn_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, local: bool,
-                pages=None, layer=None):
-    """One-token decode.  x: [B,1,D]; pos: scalar int32 or [B] int32 (cache
-    row of the current token, per batch slot — continuous batching runs
-    every slot at its own offset).
+                pages, layer):
+    """One-token decode.  x: [B,1,D]; pos: scalar int32 or [B] int32
+    (logical row of the current token, per batch slot — continuous batching
+    runs every slot at its own offset).
 
-    Unpaged: local layers use a ring-buffer cache of size `window` (write
-    at ``pos % window``); global layers write at ``pos``.  A global-layer
-    write at ``pos >= S`` is *dropped* (``mode="drop"``) rather than
-    clamped onto the last slot — overrunning the cache must never corrupt
-    slot ``S-1``; the serving engine refuses to decode past capacity
-    (explicit length error) before this can happen.
-
-    Paged (``pages`` given): the cache is page pools stacked over layers
-    [L, P, K, ps, dh], shared across the batch, this layer at the int32
-    scalar ``layer``; the write lands in place at the page-table row for
-    ``pos`` and attention follows the table (CacheLayout.PAGED).
-    Sliding-window layers store full rows like global ones and window via
-    the validity lower bound ``start = max(0, pos - window + 1)`` — no ring
-    under paging.
+    The cache is page pools stacked over layers [L, P, K, ps, dh], shared
+    across the batch, this layer at the int32 scalar ``layer``; pages:
+    [B, npp] page tables.  The new row is written in place at the
+    page-table row for ``pos`` (a row past the table's end is dropped,
+    never clamped — the engine refuses to decode past capacity first) and
+    attention follows the table.  Sliding-window layers store full rows
+    like global ones and window via the validity lower bound
+    ``start = max(0, pos - window + 1)``.
 
     The attention core routes through :func:`dispatch_attend_decode`;
     RoPE is pre-applied to cached keys, so scores need no position
     reconstruction.
     """
     B = x.shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))  # slot-indexed
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))  # one per slot
     q, k_new, v_new = _qkv(cfg, p, x, x)
     theta = cfg.rope_theta if not local else 10_000.0
     rp = pos[:, None]
     q = rope(q, rp, theta)
     k_new = rope(k_new, rp, theta)
     window = cfg.window_size if local else 0
-    if pages is not None:
-        pages = jnp.asarray(pages, jnp.int32)
-        k, v = _page_row_write((cache["k"], cache["v"]),
-                               (k_new[:, 0], v_new[:, 0]), pages, pos, layer)
-        start = jnp.maximum(pos - window + 1, 0) if window else None
-        o = dispatch_attend_decode(cfg, q, k, v, pos, start,
-                                   layout=CacheLayout.PAGED, pages=pages,
-                                   layer=layer, softcap=cfg.logit_softcap)
-    else:
-        S = cache["k"].shape[1]
-        ring = bool(local and cfg.window_size)
-        widx = (pos % S) if ring else pos
-        bidx = jnp.arange(B)
-        k = cache["k"].at[bidx, widx].set(k_new[:, 0].astype(cache["k"].dtype),
-                                          mode="drop")
-        v = cache["v"].at[bidx, widx].set(v_new[:, 0].astype(cache["v"].dtype),
-                                          mode="drop")
-        o = dispatch_attend_decode(
-            cfg, q, k, v, pos, None,
-            layout=CacheLayout.RING if ring else CacheLayout.LINEAR,
-            softcap=cfg.logit_softcap)
+    pages = jnp.asarray(pages, jnp.int32)
+    k, v = _page_row_write((cache["k"], cache["v"]),
+                           (k_new[:, 0], v_new[:, 0]), pages, pos, layer)
+    start = jnp.maximum(pos - window + 1, 0) if window else None
+    o = dispatch_attend_decode(cfg, q, k, v, pos, start, pages=pages,
+                               layer=layer, softcap=cfg.logit_softcap)
     H = q.shape[2]
     o = o.reshape(B, 1, H * v.shape[-1])
     out = dense_proj(cfg, o, p["wo"], shard=("row", cfg.padded_heads))
@@ -727,9 +681,9 @@ def mla_forward(cfg: ArchConfig, p: dict, x, positions, attn_chunk: int = 0):
 
 
 def mla_cache_specs(cfg: ArchConfig, batch: int, seq: int) -> dict:
-    # one fused [latent | k_rope] cache per layer: decode reads it as both
+    # one fused [latent | k_rope] pool per layer: decode reads it as both
     # keys (full width) and values (first kv_lora_rank columns), so the hot
-    # path never concatenates or slices the cache
+    # path never concatenates or slices the pool
     return {
         "kv": ParamSpec((batch, seq, cfg.kv_lora_rank + cfg.qk_rope_dim),
                         ("batch", "kv_seq", None), "zeros"),
@@ -743,25 +697,25 @@ def mla_prefill(cfg: ArchConfig, p: dict, x, positions, attn_chunk: int = 0):
                                         k_rope.astype(latent.dtype)], -1)}
 
 
-def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
+def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages):
     """Weight-absorbed MLA decode: attention runs in the latent space, so the
     per-step cost is O(S * kv_lora_rank) instead of O(S * H * head_dim) —
     the cached latent is never re-expanded.  (This is the paper's data-reuse
     insight applied to the KV cache.)
 
     The latent-space core is the flash-decode kernel in MQA form: queries
-    ``[q_absorbed | q_rope]`` against the fused ``[latent | k_rope]`` cache,
+    ``[q_absorbed | q_rope]`` against the fused ``[latent | k_rope]`` pool,
     which is passed as *both* keys (full width, qk dim ``kvr +
     qk_rope_dim``) and values (first ``kvr`` columns, selected by the
-    BlockSpec — no slicing copy).  With ``pages`` the cache is a
-    [P, ps, kvr+dr] page pool (read as a stack of one head-major
-    [1, P, 1, ps, kvr+dr] pool of one latent head, at layer 0) written
-    through the per-slot table, exactly as in :func:`attn_decode`.
+    BlockSpec — no slicing copy).  The cache is a [P, ps, kvr+dr] page pool
+    (read as a stack of one head-major [1, P, 1, ps, kvr+dr] pool of one
+    latent head, at layer 0) written through the per-slot page tables
+    ``pages``, exactly as in :func:`attn_decode`.
     """
     dn, dv = cfg.qk_nope_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
     B = x.shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))  # slot-indexed
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))  # one per slot
     rp = pos[:, None]
     q_nope, q_rope = _mla_q(cfg, p, x, rp)  # [B,1,H,dn],[B,1,H,dr]
     latent_new, k_rope_new = _mla_latent(cfg, p, x, rp)
@@ -769,18 +723,11 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
     # (same invariant as attn_decode; the engine errors before this happens)
     row = jnp.concatenate([latent_new, k_rope_new.astype(latent_new.dtype)],
                           -1)[:, 0]
-    if pages is not None:
-        pages = jnp.asarray(pages, jnp.int32)
-        layer = jnp.int32(0)
-        kv4, = _page_row_write((cache["kv"][None, :, None],), (row[:, None],),
-                               pages, pos, layer)
-        kv = kv4[0, :, 0]  # kv4 [1,P,1,ps,kvr+dr]: same array as k AND v
-    else:
-        bidx = jnp.arange(B)
-        kv = cache["kv"].at[bidx, pos].set(row.astype(cache["kv"].dtype),
-                                           mode="drop")
-        kv4 = kv[:, :, None]  # [B,S,1,kvr+dr]; same array as k AND v
-        layer = None
+    pages = jnp.asarray(pages, jnp.int32)
+    layer = jnp.int32(0)
+    kv4, = _page_row_write((cache["kv"][None, :, None],), (row[:, None],),
+                           pages, pos, layer)
+    kv = kv4[0, :, 0]  # kv4 [1,P,1,ps,kvr+dr]: same array as k AND v
     wkv_b = p["wkv_b"].astype(cfg.compute_dtype)  # [kvr, H, dn+dv]
     wk, wv = wkv_b[..., :dn], wkv_b[..., dn:]
     # absorb: q_lat[b,h,r] = sum_d q_nope[b,h,d] wk[r,h,d]
@@ -788,10 +735,8 @@ def mla_decode(cfg: ArchConfig, p: dict, cache: dict, x, pos, *, pages=None):
                        preferred_element_type=F32).astype(q_nope.dtype)
     q_cat = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1)
     o_lat = dispatch_attend_decode(
-        cfg, q_cat, kv4, kv4, pos, None,
-        layout=CacheLayout.PAGED if pages is not None else CacheLayout.LINEAR,
-        pages=pages, layer=layer, scale=(dn + cfg.qk_rope_dim) ** -0.5,
-        dv=kvr)
+        cfg, q_cat, kv4, kv4, pos, None, pages=pages, layer=layer,
+        scale=(dn + cfg.qk_rope_dim) ** -0.5, dv=kvr)
     o = jnp.einsum("bshr,rhd->bshd", o_lat, wv,  # expand to v space
                    preferred_element_type=F32).astype(o_lat.dtype)
     out = dense_proj(cfg, o.reshape(*o.shape[:-2], -1), p["wo"],

@@ -18,7 +18,8 @@ from repro.core.quant import QTensor
 from repro.launch.sharding import constrain
 from repro.models import layers as L
 from repro.models import ssd as S
-from repro.models.params import ParamSpec, init_params, shape_tree, stack_tree
+from repro.models.params import (ParamSpec, init_params, is_spec, shape_tree,
+                                  stack_tree)
 
 F32 = jnp.float32
 
@@ -175,19 +176,19 @@ def _layer_cache_specs(cfg: ArchConfig, spec: LayerSpec, batch: int, seq: int):
     if cfg.use_mla:
         return L.mla_cache_specs(cfg, batch, seq)
     if spec.mixer == "cross":
-        c = L.attn_cache_specs(cfg, batch, seq, local=False)
+        c = L.attn_cache_specs(cfg, batch, seq)
         K, dh, T = cfg.num_kv_heads, cfg.head_dim, cfg.vision_tokens
         c["ck"] = ParamSpec((batch, T, K, dh), ("batch", None, "kv_heads", "qk"), "zeros")
         c["cv"] = ParamSpec((batch, T, K, dh), ("batch", None, "kv_heads", "qk"), "zeros")
         return c
-    return L.attn_cache_specs(cfg, batch, seq, local=(spec.mixer == "attn_local"))
+    return L.attn_cache_specs(cfg, batch, seq)
 
 
-def cache_specs(cfg: ArchConfig, batch: int, seq: int,
-                main_repeats: int | None = None) -> list:
-    """Decode-cache spec tree.  ``batch`` is the number of serving *slots*:
-    the continuous-batching engine allocates this once at ``[slots, max_len]``
-    and recycles rows, so ``seq`` is a fixed capacity, not a growing length."""
+def _cache_specs(cfg: ArchConfig, batch: int, seq: int,
+                 main_repeats: int | None = None) -> list:
+    """Per-slot template of the decode-cache tree, ``[batch, seq, ...]``
+    leaves stacked over each stage's layers, that :func:`paged_cache_specs`
+    rewrites into page pools."""
     out = []
     for stage in cfg.stages(main_repeats):
         group = {str(i): _layer_cache_specs(cfg, sp, batch, seq)
@@ -196,30 +197,18 @@ def cache_specs(cfg: ArchConfig, batch: int, seq: int,
     return out
 
 
-def cache_shapes(cfg: ArchConfig, batch: int, seq: int,
-                 main_repeats: int | None = None):
-    return shape_tree(cache_specs(cfg, batch, seq, main_repeats), cfg.compute_dtype)
-
-
-def init_cache(cfg: ArchConfig, batch: int, seq: int):
-    tree = cache_specs(cfg, batch, seq)
-    return jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype or cfg.compute_dtype),
-        tree, is_leaf=lambda x: isinstance(x, ParamSpec))
-
-
 def paged_cache_specs(cfg: ArchConfig, max_batch: int, n_pages: int,
                       page_size: int, main_repeats: int | None = None) -> list:
-    """Paged decode-cache spec tree: every ``kv_seq`` leaf becomes a page
-    *pool* shared across sequences (per-sequence page tables map logical
-    rows to pool pages; page 0 is the engine's reserved trash page).  Pools
-    are head-major, ``[n_pages, K, page_size, d]`` for attention KV, so one
+    """The decode-cache spec tree: every ``kv_seq`` leaf is a page *pool*
+    shared across sequences (per-sequence page tables map logical rows to
+    pool pages; page 0 is the engine's reserved trash page).  Pools are
+    head-major, ``[n_pages, K, page_size, d]`` for attention KV, so one
     Pallas block is one head's ``[page_size, d]`` tile; MLA's headless
     latent pool is ``[n_pages, page_size, d]``.  Sliding-window layers get
-    full-size pages like global ones — under paging they window via the
-    decode validity bound, not a ring.  Leaves without a ``kv_seq`` axis
-    (SSM state, cross-attn image KV) stay slot-indexed ``[max_batch, ...]``."""
-    specs = cache_specs(cfg, max_batch, page_size, main_repeats)
+    full-size pages like global ones and window via the decode validity
+    bound.  Leaves without a ``kv_seq`` axis (SSM state, cross-attn image
+    KV) stay slot-indexed ``[max_batch, ...]``."""
+    specs = _cache_specs(cfg, max_batch, page_size, main_repeats)
 
     def to_pool(spec):
         if "kv_seq" not in spec.axes:
@@ -227,7 +216,7 @@ def paged_cache_specs(cfg: ArchConfig, max_batch: int, n_pages: int,
         axes, shape = list(spec.axes), list(spec.shape)
         b, s = axes.index("batch"), axes.index("kv_seq")
         axes[b] = None  # the pool's page axis is not a batch axis
-        shape[b], shape[s] = n_pages, page_size  # window rings un-shrunk
+        shape[b] = n_pages
         if "kv_heads" in axes:  # [.., kv_seq, kv_heads, d] -> head-major
             h = axes.index("kv_heads")
             axes.insert(s, axes.pop(h))
@@ -245,27 +234,28 @@ def init_paged_cache(cfg: ArchConfig, max_batch: int, n_pages: int,
         tree, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
-def pad_cache_len(cfg: ArchConfig, caches, new_len: int,
-                  main_repeats: int | None = None):
-    """Zero-pad every ``kv_seq`` dim of a prefill cache tree up to
-    ``new_len`` rows (decode capacity).  Replaces the deleted ``grow_cache``
-    for the direct ``prefill(...)`` → ``decode_step`` loop; the serving
-    engine allocates fixed-capacity paged pools instead."""
-    specs = cache_specs(cfg, 1, new_len, main_repeats)
+def write_prefill(cfg: ArchConfig, caches, small, table, slot):
+    """Write one sequence's whole-prompt prefill into the paged cache tree.
 
-    def grow(spec, leaf):
+    ``small`` is the cache tree :func:`prefill` returns for a batch of one
+    (``n`` prompt rows); ``table``: [npp] int32, the sequence's page table;
+    ``slot``: its batch row.  ``kv_seq`` leaves write their ``n`` rows to
+    logical rows ``[0, n)`` through the table; stateful leaves (SSM state,
+    cross-attention image KV) overwrite batch row ``slot``."""
+    specs = paged_cache_specs(cfg, 1, 1, 1)  # leaf formats only
+
+    def w(spec, pool, sm):
+        rows = sm[:, 0].astype(pool.dtype)  # [R, n, (K,) d]
         if "kv_seq" not in spec.axes:
-            return leaf
-        axis = spec.axes.index("kv_seq")
-        pad = spec.shape[axis] - leaf.shape[axis]
-        if pad <= 0:
-            return leaf
-        widths = [(0, 0)] * leaf.ndim
-        widths[axis] = (0, pad)
-        return jnp.pad(leaf, widths)
+            return pool.at[:, slot].set(rows)
+        ps = pool.shape[spec.axes.index("kv_seq")]
+        j = jnp.arange(rows.shape[1], dtype=jnp.int32)
+        page, off = table[j // ps], j % ps
+        if "kv_heads" in spec.axes:  # head-major pool [R, P, K, ps, d]
+            return pool.at[:, page, :, off].set(jnp.moveaxis(rows, 1, 0))
+        return pool.at[:, page, off].set(rows)  # MLA pool [R, P, ps, d]
 
-    return jax.tree.map(grow, specs, caches,
-                        is_leaf=lambda x: isinstance(x, ParamSpec))
+    return jax.tree.map(w, specs, caches, small, is_leaf=is_spec)
 
 
 # ---------------------------------------------------------------------------
@@ -274,22 +264,18 @@ def pad_cache_len(cfg: ArchConfig, caches, new_len: int,
 
 def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x, *, positions,
                  img, mode: str, cache=None, pos=None, pages=None,
-                 full_kv: bool = False, attn_chunk: int = 0, chunk_len=None,
-                 layer=None):
+                 attn_chunk: int = 0, chunk_len=None, layer=None):
     """Returns (x, new_cache, aux).
 
-    decode: ``cache`` is the layer's KV cache (slot-indexed, or, when
-    ``pages`` [B, npp] is given, page pools: the head-major attention pools
-    stacked over the stage's layers, this one at the int32 scalar
-    ``layer`` — see :func:`_carried`).  prefill: ``cache``, if set, is the
-    layer's *past* KV ({"k","v"} [B, s, K, dh], post-RoPE — a radix-cache
-    prefix hit) and ``positions`` must already be offset by ``s``;
-    ``full_kv`` keeps sliding-window layers' full linear KV (paged serving)
-    instead of the rolled ring.  chunk (chunked prefill): ``cache`` is the
-    layer's page *pools*, ``pages`` the [B, npp] tables, ``chunk_len`` the
-    valid rows in the chunk buffer — only prefix-decomposable mixers
-    (pure attention) support it; SSM/MLA/cross raise.  The stacked pools
-    come back updated in place."""
+    decode: ``cache`` is the layer's paged cache — the head-major attention
+    pools stacked over the stage's layers, this one at the int32 scalar
+    ``layer`` (see :func:`_carried`) — and ``pages`` the [B, npp] page
+    tables.  prefill: no cache in; the layer returns the prompt's full KV.
+    chunk (chunked prefill): ``cache`` is the layer's page *pools*,
+    ``pages`` the [B, npp] tables, ``chunk_len`` the valid rows in the
+    chunk buffer — only prefix-decomposable mixers (pure attention) support
+    it; SSM/MLA/cross raise.  The stacked pools come back updated in
+    place."""
     aux = jnp.zeros((), F32)
     h = L.apply_norm(cfg, p["norm1"], x)
     new_cache = None
@@ -351,8 +337,7 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x, *, positions,
                                                 layer=layer)
         elif mode == "prefill":
             m, new_cache = L.attn_prefill(cfg, p["mixer"], h, positions, local=local,
-                                          attn_chunk=attn_chunk, past_kv=cache,
-                                          full_cache=full_kv)
+                                          attn_chunk=attn_chunk)
         else:
             m = L.attn_forward(cfg, p["mixer"], h, positions, local=local,
                                attn_chunk=attn_chunk)
@@ -381,15 +366,15 @@ def _remat(cfg: ArchConfig, fn):
     return jax.checkpoint(fn)  # "full": save nothing
 
 
-def _carried(cfg: ArchConfig, spec: LayerSpec, mode: str, pages) -> set:
+def _carried(cfg: ArchConfig, spec: LayerSpec, mode: str) -> set:
     """Keys of a layer's cache that stay one stacked buffer ``[L, ...]``
     through the stage's layer loop: the head-major attention page pools
-    (``kv_seq`` and ``kv_heads`` axes) of a paged decode or chunk step.
-    Each layer writes its rows into the stack in place and the attention
-    kernels read it at the layer's index, so no layer's pool is sliced out
-    or stacked back.  Every other leaf (MLA's latent pool, SSM state,
-    cross-attention image KV, dense caches) is sliced per layer."""
-    if pages is None or mode not in ("decode", "chunk"):
+    (``kv_seq`` and ``kv_heads`` axes) of a decode or chunk step.  Each
+    layer writes its rows into the stack in place and the attention kernels
+    read it at the layer's index, so no layer's pool is sliced out or
+    stacked back.  Every other leaf (MLA's latent pool, SSM state,
+    cross-attention image KV) is sliced per layer."""
+    if mode not in ("decode", "chunk"):
         return set()
     return {k for k, s in _layer_cache_specs(cfg, spec, 1, 1).items()
             if "kv_seq" in s.axes and "kv_heads" in s.axes}
@@ -397,13 +382,12 @@ def _carried(cfg: ArchConfig, spec: LayerSpec, mode: str, pages) -> set:
 
 def _apply_stage(cfg: ArchConfig, stage: Stage, sp, x, *, positions, img,
                  mode: str, caches=None, pos=None, pages=None,
-                 full_kv: bool = False, attn_chunk: int = 0, chunk_len=None,
-                 aux0=None):
+                 attn_chunk: int = 0, chunk_len=None, aux0=None):
     """Scan `stage.repeats` iterations of the layer group.  Stacked page
     pools (:func:`_carried`) ride in the carry, indexed by the layer; every
     other cache leaf is sliced per layer (``xs``) and stacked back (``ys``)."""
     group = stage.group
-    keep = {str(gi): _carried(cfg, spec, mode, pages)
+    keep = {str(gi): _carried(cfg, spec, mode)
             for gi, spec in enumerate(group)}
     stacks, sliced = {}, caches
     if caches is not None:
@@ -422,7 +406,7 @@ def _apply_stage(cfg: ArchConfig, stage: Stage, sp, x, *, positions, img,
             xc, nc, a = _apply_layer(cfg, spec, lp[g], xc,
                                      positions=positions, img=img, mode=mode,
                                      cache=c_in, pos=pos, pages=pages,
-                                     full_kv=full_kv, attn_chunk=attn_chunk,
+                                     attn_chunk=attn_chunk,
                                      chunk_len=chunk_len, layer=r)
             if nc is not None:
                 new_caches[g] = {k: v for k, v in nc.items()
@@ -505,21 +489,16 @@ def lm_logits(cfg: ArchConfig, params, hidden):
 
 def forward_hidden(cfg: ArchConfig, params, batch: dict, *, mode="train",
                    caches=None, pos=None, pages=None, past_len=0,
-                   full_kv: bool = False, attn_chunk: int = 0, chunk_len=None,
+                   attn_chunk: int = 0, chunk_len=None,
                    main_repeats: int | None = None):
     """Run the stack; returns (hidden, aux_loss, new_caches_per_stage).
 
-    decode: ``caches`` is the per-stage cache tree; ``pages`` ([B, npp]
-    int32) switches attention caches to paged pools indirected through the
-    per-slot page table.  prefill: ``caches``, if given, is the *past* KV
-    tree of a cached prefix of ``past_len`` tokens (suffix prefill — the
-    prompt rows take positions ``past_len + arange(S)`` and attend over
-    concat(past, new)); ``full_kv`` makes sliding-window layers return
-    their full linear KV instead of a rolled ring (paged serving stores
-    every row and windows at decode time).  chunk (chunked prefill):
-    ``caches`` is the paged pool tree, ``pages`` the tables, ``past_len``
-    (traced scalar ok) the rows already prefilled, ``chunk_len`` the valid
-    rows in the fixed-size chunk buffer.
+    decode: ``caches`` is the per-stage paged cache tree, ``pages`` the
+    [B, npp] int32 page tables the attention pools are indirected through.
+    prefill: no cache in; the stack returns the prompt's full KV.  chunk
+    (chunked prefill): ``caches`` is the paged pool tree, ``pages`` the
+    tables, ``past_len`` (traced scalar ok) the rows already prefilled,
+    ``chunk_len`` the valid rows in the fixed-size chunk buffer.
     """
     x = embed_inputs(cfg, params, batch)
     x = constrain(x, ("batch", "seq", "embed"))
@@ -537,7 +516,7 @@ def forward_hidden(cfg: ArchConfig, params, batch: dict, *, mode="train",
         x, aux, ys = _apply_stage(cfg, stage, params["stages"][si], x,
                                   positions=positions, img=img, mode=mode,
                                   caches=c, pos=pos, pages=pages,
-                                  full_kv=full_kv, attn_chunk=attn_chunk,
+                                  attn_chunk=attn_chunk,
                                   chunk_len=chunk_len, aux0=aux)
         new_caches.append(ys)
     x = L.apply_norm(cfg, params["final_norm"], x)
@@ -567,34 +546,25 @@ def loss_fn(cfg: ArchConfig, params, batch: dict, *, attn_chunk: int = 0,
     return loss, {"ce": ce, "aux": aux}
 
 
-def prefill(cfg: ArchConfig, params, batch: dict, *, past=None,
-            past_len: int = 0, full_kv: bool = False,
-            cache_len: int | None = None, attn_chunk: int = 0,
+def prefill(cfg: ArchConfig, params, batch: dict, *, attn_chunk: int = 0,
             main_repeats: int | None = None):
-    """Returns (last-token logits, caches).
-
-    ``past``/``past_len``: cached-prefix KV tree + its token length (suffix
-    prefill over a radix-cache hit; the returned caches hold only the new
-    rows).  ``full_kv``: keep sliding-window layers' full linear KV (paged
-    serving).  ``cache_len``: zero-pad every kv_seq dim to this capacity so
-    the caches can be decoded into directly (replaces ``grow_cache``)."""
+    """Whole-prompt prefill.  Returns (last-token logits, caches): the
+    prompt's KV rows (every row, sliding-window layers included) and
+    stateful leaves, per stage, for :func:`write_prefill`."""
     hidden, _, caches = forward_hidden(cfg, params, batch, mode="prefill",
-                                       caches=past, past_len=past_len,
-                                       full_kv=full_kv, attn_chunk=attn_chunk,
+                                       attn_chunk=attn_chunk,
                                        main_repeats=main_repeats)
     logits = lm_logits(cfg, params, hidden[:, -1:])
-    if cache_len is not None:
-        caches = pad_cache_len(cfg, caches, cache_len, main_repeats)
     return logits, caches
 
 
-def decode_step(cfg: ArchConfig, params, caches, token, pos, *, pages=None,
+def decode_step(cfg: ArchConfig, params, caches, token, pos, *, pages,
                 main_repeats: int | None = None):
-    """One-token decode.  token: [B,1] int32; pos: scalar int32 (all slots in
-    lock-step) or [B] int32 (slot-indexed — every sequence at its own offset,
-    as driven by the continuous-batching engine).  ``pages`` ([B, npp] int32)
-    switches attention caches to paged pools: the new row is written through
-    the table and attention follows it (see ``layers.attn_decode``)."""
+    """One-token decode over the paged cache.  token: [B,1] int32; pos:
+    scalar int32 (all slots in lock-step) or [B] int32 (every sequence at
+    its own offset, as driven by the continuous-batching engine); pages:
+    [B, npp] int32 page tables.  The new row is written through the table
+    and attention follows it (see ``layers.attn_decode``)."""
     batch = {"tokens": token}
     hidden, _, new_caches = forward_hidden(cfg, params, batch, mode="decode",
                                            caches=caches, pos=pos, pages=pages,

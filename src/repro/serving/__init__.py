@@ -1,4 +1,3 @@
-from repro.core.cache import CacheLayout  # noqa: F401
 from repro.serving.chaos import (FAULT_POINTS, ChaosError,  # noqa: F401
                                  ChaosInjector)
 from repro.serving.config import CacheSpec, EngineConfig, MeshSpec  # noqa: F401

@@ -5,7 +5,7 @@ compiled shapes and memory: previous PRs accreted these as loose ``Engine``
 kwargs (``max_slots=``, ``prefill_bucket=``, ``kernel_mode=``, ...); the old
 spelling still works through a ``DeprecationWarning`` shim in ``Engine``.
 
-``CacheSpec`` describes the engine's KV-cache geometry (layout, page size,
+``CacheSpec`` describes the engine's paged KV-cache geometry (page size,
 pool size) and is derived from the config via ``EngineConfig.cache_spec()``.
 """
 from __future__ import annotations
@@ -13,19 +13,17 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from repro.core import round_up
-from repro.core.cache import CacheLayout
 
 
 @dataclass(frozen=True)
 class CacheSpec:
-    """Geometry of a serving KV cache.
+    """Geometry of the serving engine's paged KV cache.
 
-    ``layout=PAGED``: ``n_pages`` pages of ``page_size`` rows each (page 0 is
-    the engine's reserved trash page), page tables of width
-    ``pages_per_seq`` rows.  ``max_rows`` is the usable KV row budget —
-    the number every fixed-slot-vs-paged capacity comparison is made at.
+    ``n_pages`` pages of ``page_size`` rows each (page 0 is the engine's
+    reserved trash page), page tables of width ``pages_per_seq`` pages.
+    ``max_rows`` is the usable KV row budget — the number every
+    fixed-slot-vs-paged capacity comparison is made at.
     """
-    layout: CacheLayout = CacheLayout.PAGED
     page_size: int = 64
     n_pages: int = 0
     max_len: int = 512
@@ -106,9 +104,6 @@ class EngineConfig:
                    whole-suffix chunk (still through the mixed step on
                    prefix-decomposable models — one compiled variant per
                    power-of-two bucket, not per prompt length)
-    slo_ttft_s:    optional time-to-first-token SLO budget (seconds) — pure
-                   metadata for goodput reporting, no scheduling effect
-    slo_itl_s:     optional inter-token-latency SLO budget (seconds), ditto
     eos_id:        optional stop token (checked inside the scan)
     max_queue:     admission-control queue bound; past it ``submit`` finishes
                    the request immediately as ``FinishReason.REJECTED`` with
@@ -147,8 +142,6 @@ class EngineConfig:
     prefix_cache: bool = True
     decode_chunk: int = 8
     chunk_tokens: int | None = None
-    slo_ttft_s: float | None = None
-    slo_itl_s: float | None = None
     eos_id: int | None = None
     max_queue: int = 1024
     deadline_s: float | None = None
@@ -184,5 +177,4 @@ class EngineConfig:
             object.__setattr__(self, "mesh", MeshSpec.parse(self.mesh))
 
     def cache_spec(self) -> CacheSpec:
-        return CacheSpec(CacheLayout.PAGED, self.page_size, self.n_pages,
-                         self.max_len)
+        return CacheSpec(self.page_size, self.n_pages, self.max_len)

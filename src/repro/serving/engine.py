@@ -49,7 +49,7 @@ suffix_len)`` prefill executable cache; the LRU bound
 the exact-length whole-prompt path used by non-decomposable mixers
 (SSM / MLA / cross-attention), which cannot chunk.
 
-Cache layout (``EngineConfig.cache_spec()``, ``CacheLayout.PAGED``): every
+Cache layout (``EngineConfig.cache_spec()``): every
 attention layer owns a head-major ``[n_pages, K, page_size, d]`` page pool
 allocated up front via ``model.paged_cache_specs``; each live sequence holds a page
 *table* (``[pages_per_seq]`` int32, shared logically across all layers —
@@ -475,33 +475,13 @@ class ModelRunner:
 
     # -- exact-length whole-prompt prefill (non-decomposable mixers) ------
 
-    def _scatter_new(self, caches, small, table, slot, n: int):
-        """Write a whole-prompt prefill's outputs into the big cache: kv_seq
-        leaves scatter their ``n`` rows to logical rows ``[0, n)`` through
-        the page table; stateful leaves (SSM state, cross image-KV)
-        overwrite batch row ``slot``."""
-        j = jnp.arange(n, dtype=jnp.int32)
-        page, off = table[j // self.page_size], j % self.page_size
-
-        def w(spec, pool, sm):
-            rows = sm[:, 0].astype(pool.dtype)  # [R, n, (K,) d]
-            if "kv_seq" not in spec.axes:
-                return pool.at[:, slot].set(rows)
-            if "kv_heads" in spec.axes:  # head-major pool [R, P, K, ps, d]
-                return pool.at[:, page, :, off].set(jnp.moveaxis(rows, 1, 0))
-            return pool.at[:, page, off].set(rows)  # MLA pool [R, P, ps, d]
-
-        return jax.tree.map(w, self.cache_specs, caches, small,
-                            is_leaf=is_spec)
-
-    def _whole_prefill(self, n: int, params, caches, tokens, table, slot,
-                       temp1, rkey):
+    def _whole_prefill(self, params, caches, tokens, table, slot, temp1,
+                       rkey):
         """Exact-length whole-prompt prefill + cache insert (traceable —
         ``repro.analysis`` walks this jaxpr; ``whole_prefill_fn`` jits it).
         ``ok`` is the fault flag over the sampled logits row."""
-        logits, small = M.prefill(self.cfg, params, {"tokens": tokens},
-                                  full_kv=True)
-        caches = self._scatter_new(caches, small, table, slot, n)
+        logits, small = M.prefill(self.cfg, params, {"tokens": tokens})
+        caches = M.write_prefill(self.cfg, caches, small, table, slot)
         lf = logits[:, -1, : self.vocab].astype(jnp.float32)
         ok = jnp.all(jnp.isfinite(lf))
         t0, key1 = self._sample_lf(lf, temp1[None], rkey[None])
@@ -514,9 +494,8 @@ class ModelRunner:
         prompt length, LRU-bounded like the mixed variants."""
         return self._cached(
             ("whole", n),
-            lambda: jax.jit(
-                self._traced(functools.partial(self._whole_prefill, n)),
-                donate_argnums=(1,)),
+            lambda: jax.jit(self._traced(self._whole_prefill),
+                            donate_argnums=(1,)),
             limit)
 
     def _cached(self, key, build, limit: int):

@@ -282,13 +282,11 @@ def test_guarded_table_records_negative_indices():
 
 def test_shipped_kernel_specs_prove_clean():
     from repro.kernels.block_gemm import gemm_spec
-    from repro.kernels.decode_attention import fd_dense_spec, fd_paged_spec
+    from repro.kernels.decode_attention import fd_paged_spec
     from repro.kernels.flash_attention import fa_dense_spec, fa_paged_spec
 
     for spec in (fa_dense_spec(2, 4, 2, 96, 96, 64),
                  fa_paged_spec(2, 4, 2, 32, 64, 16, 4, 9),
-                 fd_dense_spec(2, 4, 2, 64, 64, 64, layout="linear"),
-                 fd_dense_spec(2, 4, 2, 64, 64, 64, layout="ring"),
                  fd_paged_spec(2, 4, 2, 64, 64, 16, 4, 9),
                  gemm_spec(64, 128, 256),
                  gemm_spec(64, 128, 256, int8=True)):

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import reference_greedy
 
 from repro.configs import get_config, reduce_config
 from repro.kernels import ref
@@ -46,24 +47,6 @@ def _econ(**kw):
     kw.setdefault("page_size", 16)
     kw.setdefault("decode_chunk", 4)
     return EngineConfig(**kw)
-
-
-def reference_greedy(cfg, params, prompt, max_new):
-    """Unpaged exact-length whole-prompt loop — the oracle every chunked
-    schedule must match bit for bit."""
-    plen = len(prompt)
-    logits, caches = M.prefill(cfg, params,
-                               {"tokens": jnp.asarray([prompt], jnp.int32)},
-                               cache_len=plen + max_new)
-    cur = int(jnp.argmax(logits[0, -1, : cfg.vocab_size]))
-    out = [cur]
-    for step in range(max_new - 1):
-        logits, caches = M.decode_step(cfg, params, caches,
-                                       jnp.asarray([[cur]], jnp.int32),
-                                       jnp.int32(plen + step))
-        cur = int(jnp.argmax(logits[0, -1, : cfg.vocab_size]))
-        out.append(cur)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -162,7 +145,7 @@ def test_paged_prefill_kernel_shared_kv_and_tail():
 @pytest.mark.parametrize("chunk_tokens", [8, 32, None])
 def test_chunked_greedy_parity_linear(olmo, chunk_tokens):
     """Every chunk schedule — 8-token chunks, 32-token chunks, whole-suffix
-    — produces the same greedy tokens as the unpaged whole-prompt loop."""
+    — produces the same greedy tokens as the whole-prompt oracle."""
     cfg, params = olmo
     eng = Engine(cfg, params, _econ(max_batch=3, chunk_tokens=chunk_tokens))
     prompts = _prompts(cfg, ["hello world", "x",

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import paged_prefill
 
 from repro.configs import get_config, reduce_config
 from repro.core.quant import QTensor
@@ -76,22 +77,22 @@ def test_interpret_gemma_window_softcap():
 
 def test_interpret_decode_matches_reference(edge):
     """Decode hot path obeys kernel_mode: interpret-mode ``decode_step``
-    (flash-decode Pallas kernel through the interpreter) matches the jnp
-    reference to <= 1e-4 logits on the edge config, stepping from the same
-    caches (capacity pre-padded via ``prefill(cache_len=...)``)."""
+    (paged flash-decode Pallas kernel through the interpreter) matches the
+    jnp reference to <= 1e-4 logits on the edge config, stepping from the
+    same pools (a whole prefill written through an identity page table)."""
     cfg, params, batch = edge
     toks = batch["tokens"]
     plen = toks.shape[1] - 3
-    _, caches = M.prefill(cfg, params, {"tokens": toks[:, :plen]},
-                          cache_len=toks.shape[1])
+    _, caches, pages = paged_prefill(cfg, params, {"tokens": toks[:, :plen]},
+                                     toks.shape[1])
     cfg_i = cfg.with_(kernel_mode="interpret")
     for step in range(3):
         lg_ref, caches_ref = M.decode_step(
             cfg, params, caches, toks[:, plen + step: plen + step + 1],
-            jnp.int32(plen + step))
+            jnp.int32(plen + step), pages=pages)
         lg_i, caches_i = M.decode_step(
             cfg_i, params, caches, toks[:, plen + step: plen + step + 1],
-            jnp.int32(plen + step))
+            jnp.int32(plen + step), pages=pages)
         np.testing.assert_allclose(np.asarray(lg_i), np.asarray(lg_ref),
                                    atol=ATOL, err_msg=f"step {step}")
         caches = caches_ref
@@ -107,11 +108,12 @@ def test_interpret_decode_matches_reference_mla():
     params = M.init(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(4), (2, 24), 0,
                               cfg.vocab_size)
-    _, caches = M.prefill(cfg, params, {"tokens": toks[:, :-1]}, cache_len=24)
+    _, caches, pages = paged_prefill(cfg, params, {"tokens": toks[:, :-1]},
+                                     24)
     lg_ref, _ = M.decode_step(cfg, params, caches, toks[:, -1:],
-                              jnp.int32(23))
+                              jnp.int32(23), pages=pages)
     lg_i, _ = M.decode_step(cfg.with_(kernel_mode="interpret"), params,
-                            caches, toks[:, -1:], jnp.int32(23))
+                            caches, toks[:, -1:], jnp.int32(23), pages=pages)
     np.testing.assert_allclose(np.asarray(lg_i, np.float32),
                                np.asarray(lg_ref, np.float32), atol=1e-2)
 
@@ -148,15 +150,15 @@ def test_w8a8_forward_close_to_fp32(edge):
 
 
 def test_w8a8_prefill_decode(edge):
-    """Quantized weights flow through prefill + the decode-step cache path."""
+    """Quantized weights flow through prefill + the paged decode step."""
     cfg, params, batch = edge
     cfg_q = cfg.with_(quant="w8a8")
     qp = M.quantize_params(cfg_q, params)
     toks = batch["tokens"]
-    lg, caches = M.prefill(cfg_q, qp, {"tokens": toks[:, :-1]},
-                           cache_len=toks.shape[1])
+    lg, caches, pages = paged_prefill(cfg_q, qp, {"tokens": toks[:, :-1]},
+                                      toks.shape[1])
     lg2, _ = M.decode_step(cfg_q, qp, caches, toks[:, -1:],
-                           jnp.int32(toks.shape[1] - 1))
+                           jnp.int32(toks.shape[1] - 1), pages=pages)
     assert np.isfinite(np.asarray(lg2, np.float32)).all()
 
 

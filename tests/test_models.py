@@ -1,10 +1,12 @@
 """Per-architecture smoke tests (reduced configs): one forward/train step on
-CPU asserting output shapes + no NaNs, plus prefill->decode consistency
-against the full forward pass (catches KV-cache/RoPE/ring-buffer bugs)."""
+CPU asserting output shapes + no NaNs, plus prefill -> paged decode
+consistency against the full forward pass (catches KV-cache/RoPE/window
+bugs)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import paged_prefill
 
 from repro.configs import REGISTRY, get_config, reduce_config
 from repro.models import model as M
@@ -75,10 +77,10 @@ def test_prefill_decode_matches_forward(built, name):
     # prefill on S-1 tokens, then decode token S-1
     b2 = dict(batch)
     b2["tokens"] = batch["tokens"][:, : S - 1]
-    _, caches = M.prefill(cfg, params, b2, cache_len=S)
+    _, caches, pages = paged_prefill(cfg, params, b2, S)
     step_logits, _ = M.decode_step(cfg, params, caches,
                                    batch["tokens"][:, S - 1:],
-                                   jnp.int32(S - 1))
+                                   jnp.int32(S - 1), pages=pages)
     np.testing.assert_allclose(
         np.asarray(step_logits[:, 0], np.float32),
         np.asarray(full_logits[:, 0], np.float32), atol=2e-2, rtol=1e-2)
@@ -96,21 +98,23 @@ def test_multi_step_decode_consistency(built, name):
 
     b2 = dict(batch)
     b2["tokens"] = batch["tokens"][:, :S]
-    logits, caches = M.prefill(cfg, params, b2, cache_len=S + extra)
+    logits, caches, pages = paged_prefill(cfg, params, b2, S + extra)
     np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
                                np.asarray(want[:, S - 1], np.float32),
                                atol=2e-2, rtol=1e-2)
     for i in range(extra):
         logits, caches = M.decode_step(
             cfg, params, caches, batch["tokens"][:, S + i: S + i + 1],
-            jnp.int32(S + i))
+            jnp.int32(S + i), pages=pages)
         np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
                                    np.asarray(want[:, S + i], np.float32),
                                    atol=3e-2, rtol=1e-2, err_msg=f"{name} step {i}")
 
 
-def test_sliding_window_ring_cache_wraps():
-    """Decode far past the window: ring buffer must stay consistent."""
+def test_sliding_window_decode_far_past_window():
+    """Decode far past the window: the window layers' validity bound
+    ``start = pos - window + 1`` must track the full forward's window mask
+    across page boundaries."""
     cfg = reduce_config(get_config("gemma3-4b"))  # window = 32
     params = M.init(cfg, jax.random.PRNGKey(0))
     B, S, extra = 1, 60, 8  # prefill spans nearly 2 windows
@@ -118,11 +122,11 @@ def test_sliding_window_ring_cache_wraps():
     hidden, _, _ = M.forward_hidden(cfg, params, batch, mode="prefill")
     want = M.lm_logits(cfg, params, hidden)
     b2 = {"tokens": batch["tokens"][:, :S]}
-    logits, caches = M.prefill(cfg, params, b2, cache_len=S + extra)
+    logits, caches, pages = paged_prefill(cfg, params, b2, S + extra)
     for i in range(extra):
         logits, caches = M.decode_step(cfg, params, caches,
                                        batch["tokens"][:, S + i: S + i + 1],
-                                       jnp.int32(S + i))
+                                       jnp.int32(S + i), pages=pages)
         np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
                                    np.asarray(want[:, S + i], np.float32),
                                    atol=3e-2, rtol=1e-2, err_msg=f"step {i}")

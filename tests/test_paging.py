@@ -1,10 +1,13 @@
 """Paged-KV building blocks: PagePool alloc/free/refcount invariants, the
 radix prefix cache (match/insert/evict, COW on divergence, hit accounting),
-and paged flash-decode parity — the oracle's page-gather against the dense
-linear layout, and the Pallas paged kernel (interpret) against the oracle."""
+the model's writes into the pools, and paged flash-decode parity — the
+oracle's page-gather against attention over the dense rows, and the Pallas
+paged kernel (interpret) against the oracle."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import attend_rows
 
 from repro.kernels import ref
 from repro.kernels import decode_attention
@@ -217,9 +220,9 @@ def _paged_fixture(B=3, H=4, K=2, P=9, ps=8, d=16, dv=None, seed=3):
 
 def test_paged_ref_equals_dense_linear():
     """The page table is pure indirection: the oracle over (pools, table)
-    must equal the oracle over the densely gathered cache."""
+    must equal attention over the densely gathered rows."""
     q, kp, vp, tbl, kd, vd, pos = _paged_fixture()
-    want = ref.flash_decode_ref(q, kd, vd, pos, None, layout="linear")
+    want = attend_rows(q, kd, vd, pos)
     got = ref.flash_decode_ref(q, kp, vp, pos, None, pages=tbl)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-6)
@@ -318,8 +321,8 @@ def _stack_layers(pool, layer, n=3, seed=0):
 @pytest.mark.parametrize("layer", [None, 0, 2])
 def test_paged_kernel_window_via_start(layer):
     """Sliding windows under paging express validity as start = pos - w + 1
-    over logical rows (no ring) — must equal the dense ring-free oracle
-    restricted to the window.  ``layer``: the pools are read as layer 0 or
+    over logical rows — must equal attention over the dense rows restricted
+    to the window.  ``layer``: the pools are read as layer 0 or
     2 of a stack of three, through the kernel's layer index (``None``: one
     layer's bare pools)."""
     q, kp, vp, tbl, kd, vd, pos = _paged_fixture(seed=5)
@@ -332,7 +335,7 @@ def test_paged_kernel_window_via_start(layer):
                        interpret=True)
     oracle = ref.flash_decode_ref(q, kp, vp, pos, start, pages=tbl,
                                   layer=layer)
-    want = ref.flash_decode_ref(q, kd, vd, pos, start, layout="linear")
+    want = attend_rows(q, kd, vd, pos, start)
     for out in (got, oracle):
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-6, rtol=1e-5)
@@ -384,6 +387,44 @@ def test_page_row_writes_land_in_place(kind, ps):
                 want[layer, tbl[b, r // ps], :, r % ps] = (
                     row[src] if kind == "decode" else row[src, i])
         np.testing.assert_array_equal(np.asarray(out), want)
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "minicpm3-4b",
+                                  "jamba-v0.1-52b"])
+def test_write_prefill_reads_back_row_for_row(arch):
+    """``M.write_prefill`` puts row ``r`` of a whole prefill, every layer,
+    at ``(table[r // ps], r % ps)`` of its pool — head-major attention
+    pools and MLA's latent pool alike — through a scattered table, and
+    overwrites batch row ``slot`` of a stateful leaf (SSM state); nothing
+    else in the tree changes."""
+    from repro.configs import get_config, reduce_config
+    from repro.models import model as M
+    from repro.models.params import is_spec
+
+    cfg = reduce_config(get_config(arch))
+    params = M.init(cfg, jax.random.PRNGKey(0))
+    n, ps, P, slot = 21, 8, 9, 1  # two full pages and part of a third
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, n), 0,
+                                cfg.vocab_size)
+    _, small = M.prefill(cfg, params, {"tokens": tokens})
+    table = np.array([7, 2, 5, 0])  # the last entry is never reached
+    got = M.write_prefill(cfg, M.init_paged_cache(cfg, 2, P, ps), small,
+                          jnp.asarray(table, jnp.int32), slot)
+    specs = jax.tree.leaves(M.paged_cache_specs(cfg, 2, P, ps),
+                            is_leaf=is_spec)
+    for spec, out, sm in zip(specs, jax.tree.leaves(got),
+                             jax.tree.leaves(small), strict=True):
+        out, sm = np.asarray(out), np.asarray(sm)
+        want = np.zeros_like(out)
+        if "kv_seq" not in spec.axes:
+            want[:, slot] = sm[:, 0]
+        for r in range(n if "kv_seq" in spec.axes else 0):
+            page, off = table[r // ps], r % ps
+            if "kv_heads" in spec.axes:  # [R, P, K, ps, d]
+                want[:, page, :, off] = sm[:, 0, r]
+            else:  # MLA: [R, P, ps, d]
+                want[:, page, off] = sm[:, 0, r]
+        np.testing.assert_array_equal(out, want, err_msg=str(spec.axes))
 
 
 def test_paged_kernel_mla_fused_operand():

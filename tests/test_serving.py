@@ -1,14 +1,14 @@
 """Paged continuous-batching engine: page allocation/recycling, radix prefix
 reuse (hit accounting, COW divergence, capacity wins at fixed memory),
-paged-vs-unpaged greedy parity (linear and sliding-window/ring-equivalent
-configs, reference and interpret kernel modes), admission control, the
-legacy-kwargs deprecation shim, and mid-flight arrivals."""
+greedy parity with the single-sequence paged oracle (linear and
+sliding-window configs, reference and interpret kernel modes), admission
+control, the legacy-kwargs deprecation shim, and mid-flight arrivals."""
 import warnings
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
+from _oracle import reference_greedy
 
 from repro.configs import get_config, reduce_config
 from repro.models import model as M
@@ -25,9 +25,8 @@ def olmo():
 
 @pytest.fixture(scope="module")
 def gemma():
-    """Local/global interleave with a sliding window — under paging the
-    window layers express validity via ``start`` instead of a ring, so this
-    is the ring-equivalent configuration."""
+    """Local/global interleave with a sliding window — the window layers
+    express validity via ``start = pos - window + 1``."""
     cfg = reduce_config(get_config("gemma3-4b"))
     params = M.init(cfg, jax.random.PRNGKey(0))
     return cfg, params
@@ -49,25 +48,6 @@ def _econ(**kw):
     kw.setdefault("page_size", 16)
     kw.setdefault("decode_chunk", 4)
     return EngineConfig(**kw)
-
-
-def reference_greedy(cfg, params, prompt, max_new):
-    """Unpaged exact-length loop: one prefill with the linear cache
-    pre-padded to plen + max_new rows, then per-token ``decode_step`` —
-    the oracle every paged engine output must match bit for bit."""
-    plen = len(prompt)
-    logits, caches = M.prefill(cfg, params,
-                               {"tokens": jnp.asarray([prompt], jnp.int32)},
-                               cache_len=plen + max_new)
-    cur = int(jnp.argmax(logits[0, -1, : cfg.vocab_size]))
-    out = [cur]
-    for step in range(max_new - 1):
-        logits, caches = M.decode_step(cfg, params, caches,
-                                       jnp.asarray([[cur]], jnp.int32),
-                                       jnp.int32(plen + step))
-        cur = int(jnp.argmax(logits[0, -1, : cfg.vocab_size]))
-        out.append(cur)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -96,7 +76,8 @@ def test_page_recycling_and_reuse(olmo):
 
 
 def test_matches_unbatched_reference_greedy(olmo):
-    """Paged scan decode == unpaged exact-length loop, token for token."""
+    """Batched paged scan decode == the single-sequence paged oracle, token
+    for token."""
     cfg, params = olmo
     eng = Engine(cfg, params, _econ(max_batch=3))
     prompts = _prompts(cfg, ["hello world", "x", "the quick brown fox"])
@@ -106,9 +87,9 @@ def test_matches_unbatched_reference_greedy(olmo):
 
 
 def test_window_config_matches_reference(gemma):
-    """Ring-equivalent config: sliding-window layers on the paged cache
-    (validity via start) decode identically to the unbatched reference path
-    with its ring caches, for prompts shorter AND longer than the window."""
+    """Sliding-window layers on the paged cache (validity via start) decode
+    identically in the engine and in the single-sequence oracle, for prompts
+    shorter AND longer than the window."""
     cfg, params = gemma
     assert cfg.window_size and cfg.local_global_pattern
     eng = Engine(cfg, params, _econ(max_len=128, max_batch=2))
@@ -349,7 +330,7 @@ def test_paged_prefix_reuse_beats_fixed_slot_at_equal_memory(edge):
     """ISSUE acceptance: 8 requests sharing a 512-token prefix.  At an
     equal KV row budget the paged+radix engine decodes all 8 concurrently
     where fixed per-slot allocation fits a single sequence — and every
-    output stays bit-identical to the unpaged exact-length loop."""
+    output stays bit-identical to the single-sequence oracle."""
     cfg, params = edge
     ps, n_req, prefix_len, suffix_len, max_new = 64, 8, 512, 8, 8
     rng = np.random.RandomState(3)
@@ -372,7 +353,7 @@ def test_paged_prefix_reuse_beats_fixed_slot_at_equal_memory(edge):
     assert stats.peak_active > fixed_slot_concurrency
     assert fixed_slot_concurrency == 1
     assert eng.prefix_hit_rate > 0.5  # requests 2..8 each hit 512/520
-    # paged-vs-unpaged greedy outputs: bit-identical
+    # engine-vs-oracle greedy outputs: bit-identical
     for p, seq in zip(prompts, out):
         assert seq[len(p):] == reference_greedy(cfg, params, p, max_new)
 
@@ -443,11 +424,12 @@ def test_engine_w8a8_serves_full_budget(olmo):
         assert all(0 <= t < cfg.vocab_size for t in seq)
 
 
-def test_paged_interpret_matches_unpaged_on_cgra_edge(edge):
-    """ISSUE acceptance: paged-vs-unpaged greedy parity on the edge config
-    in interpret mode — both sides run the exact Pallas kernel math, the
-    engine side through the paged flash-decode's page-table index map, with
-    a shared prefix exercising radix reuse + partial-page COW."""
+def test_paged_interpret_matches_single_sequence_on_cgra_edge(edge):
+    """Engine-vs-single-sequence greedy parity on the edge config in
+    interpret mode — both sides run the exact Pallas kernel math over paged
+    pools; the engine side batches, chunks, and shares a prefix (radix
+    reuse + partial-page COW), the oracle prefills one whole prompt into an
+    identity page table."""
     cfg, params = edge
     cfg_i = cfg.with_(kernel_mode="interpret")
     common = "shared edge prefix tokens: "  # 27 bytes: 1 full 16-page + COW

@@ -78,13 +78,17 @@ def test_flash_decode_paged(one_chip, page):
     assert "tpu_custom_call" in hlo
 
 
-def test_flash_decode_dense(one_chip):
-    """The slot-cache decode (``flash_decode_<layout>``), for callers of
-    ``attend_decode`` without a page table."""
+def test_flash_decode_paged_mla(one_chip):
+    """MLA's decode (minicpm3-4b widths, 64-row pages): one fused
+    ``[latent | k_rope]`` pool of one latent head passed as both k and v,
+    the value read narrowed to the latent's 256 columns by the BlockSpec."""
+    kvr, dr, Hm, npp = 256, 32, 40, MAX_LEN // 64
+    pool = ((B * npp + 1, 1, 64, kvr + dr), BF16)
     hlo = _compiled_hlo(
-        one_chip, lambda q, k, v, pos: flash_decode(q, k, v, pos),
-        ((B, H, DH), BF16), ((B, MAX_LEN, K, DH), BF16),
-        ((B, MAX_LEN, K, DH), BF16), ((B,), I32))
+        one_chip,
+        lambda q, kv, pos, pages: flash_decode(q, kv, kv, pos, pages=pages,
+                                               dv=kvr, scale=96 ** -0.5),
+        ((B, Hm, kvr + dr), BF16), pool, ((B,), I32), ((B, npp), I32))
     assert "tpu_custom_call" in hlo
 
 
